@@ -5,8 +5,8 @@ steps on an 8-virtual-device CPU mesh and records XLA ``memory_analysis()``
 per schedule: the GPipe backward can only start after all M microbatches'
 forwards, so every microbatch's residuals are live at the peak; 1F1B stashes
 at most 2S-1 stage inputs and recomputes the stage forward in the backward
-(VERDICT r3 weak #2 — "the only host-spanning schedule is the most
-memory-hungry one").
+(before it, the only host-spanning schedule was the most memory-hungry
+one).
 
 Writes benchmarks/pipeline_memory.json. Run:
   python benchmarks/run_1f1b_memory.py

@@ -1,4 +1,4 @@
-"""Per-kernel flash-attention breakdown + block sweep (VERDICT r3 weak #3).
+"""Per-kernel flash-attention breakdown + block sweep.
 
 Times the forward, dq, and dk/dv kernels SEPARATELY at seq 8192 head-dim 128
 bf16 across block shapes, attributing the fwd+bwd gap to its kernels.
@@ -9,7 +9,7 @@ dp + ds·k, dkv 8D — score recompute + dv + dp + ds·q), while the headline
 recompute excluded) by the total fwd+bwd time — the number
 grad_sweep_r3_hd128.json's 97 TFLOPS quotes.
 
-Writes benchmarks/kernel_profile_r4.json. Run ON CHIP:
+Writes benchmarks/kernel_profile.json. Run ON CHIP:
   python benchmarks/run_kernel_profile.py
 """
 
@@ -30,6 +30,9 @@ from distributed_model_parallel_tpu.ops.pallas_attention import (  # noqa: E402
     _flash_impl,
     _plan,
 )
+from distributed_model_parallel_tpu.utils.compile_cache import (  # noqa: E402
+    enable_compile_cache,
+)
 from distributed_model_parallel_tpu.utils.profiling import (  # noqa: E402
     time_fn_in_scan,
 )
@@ -39,6 +42,7 @@ PAIRS = T * (T + 1) // 2
 
 
 def main() -> None:
+    enable_compile_cache()
     rng = jax.random.key(0)
     ks = jax.random.split(rng, 4)
     q, k, v, g = (jax.random.normal(kk, (B, T, H, D), jnp.bfloat16)
@@ -119,7 +123,7 @@ def main() -> None:
                  "kernel times — the delta pass and unpad reshapes add "
                  "~2-3% on top in the end-to-end vjp."),
     }
-    path = pathlib.Path(__file__).parent / "kernel_profile_r4.json"
+    path = pathlib.Path(__file__).parent / "kernel_profile.json"
     path.write_text(json.dumps(out, indent=1) + "\n")
     print(f"wrote {path}: best={ {k: (v['block_q'], v['block_k']) for k, v in best.items()} } "
           f"model TFLOPS {out['model_tflops_at_best']}")

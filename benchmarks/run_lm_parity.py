@@ -100,6 +100,11 @@ def run_row(name: str, row: dict, steps: int) -> dict:
 
 
 def main() -> None:
+    from distributed_model_parallel_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", nargs="*", default=None,
                     help="subset of row names (default: all that fit the "

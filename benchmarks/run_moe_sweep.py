@@ -1,4 +1,4 @@
-"""MoE capacity_factor x aux-weight x z-loss sweep (VERDICT r4 next #3).
+"""MoE capacity_factor x aux-weight x z-loss sweep.
 
 The bench's one-number drop rate is measured a few steps from init, where
 an untrained router routes everything to the same top experts; what
@@ -29,6 +29,9 @@ from distributed_model_parallel_tpu.models import transformer as tfm  # noqa: E4
 from distributed_model_parallel_tpu.train.lm_trainer import (  # noqa: E402
     LMTrainConfig,
     LMTrainer,
+)
+from distributed_model_parallel_tpu.utils.compile_cache import (  # noqa: E402
+    enable_compile_cache,
 )
 from distributed_model_parallel_tpu.utils.profiling import (  # noqa: E402
     fetch,
@@ -92,6 +95,7 @@ def run_point(cf: float, aux_w: float, z_w: float) -> dict:
 
 
 def main() -> None:
+    enable_compile_cache()
     grid = list(itertools.product(
         [1.0, 1.25, 1.5, 2.0],       # capacity_factor
         [0.01, 0.05],                # load-balance aux weight
@@ -114,7 +118,7 @@ def main() -> None:
                  "top-2) balances within tens of steps under the aux loss, "
                  "so capacity should be provisioned for the steady state, "
                  "not for step 0. 'recommended' = fastest grid point with "
-                 "cf<=1.5 and steady-state drop <2% (VERDICT r4 #3)."),
+                 "cf<=1.5 and steady-state drop <2%."),
     }
     path = pathlib.Path(__file__).parent / "moe_sweep_r5.json"
     path.write_text(json.dumps(out, indent=1) + "\n")

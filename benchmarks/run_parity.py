@@ -40,14 +40,16 @@ def parse_args():
 
 
 def main():
+    from distributed_model_parallel_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     args = parse_args()
     if args.platform == "cpu":
         import jax
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices", args.device_count)
-        except Exception:
-            pass
+        jax.config.update("jax_num_cpu_devices", args.device_count)
     import jax
     import jax.numpy as jnp
 

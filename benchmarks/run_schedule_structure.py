@@ -1,4 +1,4 @@
-"""Pipeline-schedule structure evidence on the virtual mesh (VERDICT r4 #7).
+"""Pipeline-schedule structure evidence on the virtual mesh.
 
 One physical chip cannot time a real stage axis, but everything about the
 compiled schedules EXCEPT wall-clock is measurable on the 8-virtual-CPU

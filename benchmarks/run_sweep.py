@@ -191,16 +191,18 @@ def attention_sweep(args, results):
 
 
 def main():
+    from distributed_model_parallel_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     args = parse_args()
     if args.window is not None and args.window < 1:
         sys.exit("--window must be >= 1")
     if args.platform == "cpu":
         import jax
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices", args.device_count)
-        except Exception:
-            pass
+        jax.config.update("jax_num_cpu_devices", args.device_count)
     import jax
 
     results = []

@@ -25,23 +25,6 @@ collectives.
 
 __version__ = "0.1.0"
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # ``jax.shard_map`` is the stable name only in newer jax; the jax this
-    # container ships exposes it as ``jax.experimental.shard_map`` with the
-    # old ``check_rep`` kwarg where the codebase says ``check_vma``.
-    # Polyfill the stable name (must run before any submodule — every
-    # consumer imports through this package) so one codebase spans both.
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def _shard_map_compat(f, /, *args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_legacy(f, *args, **kwargs)
-
-    _jax.shard_map = _shard_map_compat
-
 from distributed_model_parallel_tpu.config import (  # noqa: F401
     DataConfig,
     MeshConfig,

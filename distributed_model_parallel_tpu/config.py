@@ -264,8 +264,7 @@ class TrainConfig:
     log_every_n_steps: int = 30             # reference data_parallel.py:116
     # Run the eval pass every N epochs (always on the final epoch). The
     # reference evals every epoch (data_parallel.py:160-172) — keep 1 for
-    # parity; raise it when eval wall-clock dominates short epochs (e.g.
-    # through a remote device tunnel where each eval batch pays an upload).
+    # parity; raise it when eval wall-clock dominates short epochs.
     eval_every: int = 1
     max_inflight_steps: int = 8             # bound on host run-ahead (async dispatch)
     # Numerical/stall guards (train/guards.py:GuardRunner): N > 0 checks

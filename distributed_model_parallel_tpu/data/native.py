@@ -1,8 +1,8 @@
 """ctypes bindings for the native host data path (native/dmp_native.cpp).
 
-Auto-builds the shared library with ``make`` on first use if a toolchain is
-available; every entry point has a pure-numpy fallback so the framework works
-without it (and tests assert native == numpy when it is available).
+Builds the shared library with ``make`` on first use; every entry point has
+a pure-numpy path so the framework works without a toolchain (one line on
+stderr says so, and tests assert native == numpy when it is available).
 """
 
 from __future__ import annotations
@@ -10,6 +10,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import sys
 import threading
 
 import numpy as np
@@ -29,15 +30,16 @@ def _load() -> ctypes.CDLL | None:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_LIB_PATH):
-            try:
-                subprocess.run(["make", "-C", _NATIVE_DIR, "-s"],
-                               check=True, capture_output=True, timeout=120)
-            except Exception:
-                return None
+        # Always ask make: it rebuilds when dmp_native.cpp is newer than
+        # the library (which git ignores) and is a no-op otherwise.
         try:
+            subprocess.run(["make", "-C", _NATIVE_DIR, "-s"],
+                           check=True, capture_output=True, timeout=120)
             lib = ctypes.CDLL(_LIB_PATH)
-        except OSError:
+        except (OSError, subprocess.SubprocessError) as e:
+            print(f"[data/native] native library unavailable "
+                  f"({type(e).__name__}: {e}); taking the numpy path",
+                  file=sys.stderr, flush=True)
             return None
         lib.dmp_gather_rows.argtypes = [
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,

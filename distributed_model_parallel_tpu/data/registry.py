@@ -181,7 +181,7 @@ def _build_split(paths: list[str], labels: list[int], image_size: int,
 
     ``lazy=None`` decides by decoded size (> LAZY_AUTO_BYTES streams) —
     small sets keep the decode-once speed, ImageNet-scale sets are no
-    longer bounded by host RAM (VERDICT r3 weak #6)."""
+    longer bounded by host RAM."""
     y = np.asarray(labels, np.int32)
     if lazy is None:
         lazy = len(paths) * image_size * image_size * 3 > LAZY_AUTO_BYTES

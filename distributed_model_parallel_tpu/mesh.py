@@ -12,7 +12,6 @@ axes; XLA inserts the collectives (psum/ppermute/all_gather) over ICI/DCN.
 from __future__ import annotations
 
 import dataclasses
-import logging
 import os
 from typing import Sequence
 
@@ -21,8 +20,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_model_parallel_tpu.config import MeshConfig
-
-logger = logging.getLogger(__name__)
 
 # Name of the cross-host (slow-network) sub-axis of data parallelism; it
 # exists in the mesh only when MeshConfig.dcn_data > 1.
@@ -41,22 +38,13 @@ def best_effort_distributed_init() -> bool:
     want = os.environ.get("DMP_TPU_DISTRIBUTED", "auto")
     if want == "0":
         return False
-    try:
-        if jax.process_count() > 1:
-            return True  # already initialized
-    except Exception as e:
-        # Backend unreachable: don't traceback out of the probe — the
-        # caller's hardened device contact (utils/device_contact.py)
-        # owns the retry/parseable-failure-record policy.
-        logger.warning("backend probe failed during distributed init: %s", e)
-        return False
-    coordinator = os.environ.get("JAX_COORDINATOR_ADDRESS")
-    if want == "1" or coordinator:
-        try:
-            jax.distributed.initialize()
-            return True
-        except Exception as e:  # pragma: no cover - environment dependent
-            logger.warning("jax.distributed.initialize failed: %s", e)
+    if jax.distributed.is_initialized():
+        return True
+    if want == "1" or os.environ.get("JAX_COORDINATOR_ADDRESS"):
+        # Asked for: a failure here propagates — carrying on as one
+        # process would train a different job than the one requested.
+        jax.distributed.initialize()
+        return True
     return False
 
 

@@ -522,7 +522,7 @@ def _cached_block(bp: dict, ck: jax.Array, cv: jax.Array, layer: jax.Array,
     place across layers and steps. The pre-round-5 layout (per-layer
     caches as scan xs with stacked ys outputs) forced a full-cache
     materialization every decode step: ~25% of decode device time was
-    whole-cache copies (hardware trace, VERDICT r4 weak #3).
+    whole-cache copies (hardware trace).
 
     ``read_len`` (static) scores against only the first ``read_len``
     cache positions instead of the whole padding — callers guarantee
@@ -815,8 +815,8 @@ def generate(params: dict, cfg: TransformerConfig, prompt: jax.Array,
     # keys 0..p, so a scan whose positions all sit below a static boundary
     # reads just that cache prefix — the written part plus <SEG slack —
     # instead of the full padded [total] every step. Decode is HBM-bound
-    # on exactly that read; the masked-out tail was pure wasted bandwidth
-    # (VERDICT r4 weak #3). Each boundary compiles its own small scan.
+    # on exactly that read; the masked-out tail was pure wasted bandwidth.
+    # Each boundary compiles its own small scan.
     SEG = DECODE_READ_SEG
     parts = []
     carry = (cache_k, cache_v, tok0, rng)

@@ -47,15 +47,6 @@ def _tree_bytes(tree: Any) -> int:
                for l in jax.tree.leaves(tree))
 
 
-def axis_size(axis_name) -> int:
-    """Static size of a named mesh axis. ``jax.lax.axis_size`` is the
-    stable spelling only in newer jax; the psum-of-1 idiom constant-folds
-    to the same int everywhere."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def flatten_padded(tree: Any, n_shards: int, dtype=jnp.float32) -> jax.Array:
     """Concatenate all leaves (cast to ``dtype``, f32 by default) into one
     flat vector padded to a multiple of ``n_shards`` — the canonical
@@ -93,7 +84,7 @@ def ppermute_shift(x: jax.Array, axis_name: str, *, shift: int = 1) -> jax.Array
     send/recv (``distributed_layers.py:7-62``); on hardware this rides the ICI
     ring neighbor links.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     record_collective("ppermute", axis_name, _tree_bytes(x), n)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return jax.lax.ppermute(x, axis_name, perm)
@@ -101,7 +92,7 @@ def ppermute_shift(x: jax.Array, axis_name: str, *, shift: int = 1) -> jax.Array
 
 def all_gather_concat(x: jax.Array, axis_name: str, *, axis: int = 0) -> jax.Array:
     """Gather shards along ``axis`` (DataParallel's output ``gather``)."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     record_collective("all_gather", axis_name, _tree_bytes(x) * n, n)
     return jax.lax.all_gather(x, axis_name, axis=axis, tiled=True)
 
@@ -110,7 +101,7 @@ def reduce_scatter_mean(x: jax.Array, axis_name: str, *, axis: int = 0) -> jax.A
     """psum_scatter-mean: each shard gets one slice of the reduced result —
     the building block of ZeRO-style sharded optimizers and of halving
     allreduce traffic when parameters are sharded."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     record_collective("reduce_scatter", axis_name, _tree_bytes(x), n)
     return jax.lax.psum_scatter(x, axis_name, scatter_dimension=axis,
                                 tiled=True) / n
@@ -168,7 +159,7 @@ def bucketed_psum(tree: Any, axis_name: str, *,
         reduce_fn = jax.lax.psum
     leaves, treedef = jax.tree.flatten(tree)
     n = jax.lax.psum(1, axis_name) if mean else 1
-    n_axis = axis_size(axis_name)
+    n_axis = jax.lax.axis_size(axis_name)
     out: list[Any] = [None] * len(leaves)
     for bucket in plan_buckets(tree, bucket_bytes):
         wire_dtype = (jnp.dtype(accum_dtype) if accum_dtype is not None
@@ -202,8 +193,8 @@ def hierarchical_psum(x: jax.Array, inner_axis: str, outer_axis: str, *,
     ``Readme.md:148-157``.) Requires ``x``'s leading dim divisible by
     |inner|; use ``hierarchical_psum_tree`` for arbitrary pytrees.
     """
-    n_in = axis_size(inner_axis)
-    n_out = axis_size(outer_axis)
+    n_in = jax.lax.axis_size(inner_axis)
+    n_out = jax.lax.axis_size(outer_axis)
     record_collective("reduce_scatter", inner_axis, _tree_bytes(x), n_in)
     shard = jax.lax.psum_scatter(x, inner_axis, scatter_dimension=0,
                                  tiled=True)
@@ -224,7 +215,7 @@ def hierarchical_psum_tree(tree: Any, inner_axis: str, outer_axis: str, *,
     ``lax.psum``) this sums by default; pass ``mean=True`` for DDP-style
     gradient averaging. The flat vector uses the promoted leaf dtype, not
     f32 — same wire-payload rule as ``bucketed_psum``."""
-    flat = flatten_padded(tree, axis_size(inner_axis),
+    flat = flatten_padded(tree, jax.lax.axis_size(inner_axis),
                           dtype=jnp.result_type(*jax.tree.leaves(tree)))
     red = hierarchical_psum(flat, inner_axis, outer_axis, mean=mean)
     return unflatten_like(red, tree)

@@ -9,26 +9,26 @@ of fixed-size pages ``[n_pages, page_size, Hkv, Dh]`` per layer plus a
 per-sequence page table, so a sequence holds exactly
 ``ceil(len / page_size)`` pages and returns them the moment it finishes.
 
-This module is the attention read over that pool. Three tiers, one math:
+This module is the attention read over that pool. Three tiers:
 
-* :func:`attend_rows` — the single softmax/score definition every path
-  shares (mirrors ``_cached_block``'s grouped-head scores + ``band_keep``
-  masking), so paged and dense decoding cannot diverge numerically;
+* :func:`attend_rows` — the single softmax/score definition of the XLA
+  path, prefill chunks and the speculative verify window (mirrors
+  ``_cached_block``'s grouped-head scores + ``band_keep`` masking), so
+  paged and dense decoding cannot diverge numerically;
 * :func:`paged_attention_xla` — gather the table's pages into a
   contiguous ``[B, T, Hkv, Dh]`` view and run :func:`attend_rows`; works
-  on every backend (the off-TPU fallback, the prefill path, and the
+  on every backend (decode off-TPU, the prefill path, and the
   speculative-decoding verify step — its ``width``-token windows ride
   the same per-row-position support prefill chunks use);
 * :func:`paged_attention_kernel` — the Pallas TPU kernel: the page table
   rides in scalar-prefetch SMEM and feeds the K/V block index maps, so
-  pages stream HBM→VMEM directly (``pl.when`` skips the DMA + copy for
-  logical pages past the sequence's length — the block-quantized-read
-  idiom from ``generate()``'s read-boundary segments, at page
-  granularity) and the gathered ``[B, T, ...]`` intermediate never
-  exists in HBM. The final grid step runs the *same* :func:`attend_rows`
-  on the VMEM-resident pages, which is what makes the kernel bitwise
-  against the XLA path in interpreter mode (the parity contract
-  tests/test_paged_attention.py pins).
+  pages stream HBM→VMEM directly (``pl.when`` skips the DMA + compute
+  for logical pages outside the row's band) and the gathered
+  ``[B, T, ...]`` intermediate never exists in HBM. Each page updates an
+  online-softmax carry in VMEM, so the kernel agrees with
+  :func:`attend_rows` to rounding, not bitwise (the tolerance
+  tests/test_paged_attention.py states; exact greedy tokens are pinned
+  at engine level).
 
 Masking is sanitizing, not just causal: positions past a row's length are
 zeroed in K/V *and* banded out of the scores, so stale page contents
@@ -47,7 +47,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from distributed_model_parallel_tpu.ops.pallas_attention import band_keep
+from distributed_model_parallel_tpu.ops.pallas_attention import (
+    _LANE_W,
+    NEG_INF,
+    band_keep,
+)
 
 
 def attend_rows(q: jax.Array, kr: jax.Array, vr: jax.Array,
@@ -113,42 +117,73 @@ def paged_attention_xla(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
 # ---------------------------------------------------------------------------
 
 def _paged_decode_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                         k_scr, v_scr, *, page: int, n_pages: int,
-                         hkv: int, dh: int, window: int | None):
-    """Grid: (B, n_pages). Scalar prefetch: tables [B, N], pos [B]. Each
-    minor step DMAs one of the row's pages (the index map reads the page
-    table; out-of-range steps re-map to the last used page so Mosaic
-    elides the repeat DMA) and copies it into the contiguous VMEM
-    scratch; ``pl.when`` skips the copy for logical pages past the row's
-    length, so a short sequence reads only its own pages. The last step
-    runs the shared :func:`attend_rows` on the assembled [T, Hkv, Dh]
-    scratch — same ops as the XLA path, which is the bitwise-parity
-    contract (interpreter). The dense-softmax-in-VMEM final step bounds
-    T at VMEM capacity (serving contexts; a multi-kilobyte-page online-
-    softmax variant is the long-context extension point).
+                         m_scr, l_scr, acc_scr, *, page: int, hkv: int,
+                         dh: int, window: int | None):
+    """Grid: (B, n_pages). Scalar prefetch: tables [B, N], pos [B]. Blocks:
+    q/o [1, H, Dh]; k/v [1, page, Hkv, Dh] (one of the row's pages per
+    minor step — the index map reads the page table and clamps
+    out-of-band steps onto an already-resident page so Mosaic elides the
+    repeat DMA). Scratch: m/l [H, 128] f32 (sublane-major, lanes
+    redundant) and acc [H, Dh] f32 — the online-softmax carry across the
+    row's pages (the flash forward's idiom, ops/pallas_attention.py), so
+    fast-memory use is one page plus the carry whatever the context.
+
+    ``pl.when`` skips pages past ``pos // page`` (and, windowed, pages
+    wholly left of the band). Inside a processed page every position the
+    band excludes — past ``pos``, or left of the window — is zeroed in
+    K/V before the dots and banded out of the scores, so stale page
+    contents reach no reduction (module docstring). Each KV head is two
+    plain 2-D dots: its ``G = H // Hkv`` query rows against the head's
+    [page, Dh] rows of the block.
     """
     b = pl.program_id(0)
     j = pl.program_id(1)
     pos = pos_ref[b]
+    g = q_ref.shape[1] // hkv
 
-    @pl.when(j <= pos // page)
-    def _copy():
-        k_scr[pl.dslice(j * page, page), :] = k_ref[0].reshape(
-            page, hkv * dh)
-        v_scr[pl.dslice(j * page, page), :] = v_ref[0].reshape(
-            page, hkv * dh)
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    @pl.when(j == n_pages - 1)
+    @pl.when(jnp.logical_and(j >= _first_page(pos, page, window),
+                             j <= pos // page))
+    def _step():
+        # The band predicate in both orientations: along lanes for the
+        # [G, page] scores, along sublanes for the [page, Dh] K/V rows.
+        keep_s = band_keep(pos, j * page + jax.lax.broadcasted_iota(
+            jnp.int32, (1, page), 1), window)
+        keep_kv = band_keep(pos, j * page + jax.lax.broadcasted_iota(
+            jnp.int32, (page, 1), 0), window)
+        q = q_ref[0]                                       # [H, Dh]
+        for h in range(hkv):
+            rows = slice(h * g, (h + 1) * g)
+            k = jnp.where(keep_kv, k_ref[0, :, h, :], 0)   # [page, Dh]
+            v = jnp.where(keep_kv, v_ref[0, :, h, :], 0)
+            s = jnp.dot(q[rows], k.T,
+                        preferred_element_type=jnp.float32) * (dh ** -0.5)
+            s = jnp.where(keep_s, s, NEG_INF)              # [G, page]
+            m = m_scr[rows]                                # [G, LW]
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1)[:, None])
+            p = jnp.where(keep_s, jnp.exp(s - m_new[:, :1]), 0.0)
+            alpha = jnp.exp(m - m_new)
+            l_scr[rows] = alpha * l_scr[rows] + jnp.sum(p, axis=-1)[:, None]
+            acc_scr[rows] = alpha[:, :1] * acc_scr[rows] + jnp.dot(
+                p, v.astype(jnp.float32), preferred_element_type=jnp.float32)
+            m_scr[rows] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
-        t = n_pages * page
-        q = q_ref[...][None]                           # [1, 1, H, Dh]
-        kr = k_scr[...].reshape(1, t, hkv, dh)
-        vr = v_scr[...].reshape(1, t, hkv, dh)
-        # lengths zeroes everything past pos (including scratch rows no
-        # copy step ever wrote — uninitialized VMEM must not reach a
-        # reduction even multiplied by an exact-zero weight).
-        o = attend_rows(q, kr, vr, pos[None, None], pos[None] + 1, window)
-        o_ref[...] = o[0].astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
+
+
+def _first_page(pos, page: int, window: int | None):
+    """First logical page holding a key inside the row's band (0 if
+    unwindowed): the page of key ``pos - window + 1``."""
+    if window is None:
+        return 0
+    return jnp.maximum(0, (pos - window + 1) // page)
 
 
 def paged_attention_kernel(q: jax.Array, k_pool: jax.Array,
@@ -161,8 +196,8 @@ def paged_attention_kernel(q: jax.Array, k_pool: jax.Array,
     [B] (the query token's absolute position; the row attends positions
     [0, pos], band-clamped under ``window``). Returns [B, 1, H, Dh].
 
-    ``interpret=None`` auto-selects interpret mode off-TPU (tests run the
-    kernel on CPU; the engine only dispatches it on real TPUs).
+    ``interpret=None`` compiles the kernel on a TPU backend and
+    interprets it elsewhere (the CPU tests).
     """
     if q.shape[1] != 1:
         raise ValueError(f"the paged decode kernel takes one query token "
@@ -171,39 +206,45 @@ def paged_attention_kernel(q: jax.Array, k_pool: jax.Array,
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
     b, _, h, dh = q.shape
-    n_total, page, hkv, _ = k_pool.shape
+    _, page, hkv, _ = k_pool.shape
     n = tables.shape[1]
-    t = n * page
 
     def page_map(bi, j, tables_ref, pos_ref):
-        # Clamp to the row's last used page: out-of-band steps re-fetch
+        # Clamp into the row's band of pages: out-of-band steps re-fetch
         # an already-resident block (DMA elided) and pl.when skips them.
-        last = pos_ref[bi] // page
-        return (tables_ref[bi, jnp.minimum(j, last)], 0, 0, 0)
+        pos = pos_ref[bi]
+        j = jnp.clip(j, _first_page(pos, page, window), pos // page)
+        return (tables_ref[bi, j], 0, 0, 0)
+
+    def q_map(bi, j, tables_ref, pos_ref):
+        return (bi, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, n),
         in_specs=[
-            pl.BlockSpec((1, h, dh), lambda bi, j, tr, pr: (bi, 0, 0)),
+            pl.BlockSpec((1, h, dh), q_map),
             pl.BlockSpec((1, page, hkv, dh), page_map),
             pl.BlockSpec((1, page, hkv, dh), page_map),
         ],
-        out_specs=pl.BlockSpec((1, h, dh), lambda bi, j, tr, pr: (bi, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, dh), q_map),
         scratch_shapes=[
-            pltpu.VMEM((t, hkv * dh), k_pool.dtype),
-            pltpu.VMEM((t, hkv * dh), v_pool.dtype),
+            pltpu.VMEM((h, _LANE_W), jnp.float32),
+            pltpu.VMEM((h, _LANE_W), jnp.float32),
+            pltpu.VMEM((h, dh), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _paged_decode_kernel, page=page, n_pages=n, hkv=hkv, dh=dh,
-        window=window)
+        _paged_decode_kernel, page=page, hkv=hkv, dh=dh, window=window)
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(tables.astype(jnp.int32), positions.astype(jnp.int32),
-      q[:, 0], k_pool, v_pool)
+        name="paged_decode_attention",
+    )(tables.astype(jnp.int32), positions.astype(jnp.int32), q[:, 0],
+      k_pool, v_pool)
     return out[:, None]
 
 
@@ -216,7 +257,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     "pallas". The kernel is decode-only (C == 1); multi-token prefill
     chunks take the gather path under EVERY impl — "pallas" forces the
     kernel for the decode steps (interpret mode off-TPU), it does not
-    turn prefill into a kernel call."""
+    turn prefill into a kernel call. On a TPU "auto" means the compiled
+    kernel and nothing else: a lowering error propagates."""
     if impl not in ("auto", "xla", "pallas"):
         raise ValueError(f"unknown paged-attention impl {impl!r}; "
                          f"known: auto, xla, pallas")

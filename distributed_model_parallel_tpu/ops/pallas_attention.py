@@ -36,11 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# ``pltpu.CompilerParams`` is the newer spelling; this container's pallas
-# still names it ``TPUCompilerParams`` (same fields).
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -83,7 +78,7 @@ def _when_banded(in_band, interior, step):
     """Dispatch one grid step to ``step(masked: bool)``: mask-free for
     band-interior tiles, masked for diagonal/window-edge tiles, skipped
     outside the band. Shared by all three kernels (the fast path matters
-    because the forward is VPU-bound — kernel_profile_r4.json)."""
+    because the forward is VPU-bound — benchmarks/run_kernel_profile.py)."""
     pl.when(jnp.logical_and(in_band, interior))(lambda: step(False))
     pl.when(jnp.logical_and(in_band, jnp.logical_not(interior)))(
         lambda: step(True))
@@ -171,7 +166,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         # Blocks strictly inside the band (every key <= every query, no
         # window edge) take a mask-free step — the iota/compare/select VPU
         # passes run only on diagonal-crossing blocks, which matters
-        # because the forward is VPU-bound (kernel_profile_r4.json).
+        # because the forward is VPU-bound
+        # (benchmarks/run_kernel_profile.py).
         in_band = jnp.logical_and(j >= _band_start_k(qi, bq, window, bk),
                                   j <= _last_k_block(qi, bq, bk))
         _when_banded(in_band, _block_interior(qi, j, bq, bk, window), _step)
@@ -431,7 +427,7 @@ def _flash_impl(q, k, v, causal, block_q, block_k, interpret, window=None):
             pltpu.VMEM((bq, _LANE_W), jnp.float32),
             pltpu.VMEM((bq, d_pad), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=_SEQ_SEMANTICS),
         interpret=interp,
     )(qf, kf, vf)
@@ -484,7 +480,7 @@ def _bwd_dq_call(qf, kf, vf, gf, lse, delta, *, bq, bk, d_pad, causal, scale,
         scratch_shapes=[pltpu.VMEM((bq, d_pad), jnp.float32),
                         pltpu.VMEM((bq, _LANE_W), jnp.float32),
                         pltpu.VMEM((bq, _LANE_W), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=_SEQ_SEMANTICS),
         interpret=interp,
     )(qf, kf, vf, gf, lse, delta)
@@ -517,7 +513,7 @@ def _bwd_dkv_call(qf, kf, vf, gf, lse, delta, *, bq, bk, d_pad, causal,
             pltpu.VMEM((bk, d_pad), jnp.float32),
             pltpu.VMEM((bk, d_pad), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=_SEQ_SEMANTICS),
         interpret=interp,
     )(qf, kf, vf, gf, lse, delta)
@@ -532,8 +528,8 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, block_q, block_k, interpret,
     own (q block, k block) tile shape — the two kernels have opposite
     residency (dq keeps queries resident and streams K/V; dk/dv the
     reverse), so their best tiles differ from the forward's and from each
-    other (measured per-kernel sweep: benchmarks/kernel_profile_r4.json;
-    both prefer 1024x1024 on v5e where the forward wants 512x1024).
+    other (per-kernel sweep: benchmarks/run_kernel_profile.py; both
+    prefer 1024x1024 on v5e where the forward wants 512x1024).
     Unset, both inherit ``block_q``/``block_k``."""
     b, t, h, d = q.shape
     t_pad, d_pad, bq, bk, interp = _plan(t, d, causal, block_q, block_k,
@@ -638,7 +634,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 _DISPATCH_TABLE: dict[str, dict] = {
     # bwd kernels carry their own measured tiles (dq_/dkv_block_*): both
     # backward kernels prefer 1024x1024 on v5e where the forward's best
-    # is 512x1024 (benchmarks/kernel_profile_r4.json, seq-8k hd-128 sweep).
+    # is 512x1024 (benchmarks/run_kernel_profile.py, seq-8k hd-128 sweep).
     "TPU v5 lite": {"min_seq": {"bfloat16": 1024, "float32": 1024},
                     "block_q": 512, "block_k": 1024, "max_head_dim": 256,
                     "dq_block_q": 1024, "dq_block_k": 1024,

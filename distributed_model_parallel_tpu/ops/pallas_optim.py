@@ -4,8 +4,9 @@ The optax path (train/optim.py) lowers the reference recipe —
 ``add_decayed_weights`` → momentum ``trace`` → ``scale_by_learning_rate``
 — to a chain of per-leaf elementwise HLO ops: for a CNN with ~160
 parameter leaves that is ~500 tiny kernels per step, each reading and
-writing its operands through HBM. kernel_profile_r4.json shows the CNN
-step is bandwidth-bound, so every avoided HBM round trip is wall time.
+writing its operands through HBM. The CNN step is bandwidth-bound
+(benchmarks/step_profile_r5.json), so every avoided HBM round trip is
+wall time.
 
 This module fuses the whole update into ONE elementwise Pallas kernel per
 flat parameter bucket (``ops/collectives.plan_buckets`` — the same

@@ -40,8 +40,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from distributed_model_parallel_tpu.ops.collectives import axis_size
-
 _NEG = -1e30
 
 
@@ -70,7 +68,7 @@ def _ring_xla(q: jax.Array, k: jax.Array, v: jax.Array, axis_name: str,
               causal: bool) -> jax.Array:
     """The XLA block-math ring: materializes each hop's local score tensor
     (fine at short T_local); online-softmax state carried in f32."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     t_local = q.shape[1]
     scale = q.shape[-1] ** -0.5
@@ -167,7 +165,7 @@ def _ring_flash_fwd_impl(q, k, v, axis_name, causal):
         default_blocks,
     )
 
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, t, h, _ = q.shape
     bq, bk = default_blocks()
@@ -214,7 +212,7 @@ def _ring_flash_bwd(axis_name, causal, res, g):
     )
 
     q, k, v, o, lse = res
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, t, h, _ = q.shape
     bq, bk = default_blocks()
@@ -307,7 +305,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     raised matmul-precision context auto-declines the kernel); "flash"
     forces the pallas kernel for dtypes/regimes the table excludes.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if q.shape[2] % n:
         raise ValueError(f"heads {q.shape[2]} not divisible by axis size {n}")
 

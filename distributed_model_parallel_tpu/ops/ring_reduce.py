@@ -27,10 +27,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from distributed_model_parallel_tpu.ops.collectives import (
-    axis_size,
-    bucketed_psum,
-)
+from distributed_model_parallel_tpu.ops.collectives import bucketed_psum
 
 
 def _neighbor_perm(n: int) -> list[tuple[int, int]]:
@@ -43,7 +40,7 @@ def _reduce_scatter_phase(chunks: jax.Array, axis_name: str) -> jax.Array:
     At step s, device i sends chunk (i - s - 1) mod N to its right neighbor
     and accumulates the incoming chunk (i - s - 2) mod N.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = _neighbor_perm(n)
 
@@ -62,7 +59,7 @@ def _all_gather_phase(chunks: jax.Array, axis_name: str) -> jax.Array:
     At step s, device i sends chunk (i - s) mod N and stores the incoming
     chunk (i - s - 1) mod N.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = _neighbor_perm(n)
 
@@ -81,7 +78,7 @@ def ring_all_reduce(x: jax.Array, axis_name: str, *, mean: bool = False
     Result equals ``lax.psum(x, axis_name)`` (divided by N when ``mean``),
     for any shape — the buffer is flattened and zero-padded to N chunks.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     shape, size = x.shape, x.size
@@ -102,7 +99,7 @@ def ring_reduce_scatter(x: jax.Array, axis_name: str, *, mean: bool = False
     buffer — same semantics as ``lax.psum_scatter(..., tiled=True)`` along
     axis 0. Requires ``x.shape[0] % N == 0``.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     if x.shape[0] % n:

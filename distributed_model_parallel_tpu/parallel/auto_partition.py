@@ -47,18 +47,12 @@ def compiled_flops_probe(fn, *args) -> float | None:
     for the loop-free per-unit programs this module costs)."""
     try:
         analysis = jax.jit(fn).lower(*args).compile().cost_analysis()
-        if isinstance(analysis, (list, tuple)):  # older jax returns [dict]
-            analysis = analysis[0] if analysis else {}
         flops = analysis.get("flops", None)
         if flops is None or not np.isfinite(flops) or flops < 0:
             return None
         return float(flops)
     except Exception:
         return None
-
-
-# Historical private name (pre-autotune callers).
-_compiled_flops = compiled_flops_probe
 
 
 def unit_costs(model: StagedModel, sample_shape: Sequence[int],

@@ -210,6 +210,26 @@ def make_verify_step(cfg: TransformerConfig, *, page_size: int,
     return jax.jit(step, donate_argnums=(1, 2))
 
 
+def decode_logits(params: dict, ck: jax.Array, cv: jax.Array,
+                  tokens: jax.Array, positions: jax.Array,
+                  tables: jax.Array, active: jax.Array,
+                  cfg: TransformerConfig, *, page_size: int, n_pages: int,
+                  impl: str) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The decode step's forward: feed ``tokens [B]`` at ``positions [B]``
+    through the paged cache (idle rows' writes dropped) and return
+    ``(ck, cv, logits [B, V])``. :func:`make_decode_step` samples from
+    these; chip_smoke.py compares them across ``impl`` values."""
+    pos2 = positions[:, None]                                 # [B, 1]
+    pages = jnp.take_along_axis(tables, pos2 // page_size, axis=1)
+    pages = jnp.where(active[:, None], pages, n_pages)        # idle: drop
+    offsets = pos2 % page_size
+    lengths = positions + 1
+    x = _embed_rows(params, tokens[:, None], pos2, cfg)
+    x, ck, cv = _layers_scan(params, ck, cv, x, pos2, pages, offsets,
+                             tables, lengths, cfg, impl)
+    return ck, cv, unembed(params, x)[:, 0]
+
+
 @functools.lru_cache(maxsize=64)
 def make_decode_step(cfg: TransformerConfig, *, page_size: int,
                      n_pages: int, impl: str, temperature: float = 0.0,
@@ -233,15 +253,9 @@ def make_decode_step(cfg: TransformerConfig, *, page_size: int,
         return jax.vmap(lambda lg, s: sampler(lg[None], s)[0])(logits, subs)
 
     def step(params, ck, cv, tokens, positions, tables, active, keys):
-        pos2 = positions[:, None]                             # [B, 1]
-        pages = jnp.take_along_axis(tables, pos2 // page_size, axis=1)
-        pages = jnp.where(active[:, None], pages, n_pages)    # idle: drop
-        offsets = pos2 % page_size
-        lengths = positions + 1
-        x = _embed_rows(params, tokens[:, None], pos2, cfg)
-        x, ck, cv = _layers_scan(params, ck, cv, x, pos2, pages, offsets,
-                                 tables, lengths, cfg, impl)
-        logits = unembed(params, x)[:, 0]                     # [B, V]
+        ck, cv, logits = decode_logits(
+            params, ck, cv, tokens, positions, tables, active, cfg,
+            page_size=page_size, n_pages=n_pages, impl=impl)
         return ck, cv, row_sample(logits, keys, positions)
 
     return jax.jit(step, donate_argnums=(1, 2))

@@ -382,8 +382,8 @@ class Checkpointer:
         read failure is ignored here: the restore attempt will surface it
         through the normal fallback machinery."""
         try:
-            meta = ocp.PyTreeCheckpointer().metadata(path)
-            saved = tree_shape_map(meta)
+            saved = tree_shape_map(ocp.PyTreeCheckpointer().metadata(
+                path).item_metadata.tree)
         except Exception:  # noqa: BLE001 - torn version, fallback handles it
             return
         want = tree_shape_map(target)
@@ -485,12 +485,7 @@ class Checkpointer:
         path = self._latest_path(name)
         if path is None:
             raise FileNotFoundError(self._path(name))
-        meta = self._ckpt.metadata(path)
-        # Newer orbax wraps the tree in .item_metadata.tree; this
-        # container's orbax returns the key->metadata mapping directly.
-        tree = getattr(getattr(meta, "item_metadata", None), "tree", None)
-        if tree is None:
-            tree = meta
+        tree = self._ckpt.metadata(path).item_metadata.tree
         missing = [k for k in target if k not in tree]
         if missing:
             raise KeyError(f"checkpoint {path} has no keys {missing}; "
@@ -503,19 +498,10 @@ class Checkpointer:
         # an 8-device mesh restored for single-device inference —
         # scripts/generate.py's whole use case).
         restore_args = ocp.checkpoint_utils.construct_restore_args(target)
-        try:
-            return ocp.PyTreeCheckpointer().restore(
-                path, args=ocp.args.PyTreeRestore(item=abstract,
-                                                  restore_args=restore_args,
-                                                  partial_restore=True))
-        except TypeError:
-            # Older orbax has no partial_restore kwarg; transforms={} is its
-            # spelling of the same thing (checkpoint keys absent from
-            # ``item`` are dropped instead of restored).
-            return ocp.PyTreeCheckpointer().restore(
-                path, args=ocp.args.PyTreeRestore(item=abstract,
-                                                  restore_args=restore_args,
-                                                  transforms={}))
+        return ocp.PyTreeCheckpointer().restore(
+            path, args=ocp.args.PyTreeRestore(item=abstract,
+                                              restore_args=restore_args,
+                                              partial_restore=True))
 
     def exists(self, name: str = "ckpt") -> bool:
         self.wait_until_finished()

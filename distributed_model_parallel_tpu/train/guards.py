@@ -80,8 +80,7 @@ def check_finite(tree: Any, *, name: str = "tree") -> None:
     if not flat:
         return
     # ONE device->host fetch for the whole tree: per-leaf device_get would
-    # pay one blocking round trip per leaf (hundreds for a real model, each
-    # a full tunnel RTT on remote-device transports).
+    # pay one blocking round trip per leaf (hundreds for a real model).
     host = jax.device_get([leaf for _path, leaf in flat])
     for (path, _leaf), arr in zip(flat, host):
         arr = np.asarray(arr)

@@ -66,7 +66,7 @@ def _filter_expected_batch_donation_warnings() -> None:
     warnings.filterwarnings(
         "ignore",
         message=r"Some donated buffers were not usable: "
-                r"(ShapedArray\((uint8|int32)[^)]*\)(, )?)+\.")
+                r"((uint8|int32)\[[\d,]*\](, )?)+\.")
 
 
 _filter_expected_batch_donation_warnings()

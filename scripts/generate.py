@@ -108,7 +108,15 @@ def main():
 
     from distributed_model_parallel_tpu.models import transformer as tfm
     from distributed_model_parallel_tpu.train.checkpoint import Checkpointer
+    from distributed_model_parallel_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+    from distributed_model_parallel_tpu.utils.device_contact import (
+        require_devices,
+    )
 
+    enable_compile_cache()
+    require_devices("generate")
     cfg = tfm.TransformerConfig(
         vocab_size=args.vocab, d_model=args.d_model, n_heads=args.heads,
         n_layers=args.layers, d_ff=args.d_ff,

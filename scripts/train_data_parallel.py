@@ -176,13 +176,16 @@ def main():
 
     flightrec.install_from_env()
     best_effort_distributed_init()
-    # First device contact, hardened (bench.py's bounded-retry pattern): a
-    # permanently unreachable backend becomes one parseable JSON record +
-    # exit 17, never a traceback (utils/device_contact.py).
+    # One attempt at the backend; anything but a TPU is refused unless
+    # JAX_PLATFORMS=cpu asked for it (utils/device_contact.py).
+    from distributed_model_parallel_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
     from distributed_model_parallel_tpu.utils.device_contact import (
         require_devices,
     )
 
+    enable_compile_cache()
     require_devices("train-data-parallel")
     import jax
 

@@ -123,12 +123,16 @@ def main():
     from distributed_model_parallel_tpu.utils import flightrec
 
     flightrec.install_from_env()
-    # First device contact, hardened (bench.py's bounded-retry pattern):
-    # an unreachable backend becomes one parseable JSON record + exit 17.
+    # One attempt at the backend; anything but a TPU is refused unless
+    # JAX_PLATFORMS=cpu asked for it (utils/device_contact.py).
+    from distributed_model_parallel_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
     from distributed_model_parallel_tpu.utils.device_contact import (
         require_devices,
     )
 
+    enable_compile_cache()
     require_devices("train-lm")
     from distributed_model_parallel_tpu.config import (
         MeshConfig,

@@ -90,12 +90,16 @@ def main():
     from distributed_model_parallel_tpu.utils import flightrec
 
     flightrec.install_from_env()
-    # First device contact, hardened (bench.py's bounded-retry pattern):
-    # an unreachable backend becomes one parseable JSON record + exit 17.
+    # One attempt at the backend; anything but a TPU is refused unless
+    # JAX_PLATFORMS=cpu asked for it (utils/device_contact.py).
+    from distributed_model_parallel_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
     from distributed_model_parallel_tpu.utils.device_contact import (
         require_devices,
     )
 
+    enable_compile_cache()
     require_devices("train-model-parallel")
     boundaries = (None if args.boundaries is None else
                   [int(x) for x in args.boundaries.split(",")])
@@ -137,7 +141,7 @@ def main():
                 "--engine spmd runs one stage per device; virtual stages "
                 "are a runner-engine schedule (interleaving only beats "
                 "GPipe under 1F1B ordering, and the SPMD 1F1B is "
-                "single-level — see docs/ROUND4.md)")
+                "single-level)")
         from distributed_model_parallel_tpu.train.trainer import Trainer
 
         Trainer(config).fit()

@@ -1,0 +1,127 @@
+"""The main path's Pallas kernels compile for the chip, asked of the
+chip's own compiler without the chip.
+
+libtpu compiles for a *described* ``v5e:2x2`` device here in the
+sandbox: what Mosaic or XLA:TPU would refuse on the chip (an unsupported
+matmul shape, a misaligned slice, too much VMEM) is refused here, at no
+chip time. Interpret-mode tests cannot see any of that. Nothing runs, so
+these say nothing about results — parity lives in the interpret-mode
+tests next to each kernel.
+
+The topology is described inside a module-scoped fixture: one process
+at a time may load the TPU library, and only the xdist worker that is
+handed this file may try. All compiles stay in this one file for the
+same reason.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from distributed_model_parallel_tpu.ops import (
+    paged_attention as pa,
+    pallas_attention as fa,
+    pallas_optim,
+)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot ask"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described device can be written to the persistent
+    # cache but never read back without a chip; keep it out of the way.
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("dtype,hkv,window", [
+    (jnp.bfloat16, 8, None),
+    (jnp.float32, 8, None),
+    (jnp.bfloat16, 2, None),
+    (jnp.float32, 2, None),
+    (jnp.bfloat16, 8, 512),
+], ids=["bf16", "f32", "bf16-gqa", "f32-gqa", "bf16-window"])
+def test_paged_decode_kernel_compiles(one_chip, dtype, hkv, window):
+    """The serving decode shape: 8 slots, 8 heads x 128, page 16, 2k
+    context."""
+    b, h, dh, page, t = 8, 8, 128, 16, 2048
+    n = t // page
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = sds((b * n, page, hkv, dh), dtype)
+    text = _compiled_text(
+        functools.partial(pa.paged_attention_kernel, window=window,
+                          interpret=False),
+        sds((b, 1, h, dh), dtype), pool, pool, sds((b, n), jnp.int32),
+        sds((b,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def _flash_args(one_chip, t):
+    # bench.py's LM shape: batch 2, 8 heads x 128, bf16.
+    x = jax.ShapeDtypeStruct((2, t, 8, 128), jnp.bfloat16, sharding=one_chip)
+    return x, x, x
+
+
+def _v5e_flash(q, k, v):
+    """flash_attention as dispatched on a v5e: the table's tiles, compiled."""
+    e = fa._DISPATCH_TABLE["TPU v5 lite"]
+    return fa.flash_attention(
+        q, k, v, causal=True, interpret=False,
+        block_q=e["block_q"], block_k=e["block_k"],
+        dq_blocks=(e["dq_block_q"], e["dq_block_k"]),
+        dkv_blocks=(e["dkv_block_q"], e["dkv_block_k"]))
+
+
+@pytest.mark.parametrize("t", [8192, 2048])
+def test_flash_forward_compiles(one_chip, t):
+    text = _compiled_text(_v5e_flash, *_flash_args(one_chip, t))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("t", [8192, 2048])
+def test_flash_forward_backward_compiles(one_chip, t):
+    def loss_grads(q, k, v):
+        return jax.grad(
+            lambda q, k, v: _v5e_flash(q, k, v).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    text = _compiled_text(loss_grads, *_flash_args(one_chip, t))
+    # forward + dq + dk/dv kernels
+    assert text.count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("momentum", [0.9, 0.0], ids=["momentum", "plain"])
+def test_fused_sgd_kernel_compiles(one_chip, momentum):
+    """One flat bucket the size of MobileNetV2's parameters (3.5 M f32)."""
+    flat = jax.ShapeDtypeStruct((3_500_000,), jnp.float32, sharding=one_chip)
+    lr = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    run = functools.partial(pallas_optim._run_kernel, momentum=momentum,
+                            weight_decay=5e-4, nesterov=False,
+                            interpret=False)
+    if momentum:
+        text = _compiled_text(run, lr, flat, flat, flat)
+    else:
+        text = _compiled_text(lambda lr, p, g: run(lr, p, None, g),
+                              lr, flat, flat)
+    assert "tpu_custom_call" in text

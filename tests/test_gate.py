@@ -38,19 +38,42 @@ def _write_stream(path, *, value=27000.0, step_time=0.019, mfu=0.083,
     return str(path)
 
 
+def _write_bench_artifacts(tmp_path) -> str:
+    """Five driver-shaped bench artifacts (``{"n","cmd","rc","tail",
+    "parsed"}``, pretty-printed as the driver writes them) under
+    ``tmp_path``: four green runs of the CNN cell and one rc-1 run with no
+    measurement. Returns their glob."""
+    cmd = "if [ -f bench.py ]; then python bench.py; else exit 0; fi"
+    for n, value in enumerate((27924.53, 26119.01, 27092.34, 26566.17), 1):
+        parsed = {"metric": CNN_METRIC, "value": value,
+                  "unit": "samples/s/chip"}
+        (tmp_path / f"BENCH_r0{n}.json").write_text(json.dumps(
+            {"n": n, "cmd": cmd, "rc": 0,
+             "tail": "[bench] devices: [TPU v5 lite0]\n"
+                     + json.dumps(parsed) + "\n",
+             "parsed": parsed}, indent=2))
+    (tmp_path / "BENCH_r05.json").write_text(json.dumps(
+        {"n": 5, "cmd": cmd, "rc": 1,
+         "tail": "RuntimeError: Unable to initialize backend 'tpu'\n",
+         "parsed": None}, indent=2))
+    return str(tmp_path / "BENCH_r0*.json")
+
+
 # ---------------------------------------------------------------------------
-# seeding from the checked-in artifacts
+# seeding from bench artifacts
 # ---------------------------------------------------------------------------
 
-def test_ingest_green_bench_artifact():
-    (e,) = baseline.ingest_artifact(str(REPO / "BENCH_r01.json"))
+def test_ingest_green_bench_artifact(tmp_path):
+    _write_bench_artifacts(tmp_path)
+    (e,) = baseline.ingest_artifact(str(tmp_path / "BENCH_r01.json"))
     assert e["green"] and e["metric"] == CNN_METRIC
     assert e["metrics"]["throughput"] == pytest.approx(27924.53)
     assert e["source"] == "BENCH_r01.json"
 
 
-def test_ingest_failed_artifact_is_not_green():
-    (e,) = baseline.ingest_artifact(str(REPO / "BENCH_r05.json"))
+def test_ingest_failed_artifact_is_not_green(tmp_path):
+    _write_bench_artifacts(tmp_path)
+    (e,) = baseline.ingest_artifact(str(tmp_path / "BENCH_r05.json"))
     assert not e["green"] and e["metrics"] == {}
 
 
@@ -75,8 +98,9 @@ def test_committed_ledger_seeded_from_artifacts():
 
 def test_seeding_is_idempotent(tmp_path):
     ledger = str(tmp_path / "ledger.jsonl")
-    n1 = dmp_gate.seed(ledger, [str(REPO / "BENCH_r0*.json")])
-    n2 = dmp_gate.seed(ledger, [str(REPO / "BENCH_r0*.json")])
+    artifacts = _write_bench_artifacts(tmp_path)
+    n1 = dmp_gate.seed(ledger, [artifacts])
+    n2 = dmp_gate.seed(ledger, [artifacts])
     assert n1 == 5 and n2 == 0
     assert len(baseline.load_ledger(ledger)) == 5
 
@@ -87,7 +111,7 @@ def test_seeding_is_idempotent(tmp_path):
 
 def test_gate_parity_passes_and_regression_fails(tmp_path, capsys):
     ledger = str(tmp_path / "ledger.jsonl")
-    dmp_gate.seed(ledger, [str(REPO / "BENCH_r0*.json"),
+    dmp_gate.seed(ledger, [_write_bench_artifacts(tmp_path),
                            str(REPO / "MULTICHIP_r0*.json")])
     # 1. parity run vs the seeded history: passes, --update records it
     #    (now the ledger also has step_time_p50_s history).
@@ -123,7 +147,8 @@ def test_artifact_vs_stream_sniffing(tmp_path):
         {"n": 9, "rc": 0, "parsed": {"metric": CNN_METRIC,
                                      "value": 27000.0, "unit": "x"}}))
     assert dmp_gate._is_artifact(str(compact))
-    pretty = REPO / "BENCH_r01.json"
+    artifacts = _write_bench_artifacts(tmp_path)
+    pretty = tmp_path / "BENCH_r01.json"
     assert dmp_gate._is_artifact(str(pretty))
     long_first = tmp_path / "long.jsonl"
     long_first.write_text(
@@ -133,7 +158,7 @@ def test_artifact_vs_stream_sniffing(tmp_path):
     assert not dmp_gate._is_artifact(str(long_first))
     # ...and the compact artifact actually gates
     ledger = str(tmp_path / "l.jsonl")
-    dmp_gate.seed(ledger, [str(REPO / "BENCH_r0*.json")])
+    dmp_gate.seed(ledger, [artifacts])
     assert dmp_gate.main([str(compact), "--ledger", ledger]) == 0
 
 

@@ -110,7 +110,7 @@ def test_stall_detector():
 
 
 # ---------------------------------------------------------------------------
-# integration: the trainers actually run the guards (VERDICT r2 item 5)
+# integration: the trainers actually run the guards
 # ---------------------------------------------------------------------------
 
 def _poison(tree):

@@ -1,11 +1,15 @@
 """Paged-attention parity: the serving cache's read path vs the dense
-cache, bitwise.
+cache.
 
-The contract (docs/SERVING.md): the XLA gather path and the Pallas
-kernel (interpreter) produce BITWISE the dense-cache result — paging is
-an indirection, never a numeric change — and stale page contents are
-unreachable (masked to exact zeros), so a request's values cannot depend
-on who held its pages before."""
+The contract (docs/SERVING.md): the XLA gather path produces BITWISE the
+dense-cache result — paging is an indirection, never a numeric change.
+The Pallas kernel streams the row's pages through an online softmax, so
+it sums in another order and agrees to rounding: float32 within a few
+ulp of the row, bfloat16 within one bf16 ulp of each output (exact
+greedy-token identity between the two is pinned at engine level,
+tests/test_serve.py and tests/test_prefix_cache.py). Stale page contents
+are unreachable on both paths (masked to exact zeros), so a request's
+values cannot depend on who held its pages before."""
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +49,22 @@ def _dense(q, kp, vp, tables, positions, window=None):
                           window)
 
 
+def _assert_rounding_close(out, ref):
+    """The kernel-vs-dense tolerance, set from the dtype: float32 within
+    4 ulp of the row's largest output (the row = one head's [Dh] vector),
+    bfloat16 within one bf16 ulp of each output."""
+    assert out.dtype == ref.dtype and out.shape == ref.shape
+    o = np.asarray(out, np.float32)
+    r = np.asarray(ref, np.float32)
+    if ref.dtype == jnp.bfloat16:
+        tol = 2.0 ** (np.floor(np.log2(np.maximum(
+            np.abs(r), np.finfo(np.float32).tiny))) - 7)
+    else:
+        tol = 4 * np.finfo(np.float32).eps * np.abs(r).max(
+            axis=-1, keepdims=True)
+    assert (np.abs(o - r) <= tol).all(), float(np.abs(o - r).max())
+
+
 def test_xla_gather_matches_dense_bitwise():
     q, kp, vp, tables, positions = _case()
     out = pa.paged_attention_xla(q, kp, vp, tables, positions[:, None],
@@ -56,14 +76,15 @@ def test_kernel_interpret_matches_dense_bitwise():
     q, kp, vp, tables, positions = _case()
     out = pa.paged_attention_kernel(q, kp, vp, tables, positions,
                                     interpret=True)
-    assert (out == _dense(q, kp, vp, tables, positions)).all()
+    _assert_rounding_close(out, _dense(q, kp, vp, tables, positions))
 
 
 def test_kernel_windowed_matches_dense_bitwise():
     q, kp, vp, tables, positions = _case()
     out = pa.paged_attention_kernel(q, kp, vp, tables, positions,
                                     window=8, interpret=True)
-    assert (out == _dense(q, kp, vp, tables, positions, window=8)).all()
+    _assert_rounding_close(out,
+                           _dense(q, kp, vp, tables, positions, window=8))
 
 
 def test_kernel_gqa_grouping_matches_dense():
@@ -72,7 +93,7 @@ def test_kernel_gqa_grouping_matches_dense():
     q, kp, vp, tables, positions = _case(h=8)
     out = pa.paged_attention_kernel(q, kp, vp, tables, positions,
                                     interpret=True)
-    assert (out == _dense(q, kp, vp, tables, positions)).all()
+    _assert_rounding_close(out, _dense(q, kp, vp, tables, positions))
 
 
 def test_stale_page_contents_unreachable():
@@ -99,15 +120,22 @@ def test_stale_page_contents_unreachable():
     out = pa.paged_attention_xla(q, jnp.asarray(kn), jnp.asarray(vn),
                                  tables, positions[:, None], positions + 1)
     assert (out == ref).all()
+    clean = pa.paged_attention_kernel(q, kp, vp, tables, positions,
+                                      interpret=True)
     outk = pa.paged_attention_kernel(q, jnp.asarray(kn), jnp.asarray(vn),
                                      tables, positions, interpret=True)
-    assert (outk == ref).all()
+    assert (outk == clean).all()
+    _assert_rounding_close(outk, ref)
 
 
 def test_prefill_chunk_matches_whole_prompt():
-    """A C-token chunk read of the paged cache scores exactly what the
-    same positions score in a single whole-prompt pass (intra-chunk
-    causality comes from the shared band mask)."""
+    """A C-token chunk read of the paged cache scores what the same
+    positions score in a single whole-prompt pass (intra-chunk causality
+    comes from the shared band mask). XLA's CPU dot picks its blocking
+    from the operand shapes, so an 8-row chunk and the 24-row prompt
+    contract in different orders: equal to float32 rounding, not bitwise.
+    What serving needs from it — chunking changes no greedy token — is
+    asserted on the engine below."""
     kp, vp = _pool(3)
     table = jnp.asarray([[5, 2, 11, 4]], jnp.int32)
     t0 = 24
@@ -121,7 +149,25 @@ def test_prefill_chunk_matches_whole_prompt():
             (lo + jnp.arange(chunk))[None], jnp.asarray([lo + chunk]))
         for lo in range(0, t0, chunk)
     ]
-    assert (jnp.concatenate(parts, axis=1) == whole).all()
+    _assert_rounding_close(jnp.concatenate(parts, axis=1), whole)
+
+    from distributed_model_parallel_tpu.models import transformer as tfm
+    from distributed_model_parallel_tpu.serve import Engine, ServeConfig
+
+    cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=4,
+                                n_layers=2, d_ff=64, max_seq_len=128,
+                                pos_embedding="rope")
+    params = tfm.init_params(jax.random.key(0), cfg)
+    prompt = list(range(1, t0 + 1))
+    tokens = []
+    for prefill_chunk in (chunk, 32):
+        eng = Engine(params, cfg, ServeConfig(
+            n_slots=2, page_size=8, n_pages=32, max_seq_len=64,
+            prefill_chunk=prefill_chunk))
+        req = eng.submit(prompt, 16)
+        eng.run()
+        tokens.append(req.generated)
+    assert tokens[0] == tokens[1]
 
 
 def test_dispatch_rejects_unknown_impl_and_multi_token_kernel():
@@ -145,5 +191,4 @@ def test_bfloat16_kernel_parity():
     k = pa.paged_attention_kernel(q, kp, vp, tables, positions,
                                   interpret=True)
     assert x.dtype == jnp.bfloat16
-    assert (jnp.asarray(x, jnp.float32) == jnp.asarray(k,
-                                                       jnp.float32)).all()
+    _assert_rounding_close(k, x)

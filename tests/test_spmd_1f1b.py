@@ -136,7 +136,7 @@ def _parity_interleaved(mesh_kw, cfg_kw, M, V, tol=2e-5):
 
 def test_1f1b_interleaved_v2():
     # 4 layers over S=2 x V=2 = 4 chunks; M=4 (M % S == 0). The last
-    # single-controller-only capability (VERDICT r4 weak #5): two-level
+    # single-controller-only capability: two-level
     # chunk scheduling with the wraparound (S-1)->0 hop riding the same
     # modular ppermute ring.
     _parity_interleaved(dict(data=1, stage=2), {}, M=4, V=2)

@@ -288,8 +288,8 @@ def test_dp_bn_stat_pooling_matches_big_batch():
 
 
 def test_1f1b_interleaved_matches_gpipe_and_runner(batch):
-    """Interleaved virtual stages (V=2) in the SPMD CNN 1F1B engine
-    (VERDICT r4 weak #5): leaf-for-leaf parity against BOTH the SPMD
+    """Interleaved virtual stages (V=2) in the SPMD CNN 1F1B engine:
+    leaf-for-leaf parity against BOTH the SPMD
     GPipe step and the single-controller PipelineRunner's interleaved
     placement (virtual_stages=2, 1f1b dispatch order) — numerics are
     V-invariant, so all three must agree on params, BN stats, and loss."""

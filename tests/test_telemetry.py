@@ -8,7 +8,6 @@ ONE parseable JSON failure record on stdout — no traceback.
 """
 
 import importlib.util
-import json
 import os
 import subprocess
 import sys
@@ -431,26 +430,20 @@ def test_lm_trainer_stream_has_tokens_and_flops(tmp_path):
 # bench.py failure contract
 # ---------------------------------------------------------------------------
 
-def test_bench_unreachable_backend_emits_json_failure_record():
+def test_bench_unreachable_backend_exits_nonzero():
     # "cuda" fails fast in this image (no GPU plugin) while exercising the
     # exact unreachable-backend path; JAX_PLATFORMS=tpu also lands here but
     # libtpu's own metadata retries make it minutes-slow.
-    env = dict(os.environ,
-               JAX_PLATFORMS="cuda",
-               DMP_BENCH_RETRIES="2",
-               DMP_BENCH_RETRY_DELAY_S="0.05")
+    env = dict(os.environ, JAX_PLATFORMS="cuda")
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py")],
         capture_output=True, text=True, timeout=300, env=env, cwd=REPO)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    lines = [ln for ln in proc.stdout.strip().splitlines() if ln.strip()]
-    assert len(lines) == 1, f"expected ONE json record, got: {proc.stdout!r}"
-    rec = json.loads(lines[0])
-    assert rec["error"] == "tpu-unreachable"
-    assert rec["attempts"] == 2
-    assert rec["value"] is None
-    assert "Traceback" not in proc.stdout
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == "", proc.stdout   # no result, no record
+    assert "[bench] no usable accelerator" in proc.stderr
+    assert "cuda" in proc.stderr
+    assert "Traceback" not in proc.stderr
 
 
 # ---------------------------------------------------------------------------

@@ -541,7 +541,7 @@ def test_ring_flash_grads_match_full():
 def test_ring_bf16_accumulates_f32():
     """bf16 inputs must get f32 online-softmax accumulation in the ring —
     parity with the single-device path at f32-class tolerance, much tighter
-    than bf16 accumulation drift (VERDICT r2 weak item 4)."""
+    than bf16 accumulation drift."""
     spec = make_mesh(MeshConfig(data=1, seq=8))
     q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(seed=4, t=64))
     ref = full_attention(q.astype(jnp.float32), k.astype(jnp.float32),

@@ -7,7 +7,7 @@ zero-valued stat presence, fusion classification from HLO text — on
 hand-built protos, so a regression fails fast on CPU. The proto-building
 tests skip when tensorflow is absent (module-scoped ``tf_pb2`` fixture);
 the graceful-degradation tests run REGARDLESS — they pin exactly the
-no-tensorflow behavior (VERDICT next #8).
+no-tensorflow behavior.
 """
 
 import pytest
