@@ -20,15 +20,19 @@ This module is the attention read over that pool. Three tiers:
   on every backend (decode off-TPU, the prefill path, and the
   speculative-decoding verify step — its ``width``-token windows ride
   the same per-row-position support prefill chunks use);
-* :func:`paged_attention_kernel` — the Pallas TPU kernel: the page table
-  rides in scalar-prefetch SMEM and feeds the K/V block index maps, so
-  pages stream HBM→VMEM directly (``pl.when`` skips the DMA + compute
-  for logical pages outside the row's band) and the gathered
-  ``[B, T, ...]`` intermediate never exists in HBM. Each page updates an
-  online-softmax carry in VMEM, so the kernel agrees with
-  :func:`attend_rows` to rounding, not bitwise (the tolerance
-  tests/test_paged_attention.py states; exact greedy tokens are pinned
-  at engine level).
+* :func:`paged_attention_kernel` — the Pallas TPU kernel: one grid step
+  a row, the pools left whole in HBM, and inside the step a loop over the
+  row's **live** blocks of pages (from the band's first page to
+  ``pos // page``), so its work follows the context each row has and not
+  the context it may reach. The page table rides in scalar-prefetch SMEM
+  and names the pages the kernel copies itself, one DMA a page, into a
+  double-buffered VMEM block (the next block's copies are in flight
+  while this one is computed); the gathered ``[B, T, ...]`` intermediate
+  never exists in HBM. Each block updates an online-softmax carry in
+  VMEM, so VMEM use is two blocks plus the carry whatever the context,
+  and the kernel agrees with :func:`attend_rows` to rounding, not bitwise
+  (the tolerance tests/test_paged_attention.py states; exact greedy
+  tokens are pinned at engine level).
 
 Masking is sanitizing, not just causal: positions past a row's length are
 zeroed in K/V *and* banded out of the scores, so stale page contents
@@ -116,66 +120,119 @@ def paged_attention_xla(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
 # Pallas kernel (decode: one query token per row)
 # ---------------------------------------------------------------------------
 
-def _paged_decode_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_scr, l_scr, acc_scr, *, page: int, hkv: int,
-                         dh: int, window: int | None):
-    """Grid: (B, n_pages). Scalar prefetch: tables [B, N], pos [B]. Blocks:
-    q/o [1, H, Dh]; k/v [1, page, Hkv, Dh] (one of the row's pages per
-    minor step — the index map reads the page table and clamps
-    out-of-band steps onto an already-resident page so Mosaic elides the
-    repeat DMA). Scratch: m/l [H, 128] f32 (sublane-major, lanes
-    redundant) and acc [H, Dh] f32 — the online-softmax carry across the
-    row's pages (the flash forward's idiom, ops/pallas_attention.py), so
-    fast-memory use is one page plus the carry whatever the context.
+def _pages_per_block(page: int, hkv: int, dh: int, n: int) -> int:
+    """Pages one loop turn of the decode kernel handles, from the shapes
+    alone: a block is 512 rows of the pool's ``[P, page * Hkv, Dh]`` view
+    while a head is at most 128 wide (256 keys at two KV heads: 128 KB of
+    bf16 for K, as much for V), fewer rows for wider heads; at least one
+    page, never more than a row's table holds."""
+    rows = min(512, max(256, 65536 // dh))
+    return max(1, min(n, rows // (page * hkv)))
 
-    ``pl.when`` skips pages past ``pos // page`` (and, windowed, pages
-    wholly left of the band). Inside a processed page every position the
-    band excludes — past ``pos``, or left of the window — is zeroed in
-    K/V before the dots and banded out of the scores, so stale page
-    contents reach no reduction (module docstring). Each KV head is two
-    plain 2-D dots: its ``G = H // Hkv`` query rows against the head's
-    [page, Dh] rows of the block.
+
+def _paged_decode_kernel(tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         k_buf, v_buf, sems, m_scr, l_scr, acc_scr, *,
+                         page: int, ppb: int, hkv: int, dh: int,
+                         window: int | None):
+    """Grid: (B,), one step a row. Scalar prefetch: tables [B, N], pos
+    [B]. q/o blocks [1, H, Dh]. The K/V pools stay whole in HBM, seen as
+    [P, page * Hkv, Dh]: row ``r`` of a page is key ``r // Hkv`` of KV
+    head ``r % Hkv`` (the pool's own memory order, so a page is one
+    contiguous copy that serves every KV head). Scratch: k_buf/v_buf
+    [2, ppb * page * Hkv, Dh] (two blocks of ``ppb`` pages), DMA
+    semaphores [2 (K, V), 2 (buffer)], and the online-softmax carry m/l
+    [H, 128] f32 (sublane-major, lanes redundant) and acc [H, Dh] f32
+    (the flash forward's idiom, ops/pallas_attention.py): fast-memory use
+    is two blocks plus the carry whatever the context.
+
+    The row's work follows its live context: a loop from the block that
+    holds ``_first_page`` to the block that holds ``pos // page`` (one
+    block for an idle row at ``pos == 0``). A turn starts the page copies
+    of the next block into the other buffer (one DMA a page and pool,
+    page ids from the table in SMEM), waits for its own, and makes one
+    online-softmax update over the block. Pages of a block outside the
+    row's band of pages are not copied; the buffer holds there whatever
+    an earlier block left.
+
+    Heads are not taken apart: a block's scores are all H query heads
+    against all of its rows, [H, ppb * page * Hkv], two plain 2-D dots a
+    block, and a column counts for a query head only if it is a key of
+    that head's KV head (``head_ok``) inside the row's band. Reading one
+    KV head's rows out of the interleaved block costs four times the
+    whole update (PERF.md section 6, PR 27). Every position the band
+    excludes — past ``pos``, or left of the window — is zeroed in K/V
+    before the dots and banded out of the scores, so neither stale pool
+    pages nor stale buffer rows reach a reduction (module docstring); a
+    column of another KV head weighs exactly 0.0 against finite values.
     """
     b = pl.program_id(0)
-    j = pl.program_id(1)
     pos = pos_ref[b]
-    g = q_ref.shape[1] // hkv
+    h = q_ref.shape[1]
+    page_rows = page * hkv
+    bk, br = ppb * page, ppb * page_rows
+    first = _first_page(pos, page, window)
+    last = pos // page
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
-        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
-        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+    def block_copies(blk, slot, start: bool):
+        for i in range(ppb):
+            j = blk * ppb + i
 
-    @pl.when(jnp.logical_and(j >= _first_page(pos, page, window),
-                             j <= pos // page))
-    def _step():
-        # The band predicate in both orientations: along lanes for the
-        # [G, page] scores, along sublanes for the [page, Dh] K/V rows.
-        keep_s = band_keep(pos, j * page + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page), 1), window)
-        keep_kv = band_keep(pos, j * page + jax.lax.broadcasted_iota(
-            jnp.int32, (page, 1), 0), window)
-        q = q_ref[0]                                       # [H, Dh]
-        for h in range(hkv):
-            rows = slice(h * g, (h + 1) * g)
-            k = jnp.where(keep_kv, k_ref[0, :, h, :], 0)   # [page, Dh]
-            v = jnp.where(keep_kv, v_ref[0, :, h, :], 0)
-            s = jnp.dot(q[rows], k.T,
-                        preferred_element_type=jnp.float32) * (dh ** -0.5)
-            s = jnp.where(keep_s, s, NEG_INF)              # [G, page]
-            m = m_scr[rows]                                # [G, LW]
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1)[:, None])
-            p = jnp.where(keep_s, jnp.exp(s - m_new[:, :1]), 0.0)
-            alpha = jnp.exp(m - m_new)
-            l_scr[rows] = alpha * l_scr[rows] + jnp.sum(p, axis=-1)[:, None]
-            acc_scr[rows] = alpha[:, :1] * acc_scr[rows] + jnp.dot(
-                p, v.astype(jnp.float32), preferred_element_type=jnp.float32)
-            m_scr[rows] = m_new
+            @pl.when(jnp.logical_and(j >= first, j <= last))
+            def _page():
+                # A wait needs the semaphore and the copy's size only.
+                pid = tables_ref[b, j] if start else 0
+                rows = pl.ds(i * page_rows, page_rows)
+                for hbm, buf, sem in ((k_hbm, k_buf, sems.at[0, slot]),
+                                      (v_hbm, v_buf, sems.at[1, slot])):
+                    dma = pltpu.make_async_copy(
+                        hbm.at[pid], buf.at[slot, rows], sem)
+                    if start:
+                        dma.start()
+                    else:
+                        dma.wait()
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finalize():
-        o_ref[0] = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
+    m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+    acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+    first_blk = first // ppb
+    last_blk = last // ppb
+    block_copies(first_blk, first_blk % 2, start=True)
+    # Row r of a block, column r of its scores: key r // Hkv, KV head
+    # r % Hkv. Along lanes for the scores, along sublanes for K/V.
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, br), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (br, 1), 0)
+    head_ok = (jax.lax.broadcasted_iota(jnp.int32, (h, 1), 0) // (h // hkv)
+               == col % hkv)                               # [H, br]
+
+    def block(blk, carry):
+        slot = blk % 2
+
+        @pl.when(blk < last_blk)
+        def _prefetch():
+            block_copies(blk + 1, 1 - slot, start=True)
+
+        block_copies(blk, slot, start=False)
+        keep_s = jnp.logical_and(
+            head_ok, band_keep(pos, blk * bk + col // hkv, window))
+        keep_kv = band_keep(pos, blk * bk + row // hkv, window)
+        k = jnp.where(keep_kv, k_buf[slot], 0)             # [br, Dh]
+        v = jnp.where(keep_kv, v_buf[slot], 0)
+        s = jax.lax.dot_general(
+            q_ref[0], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * (dh ** -0.5)
+        s = jnp.where(keep_s, s, NEG_INF)                  # [H, br]
+        m = m_scr[...]                                     # [H, LW]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1)[:, None])
+        p = jnp.where(keep_s, jnp.exp(s - m_new[:, :1]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1)[:, None]
+        acc_scr[...] = alpha[:, :1] * acc_scr[...] + jnp.dot(
+            p, v.astype(jnp.float32), preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
+        return carry
+
+    jax.lax.fori_loop(first_blk, last_blk + 1, block, None)
+    o_ref[0] = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
 
 
 def _first_page(pos, page: int, window: int | None):
@@ -207,44 +264,43 @@ def paged_attention_kernel(q: jax.Array, k_pool: jax.Array,
         interpret = jax.devices()[0].platform != "tpu"
     b, _, h, dh = q.shape
     _, page, hkv, _ = k_pool.shape
-    n = tables.shape[1]
+    ppb = _pages_per_block(page, hkv, dh, tables.shape[1])
 
-    def page_map(bi, j, tables_ref, pos_ref):
-        # Clamp into the row's band of pages: out-of-band steps re-fetch
-        # an already-resident block (DMA elided) and pl.when skips them.
-        pos = pos_ref[bi]
-        j = jnp.clip(j, _first_page(pos, page, window), pos // page)
-        return (tables_ref[bi, j], 0, 0, 0)
-
-    def q_map(bi, j, tables_ref, pos_ref):
+    def q_map(bi, tables_ref, pos_ref):
         return (bi, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, n),
+        grid=(b,),
         in_specs=[
             pl.BlockSpec((1, h, dh), q_map),
-            pl.BlockSpec((1, page, hkv, dh), page_map),
-            pl.BlockSpec((1, page, hkv, dh), page_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, h, dh), q_map),
         scratch_shapes=[
+            pltpu.VMEM((2, ppb * page * hkv, dh), k_pool.dtype),
+            pltpu.VMEM((2, ppb * page * hkv, dh), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((h, _LANE_W), jnp.float32),
             pltpu.VMEM((h, _LANE_W), jnp.float32),
             pltpu.VMEM((h, dh), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _paged_decode_kernel, page=page, hkv=hkv, dh=dh, window=window)
+        _paged_decode_kernel, page=page, ppb=ppb, hkv=hkv, dh=dh,
+        window=window)
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, dh), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel",)),
         interpret=interpret,
         name="paged_decode_attention",
     )(tables.astype(jnp.int32), positions.astype(jnp.int32), q[:, 0],
-      k_pool, v_pool)
+      # a view, not a copy: the pool's rows in its own memory order
+      k_pool.reshape(-1, page * hkv, dh),
+      v_pool.reshape(-1, page * hkv, dh))
     return out[:, None]
 
 

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from distributed_model_parallel_tpu.ops import paged_attention as pa
 
@@ -126,6 +127,63 @@ def test_stale_page_contents_unreachable():
                                      tables, positions, interpret=True)
     assert (outk == clean).all()
     _assert_rounding_close(outk, ref)
+
+
+# The kernel's loop runs over blocks of several pages, from the block of
+# the band's first page to the block of pos // page. Positions below are
+# in units the code derives (bk = keys a block), so the edges stay edges
+# if the block size changes. Each case: (positions, window) as functions
+# of (bk, page, n_pages).
+_BLOCK_EDGES = {
+    "idle-row-context-1": lambda bk, page, n: ([0], None),
+    "ends-on-a-blocks-last-key":
+        lambda bk, page, n: ([bk - 1, 2 * bk - 1], None),
+    "ends-on-a-blocks-first-key":
+        lambda bk, page, n: ([bk, 2 * bk], None),
+    "ends-mid-page":
+        lambda bk, page, n: ([bk + page + page // 2 - 1], None),
+    "rows-of-1-2-and-max-blocks":
+        lambda bk, page, n: ([page // 2, bk + 1, n * page - 1], None),
+    "window-first-page-not-block-aligned":
+        lambda bk, page, n: ([2 * bk + 5 * page + 2, bk + 3, 2],
+                             bk + 2 * page),
+    "unused-table-entries-name-a-nan-page":
+        lambda bk, page, n: ([0, page - 1, bk + 1, 2 * bk - 1], None),
+}
+
+
+@pytest.mark.parametrize("edge", list(_BLOCK_EDGES))
+def test_kernel_block_loop_edges(edge):
+    """The block loop's bounds, against the dense reference. Every row's
+    table holds its live pages and, outside them, the id of a page that
+    is NaN throughout (id 0), and the interpreter hands the kernel NaN
+    for scratch it has not written: a page the loop copied and the band
+    did not mask, or a buffer row the loop left stale and the band did
+    not mask, turns the output NaN."""
+    page, hkv, h, dh, blocks = 8, 2, 4, 16, 3
+    ppb = pa._pages_per_block(page, hkv, dh, 1 << 30)
+    n, bk = blocks * ppb, ppb * page
+    positions, window = _BLOCK_EDGES[edge](bk, page, n)
+    b = len(positions)
+    kp, vp = _pool(17, p=1 + b * n, page=page, hkv=hkv, dh=dh)
+    # Non-negative V: over hundreds of keys a signed V averages out to a
+    # tenth of its terms, and 4 ulp of that output lies under the rounding
+    # of either side's sums. Without cancellation the tolerance holds as
+    # it stands.
+    kp, vp = kp.at[0].set(jnp.nan), jnp.abs(vp).at[0].set(jnp.nan)
+    ids = 1 + np.random.default_rng(3).permutation(b * n).reshape(b, n)
+    live = np.asarray([[pa._first_page(p, page, window) <= j <= p // page
+                        for j in range(n)] for p in positions])
+    tables = jnp.asarray(np.where(live, ids, 0), jnp.int32)
+    positions = jnp.asarray(positions, jnp.int32)
+    q = jax.random.normal(jax.random.key(23), (b, 1, h, dh))
+    out = pa.paged_attention_kernel(
+        q, kp, vp, tables, positions, window=window,
+        interpret=pltpu.InterpretParams(uninitialized_memory="nan"))
+    ref = _dense(q, kp, vp, jnp.asarray(ids, jnp.int32), positions,
+                 window=window)
+    assert np.isfinite(np.asarray(ref)).all()
+    _assert_rounding_close(out, ref)
 
 
 def test_prefill_chunk_matches_whole_prompt():
