@@ -62,6 +62,9 @@ FULL = dict(
             seq=8192, batch=2, steps=4, parity_seq=2048),
     serve=dict(n_slots=8, page=16, max_seq=2048, chunk=128, n_requests=12,
                prompt=(64, 1500), new=(32, 128), shared_prefix=256),
+    routed=dict(vocab=8192, d_model=512, heads=8, kv_heads=2, head_dim=128,
+                d_ff=1024, d_expert=256, experts=16, held=(4, 4), top_k=4,
+                window=128),
     multi=dict(lm_seq=2048, lm_batch=16, loss_chunk=512),
 )
 TINY = dict(
@@ -70,6 +73,9 @@ TINY = dict(
             seq=256, batch=2, steps=4, parity_seq=128),
     serve=dict(n_slots=4, page=16, max_seq=128, chunk=16, n_requests=6,
                prompt=(8, 80), new=(8, 24), shared_prefix=32),
+    routed=dict(vocab=256, d_model=64, heads=4, kv_heads=2, head_dim=32,
+                d_ff=128, d_expert=32, experts=16, held=(4, 4), top_k=4,
+                window=16),
     multi=dict(lm_seq=128, lm_batch=16, loss_chunk=0),
 )
 
@@ -476,12 +482,12 @@ def phase_serve(size: dict, model, params, on_tpu: bool, seed: int) -> None:
     b = size["n_slots"]
     if on_tpu:
         check(has_custom_call(
-            make_decode_step(cfg, page_size=size["page"],
-                             n_pages=geometry["n_pages"], impl=kernel),
-            params, eng.cache.ck, eng.cache.cv, jnp.zeros(b, jnp.int32),
+            make_decode_step(cfg, page_size=size["page"], impl=kernel),
+            params, eng.cache.pools, None, jnp.zeros(b, jnp.int32),
             jnp.zeros(b, jnp.int32),
-            jnp.zeros((b, pages_per_seq), jnp.int32), jnp.zeros(b, bool),
-            None), 'attn_impl="auto" decode step lowers to the Pallas '
+            (jnp.zeros((b, pages_per_seq), jnp.int32), None),
+            jnp.zeros(b, bool), None),
+              'attn_impl="auto" decode step lowers to the Pallas '
                    'paged-decode kernel')
     del eng
 
@@ -489,10 +495,9 @@ def phase_serve(size: dict, model, params, on_tpu: bool, seed: int) -> None:
     logits = {}
     for impl in (kernel, "xla"):
         fn = jax.jit(lambda p, ck, cv, tok, pos, tab, act, impl=impl:
-                     decode_logits(p, ck, cv, tok, pos, tab, act, cfg,
-                                   page_size=size["page"],
-                                   n_pages=geometry["n_pages"],
-                                   impl=impl)[2])
+                     decode_logits(p, (ck, cv, None, None), None, tok, pos,
+                                   (tab, None), act, cfg,
+                                   page_size=size["page"], impl=impl)[2])
         out = fn(params, snap["ck"], snap["cv"], snap["tokens"],
                  snap["positions"], snap["tables"], snap["active"])
         logits[impl] = np.asarray(out, np.float32)[snap["active"]]
@@ -537,6 +542,64 @@ def phase_serve(size: dict, model, params, on_tpu: bool, seed: int) -> None:
           "f32 engine: prefix cache + speculation change no token")
 
 
+def phase_serve_routed(size: dict, block: dict, on_tpu: bool,
+                       seed: int) -> None:
+    """The gated, routed, mixed-attention block through the engine: a
+    dense layer and one period of sliding, sliding, full, sliding; heads
+    wider than the hidden size; RMSNorm, QK-norm, a SiLU-gated FFN;
+    sigmoid-routed dropless experts of which a quarter are held, beside a
+    shared one; sliding layers in rings, the full layer in the pool."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_model_parallel_tpu.models import transformer as tfm
+    from distributed_model_parallel_tpu.serve import ServeConfig
+
+    kernel = "auto" if on_tpu else "pallas"
+    s = tfm.LayerKind(window=block["window"], rope=True, ffn="moe")
+    kinds = (dataclasses.replace(s, ffn="dense"), s, s,
+             dataclasses.replace(s, window=None, rope=False), s)
+    cfg = tfm.TransformerConfig(
+        vocab_size=block["vocab"], d_model=block["d_model"],
+        n_heads=block["heads"], n_kv_heads=block["kv_heads"],
+        d_head=block["head_dim"], n_layers=len(kinds), d_ff=block["d_ff"],
+        max_seq_len=size["max_seq"], dtype=jnp.float32,
+        pos_embedding="rope", norm="rmsnorm", ffn="swiglu", qk_norm=True,
+        layer_kinds=kinds, moe_experts=block["experts"],
+        moe_top_k=block["top_k"], moe_dropless=True, moe_scoring="sigmoid",
+        moe_routed_scale=2.5, moe_router_bias=True,
+        moe_d_ff=block["d_expert"], moe_shared_experts=1,
+        moe_experts_held=block["held"])
+    params = tfm.init_params(jax.random.key(seed), cfg)
+    pages_per_seq = -(-size["max_seq"] // size["page"])
+    geometry = dict(n_slots=size["n_slots"], page_size=size["page"],
+                    n_pages=(size["n_slots"] + 1) * pages_per_seq,
+                    max_seq_len=size["max_seq"], prefill_chunk=size["chunk"])
+    requests = make_requests(size, cfg.vocab_size, seed)
+    with jax.default_matmul_precision("highest"):
+        runs = {}
+        for name, impl in (("kernel", kernel), ("xla", "xla")):
+            runs[name], eng, _ = run_engine(
+                params, cfg, ServeConfig(attn_impl=impl, **geometry),
+                requests)
+            lay = eng.cache.layout
+            check(lay.n_ring == 4 and lay.n_full == 1
+                  and lay.ring_pages < pages_per_seq,
+                  f"sliding layers keep rings of {lay.ring_pages} pages, "
+                  f"the full layer {pages_per_seq} a sequence")
+            check(eng.cache.ring_pool.free_pages == eng.cache.ring_pool.n_pages
+                  and eng.cache.pool.free_pages == eng.cache.pool.n_pages,
+                  "rings and page pool back to empty")
+            counters = eng.moe_counters()
+            check(sorted(counters) == [1, 2, 3, 4] and all(
+                0 < c["held_assignments"] < block["top_k"]
+                * c["tokens_routed"] for c in counters.values()),
+                "every routed layer counted tokens on its held experts")
+            del eng
+    check(runs["kernel"] == runs["xla"],
+          "f32 routed engine: greedy tokens identical, kernel vs xla")
+
+
 def run_single(sizes: dict, workdir: str, meter: CompileMeter, dev,
                seed: int) -> None:
     on_tpu = dev.platform == "tpu"
@@ -546,6 +609,8 @@ def run_single(sizes: dict, workdir: str, meter: CompileMeter, dev,
         model, params = phase_lm(sizes["lm"], workdir, on_tpu)
     with phase("serve", meter):
         phase_serve(sizes["serve"], model, params, on_tpu, seed)
+    with phase("serve-routed", meter):
+        phase_serve_routed(sizes["serve"], sizes["routed"], on_tpu, seed)
 
 
 # ---------------------------------------------------------------------------

@@ -16,7 +16,14 @@ control of array layout:
   default, ring attention inside a ``seq`` shard_map.
 
 Pre-LN, GELU MLP, learned positional embeddings, weight-tied LM head kept
-separate (simplicity > tying).
+separate (simplicity > tying) is the default block. Other blocks are read
+from ``TransformerConfig`` fields, never from a model's name: the norm
+(``norm``), the dense FFN (``ffn``), a head size of its own (``d_head``),
+QK-norm, and one ``LayerKind`` a layer where layers differ (window or none,
+rotated or not, dense or routed FFN: ``layer_kinds``, arranged by
+``layer_plan`` as leading layers and a scanned period). ``block_apply``
+and the serving engine's paged steps (serve/model.py) take every kind;
+``generate`` and its dense cache keep to the default block.
 """
 
 from __future__ import annotations
@@ -32,6 +39,17 @@ from distributed_model_parallel_tpu.ops.ring_attention import (
     full_attention,
     ring_attention,
 )
+
+@dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """What one layer is, beyond the widths all layers share: its
+    attention (``window`` keys back, None = causal over everything;
+    ``rope`` = rotate q and k) and its FFN (``"dense"`` or ``"moe"``)."""
+
+    window: int | None = None
+    rope: bool = False
+    ffn: str = "dense"
+
 
 # Length of the MoE stats vector every block's aux channel carries:
 # [load-balance loss, router z-loss, drop rate] (ops/moe._route). Dense
@@ -114,8 +132,45 @@ class TransformerConfig:
     # lever on the head side (the head, not attention, is the single-chip
     # HBM ceiling past ~32k tokens). 0 = dense head.
     loss_chunk: int = 0
+    # -- the block, from fields (defaults: the Pre-LN LayerNorm GELU block) --
+    # Head size where it is not d_model // n_heads (q is then
+    # [d_model, n_heads * d_head] and wo [n_heads * d_head, d_model]).
+    d_head: int | None = None
+    norm: str = "layernorm"        # "layernorm" | "rmsnorm" (f32, no bias)
+    norm_eps: float = 1e-5
+    # Dense FFN: "gelu" (w1/b1/w2/b2) or "swiglu" (silu(h wg) * (h wu)) wd,
+    # bias-free.
+    ffn: str = "gelu"
+    qk_norm: bool = False          # RMSNorm over each head of q and of k
+    # One LayerKind a layer where layers differ (window or none, rotated
+    # or not, dense or routed FFN); None = every layer alike, from
+    # attn_window / pos_embedding / moe_experts above.
+    layer_kinds: tuple | None = None
+    # The routed layer. moe_dropless: every chosen expert computes (no
+    # capacity, ops/moe.moe_ffn_dropless: the serving path); otherwise the
+    # capacity-dropping softmax layer above (training). The fields below
+    # are read by the dropless layer only.
+    moe_dropless: bool = False
+    moe_scoring: str = "softmax"   # "softmax" | "sigmoid"
+    moe_norm_topk: bool = True     # chosen scores normalised to sum 1
+    moe_routed_scale: float = 1.0  # ... then scaled
+    moe_router_bias: bool = False  # per-expert bias, for the choice only
+    moe_d_ff: int | None = None    # expert width (None = d_ff)
+    moe_shared_experts: int = 0    # always-on experts of that width
+    # (first, count): which of the moe_experts this chip holds (expert
+    # parallelism's share); None = all. The router keeps its full width.
+    moe_experts_held: tuple | None = None
 
     def __post_init__(self):
+        if self.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(f"unknown norm {self.norm!r}")
+        if self.ffn not in ("gelu", "swiglu"):
+            raise ValueError(f"unknown ffn {self.ffn!r}")
+        if (self.layer_kinds is not None
+                and len(self.layer_kinds) != self.n_layers):
+            raise ValueError(
+                f"layer_kinds names {len(self.layer_kinds)} layers, "
+                f"n_layers is {self.n_layers}")
         if self.attn_window is not None and self.attn_window < 1:
             raise ValueError(
                 f"attn_window must be >= 1, got {self.attn_window}")
@@ -126,7 +181,39 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return (self.d_head if self.d_head is not None
+                else self.d_model // self.n_heads)
+
+    @property
+    def kinds(self) -> tuple:
+        """One LayerKind a layer."""
+        if self.layer_kinds is not None:
+            return tuple(self.layer_kinds)
+        return (LayerKind(self.attn_window, self.pos_embedding == "rope",
+                          "moe" if self.moe_experts else "dense"),
+                ) * self.n_layers
+
+    @property
+    def layer_plan(self) -> tuple:
+        """(n_lead, period, n_periods): the layers as ``n_lead`` leading
+        ones and then ``n_periods`` repeats of a pattern ``period`` long,
+        the split with the fewest distinct layer bodies (a stack of equal
+        layers is (0, 1, n_layers)). ``run_layers`` unrolls the leading
+        layers and one period and scans over the repeats."""
+        kinds, n = self.kinds, self.n_layers
+        best = None
+        for lead in range(n):
+            rest = kinds[lead:]
+            for p in range(1, len(rest) + 1):
+                if len(rest) % p == 0 and rest == rest[:p] * (len(rest) // p):
+                    if best is None or lead + p < best[0] + best[1]:
+                        best = (lead, p, len(rest) // p)
+                    break
+        return best
+
+    @property
+    def homogeneous(self) -> bool:
+        return self.layer_plan[:2] == (0, 1)
 
     @property
     def kv_heads(self) -> int:
@@ -142,29 +229,36 @@ class TransformerConfig:
             return None
         from distributed_model_parallel_tpu.ops.moe import MoEConfig
         return MoEConfig(num_experts=self.moe_experts, d_model=self.d_model,
-                         d_ff=self.d_ff, top_k=self.moe_top_k,
-                         capacity_factor=self.moe_capacity_factor)
+                         d_ff=self.moe_d_ff or self.d_ff,
+                         top_k=self.moe_top_k,
+                         capacity_factor=self.moe_capacity_factor,
+                         normalize_gates=self.moe_norm_topk,
+                         scoring=self.moe_scoring,
+                         routed_scale=self.moe_routed_scale,
+                         held=self.moe_experts_held)
 
 
-def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
-    """Parameter pytree; blocks stacked on a leading [n_layers] axis."""
-    k = jax.random.split(rng, 8)
-    d, f, L = cfg.d_model, cfg.d_ff, cfg.n_layers
+def _init_blocks(k, cfg: TransformerConfig, kind: LayerKind, L: int) -> dict:
+    """``L`` layers of one kind, stacked on a leading axis. ``k``: 8 keys
+    (the default block draws from them as it always has)."""
+    d, f = cfg.d_model, cfg.d_ff
     dt = cfg.dtype
-
-    def dense(key, shape, fan_in):
-        return (jax.random.normal(key, shape, dt) * (fan_in ** -0.5))
+    hd = cfg.n_heads * cfg.head_dim
 
     def stack(key, shape, fan_in):
-        return dense(key, (L,) + shape, fan_in)
+        return jax.random.normal(key, (L,) + shape, dt) * (fan_in ** -0.5)
 
     blocks = {
         "ln1_scale": jnp.ones((L, d), dt),
-        "ln1_bias": jnp.zeros((L, d), dt),
-        "wo": stack(k[3], (d, d), d),
+        "wo": stack(k[3], (hd, d), hd),
         "ln2_scale": jnp.ones((L, d), dt),
-        "ln2_bias": jnp.zeros((L, d), dt),
     }
+    if cfg.norm == "layernorm":
+        blocks["ln1_bias"] = jnp.zeros((L, d), dt)
+        blocks["ln2_bias"] = jnp.zeros((L, d), dt)
+    if cfg.qk_norm:
+        blocks["q_norm"] = jnp.ones((L, cfg.head_dim), dt)
+        blocks["k_norm"] = jnp.ones((L, cfg.head_dim), dt)
     if cfg.gqa:
         if not (1 <= cfg.kv_heads <= cfg.n_heads):
             raise ValueError(f"n_kv_heads={cfg.kv_heads} must be in "
@@ -179,12 +273,36 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
         # [d, H, 3*Dh]: head dim explicit so tensor parallelism shards
         # whole heads (column-parallel over the H axis).
         blocks["wqkv"] = stack(k[2], (d, cfg.n_heads, 3 * cfg.head_dim), d)
-    if cfg.moe_experts:
+    if kind.ffn == "moe" and cfg.moe_dropless:
+        E, fe = cfg.moe_experts, cfg.moe_d_ff or f
+        G = cfg.moe_experts_held[1] if cfg.moe_experts_held else E
+        blocks.update({
+            "router": stack(k[4], (d, E), d),
+            "we_g": stack(k[5], (G, d, fe), d),
+            "we_u": stack(jax.random.fold_in(k[5], 1), (G, d, fe), d),
+            "we_d": stack(k[7], (G, fe, d), fe),
+        })
+        if cfg.moe_router_bias:
+            blocks["router_bias"] = jnp.zeros((L, E), dt)
+        if cfg.moe_shared_experts:
+            fs = fe * cfg.moe_shared_experts
+            blocks.update({
+                "ws_g": stack(jax.random.fold_in(k[4], 1), (d, fs), d),
+                "ws_u": stack(jax.random.fold_in(k[4], 2), (d, fs), d),
+                "ws_d": stack(jax.random.fold_in(k[4], 3), (fs, d), fs),
+            })
+    elif kind.ffn == "moe":
         E = cfg.moe_experts
         blocks.update({
             "router": stack(k[4], (d, E), d),
             "w_in": stack(k[5], (E, d, f), d),
             "w_out": stack(k[7], (E, f, d), f),
+        })
+    elif cfg.ffn == "swiglu":
+        blocks.update({
+            "wg": stack(k[4], (d, f), d),
+            "wu": stack(jax.random.fold_in(k[4], 1), (d, f), d),
+            "wd": stack(k[5], (f, d), f),
         })
     else:
         blocks.update({
@@ -193,13 +311,41 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
             "w2": stack(k[5], (f, d), f),
             "b2": jnp.zeros((L, d), dt),
         })
+    return blocks
+
+
+def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
+    """Parameter pytree. A stack of equal layers: ``blocks`` is one dict,
+    stacked on a leading [n_layers] axis. Where layers differ
+    (``cfg.layer_plan``): ``lead`` is a tuple of single layers' dicts and
+    ``blocks`` a tuple with one dict a position of the period, each
+    stacked on [n_periods]."""
+    k = jax.random.split(rng, 8)
+    d = cfg.d_model
+    dt = cfg.dtype
+    n_lead, period, n_periods = cfg.layer_plan
+    kinds = cfg.kinds
     out = {
         "embed": jax.random.normal(k[0], (cfg.vocab_size, d), dt) * 0.02,
-        "blocks": blocks,
         "ln_f_scale": jnp.ones((d,), dt),
-        "ln_f_bias": jnp.zeros((d,), dt),
-        "head": dense(k[6], (d, cfg.vocab_size), d),
+        "head": jax.random.normal(k[6], (d, cfg.vocab_size), dt)
+        * (d ** -0.5),
     }
+    if cfg.norm == "layernorm":
+        out["ln_f_bias"] = jnp.zeros((d,), dt)
+    if cfg.homogeneous:
+        out["blocks"] = _init_blocks(k, cfg, kinds[0], cfg.n_layers)
+    else:
+        def keys(i):
+            return jax.random.split(jax.random.fold_in(rng, 1 + i), 8)
+
+        out["lead"] = tuple(
+            jax.tree.map(lambda a: a[0],
+                         _init_blocks(keys(i), cfg, kinds[i], 1))
+            for i in range(n_lead))
+        out["blocks"] = tuple(
+            _init_blocks(keys(n_lead + i), cfg, kinds[n_lead + i],
+                         n_periods) for i in range(period))
     if cfg.pos_embedding == "learned":
         out["pos"] = jax.random.normal(k[1], (cfg.max_seq_len, d), dt) * 0.02
     elif cfg.pos_embedding != "rope":
@@ -211,6 +357,23 @@ def layer_norm(x, scale, bias, eps=1e-5):
     mu = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.var(x, axis=-1, keepdims=True)
     return (x - mu) * jax.lax.rsqrt(var + eps) * scale + bias
+
+
+def rms_norm(x, scale, eps=1e-5):
+    """x / sqrt(mean(x^2) + eps) * scale, computed in float32."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                           + eps)
+    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def _norm(tree: dict, name: str, x, cfg: TransformerConfig):
+    """The configuration's norm with the leaves ``<name>_scale`` (and
+    ``<name>_bias``) of ``tree``."""
+    if cfg.norm == "rmsnorm":
+        return rms_norm(x, tree[name + "_scale"], cfg.norm_eps)
+    return layer_norm(x, tree[name + "_scale"], tree[name + "_bias"],
+                      cfg.norm_eps)
 
 
 def apply_rope(x: jax.Array, positions: jax.Array,
@@ -267,6 +430,9 @@ def _qkv_proj(bp: dict, h: jax.Array, cfg: TransformerConfig):
     else:
         qkv = jnp.einsum("btd,dhx->bthx", h, bp["wqkv"])
         q, k, v = jnp.split(qkv, 3, axis=-1)
+    if cfg.qk_norm:
+        q = rms_norm(q, bp["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, bp["k_norm"], cfg.norm_eps)
     return q, k, v
 
 
@@ -278,9 +444,9 @@ def _repeat_kv(x: jax.Array, q: jax.Array) -> jax.Array:
     return x if groups == 1 else jnp.repeat(x, groups, axis=2)
 
 
-def _attention(q, k, v, cfg: TransformerConfig):
+def _attention(q, k, v, cfg: TransformerConfig, window: int | None):
     if cfg.sp_axis is not None:
-        if cfg.attn_window is not None:
+        if window is not None:
             raise ValueError(
                 "attn_window is not supported with sequence parallelism")
         if cfg.sp_impl == "ring":
@@ -295,60 +461,91 @@ def _attention(q, k, v, cfg: TransformerConfig):
         flash_attention,
         should_use_flash,
     )
-    if cfg.attn_window is not None:
+    if window is not None:
         # Banded compute lives in the flash kernels (both directions);
         # there is no windowed XLA fallback, so the knob forces flash.
         if cfg.attn_impl != "flash":
             raise ValueError(
                 "attn_window requires attn_impl='flash' (the banded "
                 "block-skipping lives in the pallas kernels)")
-        return flash_attention(q, k, v, causal=True, window=cfg.attn_window)
+        return flash_attention(q, k, v, causal=True, window=window)
     if should_use_flash(q.shape[1], causal=True, impl=cfg.attn_impl,
                         head_dim=q.shape[-1], dtype=q.dtype):
         return flash_attention(q, k, v, causal=True)
     return full_attention(q, k, v, causal=True)
 
 
-def block_apply(bp: dict, x: jax.Array, cfg: TransformerConfig
+def block_apply(bp: dict, x: jax.Array, cfg: TransformerConfig,
+                kind: LayerKind | None = None
                 ) -> tuple[jax.Array, jax.Array]:
     """One transformer block on [B, T(_local), d]. ``bp`` holds *unstacked*
-    per-layer arrays (a leaf slice of params["blocks"]). Returns
-    ``(x, aux)`` where ``aux`` is the MoE load-balance loss (0 for dense).
+    per-layer arrays (a leaf slice of params["blocks"]); ``kind`` says
+    what this layer is where layers differ (None: ``cfg.kinds[0]``).
+    Returns ``(x, aux)`` where ``aux`` is the MoE load-balance loss (0
+    for dense).
 
     Tensor parallelism: when ``cfg.tp_axis`` is bound, wqkv/w1 arrive
     column-sharded and wo/w2 row-sharded (shard_map hands each device its
     slice); the two psums below complete the Megatron pattern.
     """
     b, t, d = x.shape
+    kind = cfg.kinds[0] if kind is None else kind
 
-    h = layer_norm(x, bp["ln1_scale"], bp["ln1_bias"])
+    h = _norm(bp, "ln1", x, cfg)
     q, k, v = _qkv_proj(bp, h, cfg)          # q:[B,T,H,Dh] kv:[B,T,Hkv,Dh]
-    if cfg.pos_embedding == "rope":
+    if kind.rope:
         q, k = _rope_qk(q, k, cfg)
     k, v = _repeat_kv(k, q), _repeat_kv(v, q)
-    o = _attention(q, k, v, cfg)             # [B,T,H_local,Dh]
+    o = _attention(q, k, v, cfg, kind.window)  # [B,T,H_local,Dh]
     o = o.reshape(b, t, -1) @ bp["wo"]       # row-parallel: partial sums
     if cfg.tp_axis is not None:
         o = jax.lax.psum(o, cfg.tp_axis)
     x = x + o
 
-    h = layer_norm(x, bp["ln2_scale"], bp["ln2_bias"])
-    h, aux = _ffn(bp, h, cfg, tp_axis=cfg.tp_axis, ep_axis=cfg.ep_axis)
+    h = _norm(bp, "ln2", x, cfg)
+    h, aux = _ffn(bp, h, cfg, tp_axis=cfg.tp_axis, ep_axis=cfg.ep_axis,
+                  kind=kind)
     return x + h, aux
 
 
+def _gated(h, wg, wu, wd):
+    """(silu(h wg) * (h wu)) wd, bias-free."""
+    return (jax.nn.silu(h @ wg) * (h @ wu)) @ wd
+
+
 def _ffn(bp: dict, h: jax.Array, cfg: TransformerConfig, *,
-         tp_axis: str | None, ep_axis: str | None):
-    """Post-attention MLP tail, shared by the training path (``block_apply``)
-    and cached decoding (``_decode_block``) so they cannot diverge.
-    Returns (y, aux)."""
-    if cfg.moe_experts:
+         tp_axis: str | None, ep_axis: str | None,
+         kind: LayerKind | None = None, valid=None):
+    """Post-attention MLP tail, shared by the training path
+    (``block_apply``), cached decoding (``_decode_block``) and the paged
+    steps (serve/model.py) so they cannot diverge. Returns (y, aux): the
+    [AUX_STATS] losses, or for the dropless routed layer its counters
+    (ops/moe.moe_ffn_dropless; ``valid`` [B, T] says which tokens count
+    and are routed at all)."""
+    kind = cfg.kinds[0] if kind is None else kind
+    if kind.ffn == "moe" and cfg.moe_dropless:
+        from distributed_model_parallel_tpu.ops.moe import moe_ffn_dropless
+        if tp_axis is not None or ep_axis is not None:
+            raise NotImplementedError(
+                "the dropless routed layer runs on one chip: its tensor-"
+                "and expert-parallel forms are not written (ROADMAP M2)")
+        y, counts = moe_ffn_dropless(bp, h, cfg.moe, valid=valid)
+        if cfg.moe_shared_experts:
+            with jax.named_scope("moe_shared"):
+                y = y + _gated(h, bp["ws_g"], bp["ws_u"], bp["ws_d"])
+        return y, counts
+    if kind.ffn == "moe":
         from distributed_model_parallel_tpu.ops.moe import moe_ffn
         y, aux = moe_ffn(
             {"router": bp["router"], "w_in": bp["w_in"],
              "w_out": bp["w_out"]},
             h, cfg.moe, ep_axis=ep_axis)
         return y, aux.astype(jnp.float32)
+    if cfg.ffn == "swiglu":
+        y = _gated(h, bp["wg"], bp["wu"], bp["wd"])
+        if tp_axis is not None:
+            y = jax.lax.psum(y, tp_axis)
+        return y, jnp.zeros((AUX_STATS,), jnp.float32)
     y = jax.nn.gelu(h @ bp["w1"] + bp["b1"])
     y = y @ bp["w2"]
     if tp_axis is not None:
@@ -357,10 +554,60 @@ def _ffn(bp: dict, h: jax.Array, cfg: TransformerConfig, *,
     return y, jnp.zeros((AUX_STATS,), jnp.float32)
 
 
+def run_layers(params: dict, carry, fn, cfg: TransformerConfig):
+    """Every layer in order. ``fn(bp, kind, body, rep, carry) -> (carry,
+    out)`` is one layer: ``bp`` its unstacked leaves, ``body`` (static)
+    which of the ``n_lead + period`` distinct layer bodies of
+    ``cfg.layer_plan`` it is, ``rep`` which repeat of the period (traced
+    under the scan; 0 for a leading layer), so the layer's index is
+    ``body + rep * period``. Leading layers and the positions of one
+    period are unrolled; the repeats are one ``lax.scan`` (a stack of
+    equal layers is a scan over all of them, as it always was; a single
+    repeat is unrolled too, which keeps every index static). Returns
+    ``(carry, outs)``: one ``out`` a body, those of the period stacked
+    on [n_periods]."""
+    n_lead, period, _ = cfg.layer_plan
+    kinds = cfg.kinds
+    outs = []
+    for i in range(n_lead):
+        carry, o = fn(params["lead"][i], kinds[i], i, 0, carry)
+        outs.append(o)
+    blocks = params["blocks"]
+    if isinstance(blocks, dict):
+        blocks = (blocks,)
+    # the stack's own length: a pipeline stage hands in its slice
+    n_periods = jax.tree.leaves(blocks[0])[0].shape[0]
+
+    def body(carry, xs):
+        bps, rep = xs
+        got = []
+        for i in range(period):
+            carry, o = fn(bps[i], kinds[n_lead + i], n_lead + i, rep, carry)
+            got.append(o)
+        return carry, tuple(got)
+
+    if n_periods == 1:
+        carry, got = body(carry, (jax.tree.map(lambda a: a[0], blocks), 0))
+        got = jax.tree.map(lambda a: a[None], got)
+    else:
+        carry, got = jax.lax.scan(body, carry,
+                                  (tuple(blocks), jnp.arange(n_periods)))
+    return carry, tuple(outs) + tuple(got)
+
+
 def blocks_scan(blocks: dict, x: jax.Array, cfg: TransformerConfig
                 ) -> tuple[jax.Array, jax.Array]:
-    """Run all stacked blocks with lax.scan (single device / per-stage).
-    Returns ``(x, aux)``; aux is the mean per-layer MoE load-balance loss."""
+    """Run stacked blocks of equal layers (single device, or a pipeline
+    stage's slice of them). Returns ``(x, aux)``; aux is the mean
+    per-layer MoE load-balance loss."""
+    return layers_forward({"blocks": blocks}, x, cfg)
+
+
+def layers_forward(params: dict, x: jax.Array, cfg: TransformerConfig
+                   ) -> tuple[jax.Array, jax.Array]:
+    """Every layer of ``params`` on x (``run_layers``: ``lead`` and
+    ``blocks`` as ``init_params`` arranges them). Returns ``(x, aux)``
+    like :func:`blocks_scan`."""
     apply = block_apply
     if cfg.remat:
         if cfg.remat_policy == "dots":
@@ -370,14 +617,18 @@ def blocks_scan(blocks: dict, x: jax.Array, cfg: TransformerConfig
         else:
             raise ValueError(f"unknown remat_policy {cfg.remat_policy!r}; "
                              f"known: full, dots")
-        apply = jax.checkpoint(block_apply, static_argnums=(2,),
+        apply = jax.checkpoint(block_apply, static_argnums=(2, 3),
                                policy=policy)
 
-    def body(carry, bp):
-        carry, aux = apply(bp, carry, cfg)
+    def layer(bp, kind, body, rep, carry):
+        carry, aux = apply(bp, carry, cfg, kind)
+        if kind.ffn == "moe" and cfg.moe_dropless:
+            # the dropless layer's counters are not a loss
+            aux = jnp.zeros((AUX_STATS,), jnp.float32)
         return carry, aux
 
-    out, auxes = jax.lax.scan(body, x, blocks)
+    out, auxes = run_layers(params, x, layer, cfg)
+    auxes = jnp.concatenate([a.reshape(-1, AUX_STATS) for a in auxes])
     return out, jnp.mean(auxes, axis=0)       # [AUX_STATS], mean over layers
 
 
@@ -399,8 +650,13 @@ def embed(params: dict, tokens: jax.Array, cfg: TransformerConfig,
     return params["embed"][tokens] + pos[None]
 
 
-def unembed(params: dict, x: jax.Array) -> jax.Array:
-    x = layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
+def unembed(params: dict, x: jax.Array,
+            cfg: TransformerConfig | None = None) -> jax.Array:
+    """Final norm and head (``cfg`` None: the default LayerNorm)."""
+    if cfg is None:
+        x = layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
+    else:
+        x = _norm(params, "ln_f", x, cfg)
     return x @ params["head"]
 
 
@@ -411,14 +667,14 @@ def hidden_with_aux(params: dict, tokens: jax.Array, cfg: TransformerConfig,
     head (``apply_with_aux``) and the chunked head (``lm_loss`` with
     ``loss_chunk``) so the two paths cannot drift."""
     x = embed(params, tokens, cfg, pos_offset=pos_offset)
-    return blocks_scan(params["blocks"], x, cfg)
+    return layers_forward(params, x, cfg)
 
 
 def apply_with_aux(params: dict, tokens: jax.Array, cfg: TransformerConfig,
                    *, pos_offset: int = 0) -> tuple[jax.Array, jax.Array]:
     """Full forward: [B, T] int tokens -> ([B, T, V] logits, moe aux loss)."""
     x, aux = hidden_with_aux(params, tokens, cfg, pos_offset=pos_offset)
-    return unembed(params, x), aux
+    return unembed(params, x, cfg), aux
 
 
 def apply(params: dict, tokens: jax.Array, cfg: TransformerConfig,
@@ -505,6 +761,18 @@ def lm_loss(params: dict, tokens: jax.Array, targets: jax.Array,
     return token_loss(logits, targets, aux, cfg)
 
 
+def _require_default_block(cfg: TransformerConfig, what: str) -> None:
+    """``generate`` and its dense cache know the default block only
+    (LayerNorm, the GELU or capacity-routed FFN, every layer alike); the
+    other kinds are served through ``serve.Engine`` (ROADMAP M1)."""
+    if (not cfg.homogeneous or cfg.norm != "layernorm" or cfg.ffn != "gelu"
+            or cfg.qk_norm or cfg.moe_dropless):
+        raise NotImplementedError(
+            f"{what} runs the default block only (LayerNorm, GELU or "
+            f"capacity-routed FFN, all layers alike); serve this "
+            f"configuration through serve.Engine")
+
+
 def _cached_block(bp: dict, ck: jax.Array, cv: jax.Array, layer: jax.Array,
                   x: jax.Array, positions: jax.Array,
                   cfg: TransformerConfig, *,
@@ -538,6 +806,7 @@ def _cached_block(bp: dict, ck: jax.Array, cv: jax.Array, layer: jax.Array,
     """
     b, c = x.shape[:2]
     total = ck.shape[2]
+    _require_default_block(cfg, "the dense-cache decode path")
 
     h = layer_norm(x, bp["ln1_scale"], bp["ln1_bias"])
     q, k, v = _qkv_proj(bp, h, cfg)      # q:[B,C,H,Dh] kv:[B,C,Hkv,Dh]
@@ -675,6 +944,7 @@ def generate(params: dict, cfg: TransformerConfig, prompt: jax.Array,
     The reference has no inference path at all; this rounds out the LM
     tooling the flagship model needs.
     """
+    _require_default_block(cfg, "generate()")
     b, t0 = prompt.shape
     total = t0 + steps
     if steps < 1:

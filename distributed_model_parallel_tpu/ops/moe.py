@@ -34,12 +34,30 @@ class MoEConfig:
     # Only consulted for top_k > 1: renormalize the selected experts' gates to
     # sum to 1 (GShard). top-1 always uses the raw softmax prob (Switch).
     normalize_gates: bool = True
+    # Read by the dropless layer (moe_ffn_dropless) only: how the router's
+    # logits become scores ("softmax" | "sigmoid"), the factor on the
+    # chosen (normalised) scores, and (first, count), the experts this
+    # chip holds of the num_experts the router chooses among (None: all).
+    scoring: str = "softmax"
+    routed_scale: float = 1.0
+    held: tuple | None = None
 
     def __post_init__(self):
         if not (1 <= self.top_k <= self.num_experts):
             raise ValueError(
                 f"top_k={self.top_k} must be in [1, num_experts="
                 f"{self.num_experts}]")
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown scoring {self.scoring!r}")
+        first, count = self.held_range
+        if not (0 <= first and count >= 1
+                and first + count <= self.num_experts):
+            raise ValueError(f"held={self.held} is not a range of the "
+                             f"{self.num_experts} experts")
+
+    @property
+    def held_range(self) -> tuple:
+        return self.held if self.held is not None else (0, self.num_experts)
 
 
 def init_moe_params(rng: jax.Array, cfg: MoEConfig) -> dict:
@@ -178,3 +196,89 @@ def moe_ffn(params: dict, x: jax.Array, cfg: MoEConfig,
     # stream must come back bf16 (a promoted carry breaks the blocks
     # lax.scan under mixed precision).
     return y.reshape(b, t, d).astype(x.dtype), aux
+
+
+# ---------------------------------------------------------------------------
+# The dropless layer (serving): every chosen expert that is held computes
+# ---------------------------------------------------------------------------
+
+def route_scores(params: dict, xf: jax.Array, cfg: MoEConfig):
+    """The router of the dropless layer, in float32 (true-float32 product:
+    a rounded logit flips a choice): scores ``s`` [N, E] (sigmoid or
+    softmax of ``xf @ router``), the ``top_k`` indices of largest
+    ``s + router_bias`` (the bias, where the tree has one, decides the
+    choice only) and their weights: the chosen scores, normalised to sum
+    to one (``normalize_gates``) and scaled by ``routed_scale``. Returns
+    ``(experts [N, k] int32, weights [N, k] float32)``."""
+    logits = jnp.dot(xf.astype(jnp.float32),
+                     params["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    s = (jax.nn.sigmoid(logits) if cfg.scoring == "sigmoid"
+         else jax.nn.softmax(logits, axis=-1))
+    pick = s
+    if "router_bias" in params:
+        pick = s + params["router_bias"].astype(jnp.float32)
+    _, experts = jax.lax.top_k(pick, cfg.top_k)
+    w = jnp.take_along_axis(s, experts, axis=-1)
+    if cfg.normalize_gates:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return experts.astype(jnp.int32), w * cfg.routed_scale
+
+
+def moe_ffn_dropless(params: dict, x: jax.Array, cfg: MoEConfig,
+                     valid: jax.Array | None = None
+                     ) -> tuple[jax.Array, jax.Array]:
+    """Routed SiLU-gated experts without capacity: the router chooses
+    ``top_k`` of all ``num_experts`` for every token, and every choice
+    that falls on an expert held here (``cfg.held``; ``we_g``/``we_u``
+    [G, d, f] and ``we_d`` [G, f, d] hold those G) computes. What the
+    experts held elsewhere would add is left out: under expert
+    parallelism the other chips add it; on one chip nothing stands in.
+
+    The held assignments are sorted by expert, their tokens gathered into
+    one [N * k, d] buffer (the worst case: every choice held here; rows
+    past the held ones belong to no group and are zeroed), three grouped
+    products (``jax.lax.ragged_dot``: work follows the rows the groups
+    really have) and a gather back, each token summing its choices in
+    the order it made them. A row's result is its own: nothing depends on
+    which other tokens share the call, so a request's tokens do not
+    depend on its batch.
+
+    x [..., d]; ``valid`` [...] bool: tokens that exist (padding and idle
+    rows are routed nowhere and not counted). Returns ``(y, counts)``,
+    counts int32 [G + 2]: tokens a held expert, then tokens routed, then
+    held experts that got a token at all (whose weights this call read).
+    """
+    d = x.shape[-1]
+    xf = x.reshape(-1, d)
+    n, k = xf.shape[0], cfg.top_k
+    first, g = cfg.held_range
+    with jax.named_scope("moe_route"):
+        experts, w = route_scores(params, xf, cfg)
+        local = experts - first
+        held = jnp.logical_and(local >= 0, local < g)
+        if valid is not None:
+            held = jnp.logical_and(held, valid.reshape(-1, 1))
+        group = jnp.where(held, local, g).reshape(-1)          # [N * k]
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.zeros((g + 1,), jnp.int32).at[group].add(1)[:g]
+        n_held = jnp.sum(sizes)
+        token = order // k               # the sorted rows' tokens
+    with jax.named_scope("moe_experts"):
+        xs = xf[token]                                         # [N * k, d]
+        a = (jax.nn.silu(jax.lax.ragged_dot(xs, params["we_g"], sizes))
+             * jax.lax.ragged_dot(xs, params["we_u"], sizes))
+        o = jax.lax.ragged_dot(a, params["we_d"], sizes)
+        o = jnp.where((jnp.arange(n * k) < n_held)[:, None], o, 0)
+    with jax.named_scope("moe_combine"):
+        back = jnp.argsort(order)        # row of (token, choice) in o
+        picked = o[back].reshape(n, k, d).astype(jnp.float32)
+        wk = jnp.where(held, w, 0.0)
+        y = jnp.zeros((n, d), jnp.float32)
+        for j in range(k):               # the token's own order: fixed
+            y = y + wk[:, j, None] * picked[:, j]
+    routed = n if valid is None else jnp.sum(valid)
+    counts = jnp.concatenate([
+        sizes, jnp.asarray(routed, jnp.int32)[None],
+        jnp.sum(sizes > 0, dtype=jnp.int32)[None]])
+    return y.astype(x.dtype).reshape(x.shape), counts

@@ -60,14 +60,16 @@ from distributed_model_parallel_tpu.ops.pallas_attention import (
 
 def attend_rows(q: jax.Array, kr: jax.Array, vr: jax.Array,
                 positions: jax.Array, lengths: jax.Array,
-                window: int | None = None) -> jax.Array:
+                window: int | None = None,
+                k_positions: jax.Array | None = None) -> jax.Array:
     """Grouped-head cached attention over per-row contiguous K/V.
 
     q: [B, C, H, Dh] queries (C contiguous tokens per row); kr/vr:
     [B, T, Hkv, Dh]; positions: [B, C] absolute token positions;
     lengths: [B] valid K prefix per row (everything at k_pos >= length is
-    zeroed before any reduction — see module docstring). Returns
-    [B, C, H, Dh].
+    zeroed before any reduction — see module docstring); k_positions:
+    [B, T] the keys' absolute positions where kr/vr do not start at
+    position 0 (None: 0..T-1). Returns [B, C, H, Dh].
 
     The score/softmax expression is ``_cached_block``'s exactly (query
     head h attends kv head h // G; same ``band_keep`` predicate), so the
@@ -75,7 +77,9 @@ def attend_rows(q: jax.Array, kr: jax.Array, vr: jax.Array,
     """
     b, c, h, dh = q.shape
     t, hkv = kr.shape[1], kr.shape[2]
-    valid = jnp.arange(t)[None, :] < lengths[:, None]            # [B, T]
+    kpos = (jnp.arange(t)[None, :] if k_positions is None
+            else k_positions)                                    # [1|B, T]
+    valid = kpos < lengths[:, None]                              # [B, T]
     kr = jnp.where(valid[:, :, None, None], kr, 0)
     vr = jnp.where(valid[:, :, None, None], vr, 0)
     qg = q.reshape(b, c, hkv, h // hkv, dh)
@@ -85,8 +89,8 @@ def attend_rows(q: jax.Array, kr: jax.Array, vr: jax.Array,
     # pinning the accumulator is also just better serving numerics.
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, kr,
                    preferred_element_type=jnp.float32) * (dh ** -0.5)
-    keep = band_keep(positions[:, :, None],
-                     jnp.arange(t)[None, None, :], window)       # [B, C, T]
+    keep = band_keep(positions[:, :, None], kpos[:, None, :],
+                     window)                                     # [B, C, T]
     keep = jnp.logical_and(keep, valid[:, None, :])
     s = jnp.where(keep[:, None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
@@ -108,12 +112,29 @@ def paged_attention_xla(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     [B, C]; lengths: [B]. Materializes the gathered [B, N*page, Hkv, Dh]
     view in HBM — fine off-TPU and for prefill chunks; the decode hot
     loop on TPU wants :func:`paged_attention_kernel`.
+
+    Under a ``window`` narrower than the table, only the pages a row's C
+    queries can see are gathered (from the page of key ``pos0 - window +
+    1``: at most ``window + C - 1`` keys, whatever the context), so a
+    sliding layer's scores are [C, window + C] and not [C, max_seq_len].
     """
     b, n = tables.shape
     page = k_pool.shape[1]
-    kr = k_pool[tables].reshape(b, n * page, *k_pool.shape[2:])
-    vr = v_pool[tables].reshape(b, n * page, *v_pool.shape[2:])
-    return attend_rows(q, kr, vr, positions, lengths, window)
+    c = q.shape[1]
+    span = n if window is None else min(n, -(-(window + c - 1) // page) + 1)
+    if span < n:
+        first = _first_page(positions[:, 0], page, window)       # [B]
+        idx = first[:, None] + jnp.arange(span)[None, :]         # [B, span]
+        tables = jnp.take_along_axis(tables, jnp.minimum(idx, n - 1), axis=1)
+        # a page past the table is a copy of the last one under a position
+        # no query reaches: masked like any key ahead of its query
+        k_positions = (idx[:, :, None] * page
+                       + jnp.arange(page)[None, None, :]).reshape(b, -1)
+    else:
+        k_positions = None
+    kr = k_pool[tables].reshape(b, span * page, *k_pool.shape[2:])
+    vr = v_pool[tables].reshape(b, span * page, *v_pool.shape[2:])
+    return attend_rows(q, kr, vr, positions, lengths, window, k_positions)
 
 
 # ---------------------------------------------------------------------------

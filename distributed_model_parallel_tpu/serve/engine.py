@@ -32,11 +32,14 @@ from distributed_model_parallel_tpu.models.transformer import (
     validate_sampling,
 )
 from distributed_model_parallel_tpu.serve.model import (
+    init_stats,
     make_decode_step,
     make_prefill_step,
     make_verify_step,
+    stats_by_layer,
 )
 from distributed_model_parallel_tpu.serve.paged_kv import (
+    CacheLayout,
     PagedKVCache,
     PagePoolError,
     memory_gauges,
@@ -139,12 +142,14 @@ class Engine:
                  serve: ServeConfig, *, telemetry=None, step_hook=None,
                  slo_metrics: bool = True, replica: str | None = None,
                  clock=None, journal=None, meter: bool = True):
-        if cfg.moe_experts:
+        if cfg.moe_experts and not cfg.moe_dropless:
             raise ValueError(
-                "MoE decode routing is batch-coupled (expert-capacity "
-                "drops depend on co-resident tokens), which breaks "
-                "continuous batching's per-request determinism; decode "
-                "MoE models via models.transformer.generate")
+                "the capacity-dropping MoE layer is batch-coupled "
+                "(which tokens an expert drops depends on co-resident "
+                "tokens), which breaks continuous batching's per-request "
+                "determinism; serve routed models with moe_dropless=True "
+                "(ops/moe.moe_ffn_dropless), or decode this one via "
+                "models.transformer.generate")
         if cfg.tp_axis is not None or cfg.sp_axis is not None:
             raise ValueError("the serving engine runs replicated; build "
                              "it with tp_axis=None/sp_axis=None (sharded "
@@ -193,10 +198,23 @@ class Engine:
         # engines must not pollute the samples a telemetry stream's
         # metrics record snapshots for the real runs.
         self._slo_metrics = slo_metrics
+        # Caches by layer kind, from what the engine already knows: in a
+        # model of sliding layers beside full ones, a layer whose window
+        # (plus the most tokens one program writes before it reads) is
+        # much shorter than max_seq_len keeps a ring a slot, every other
+        # layer whole contexts in the shared pool. A stack of equal
+        # layers keeps whole pages as it always has, and so does every
+        # model under the prefix cache (a shared prefix needs every
+        # layer's pages whole).
+        layout = CacheLayout.of(
+            cfg, page_size=serve.page_size, max_seq_len=serve.max_seq_len,
+            span=max(serve.prefill_chunk, serve.spec_k + 1),
+            whole_pages=serve.prefix_cache)
         self.cache = PagedKVCache(
             cfg, n_pages=serve.n_pages, page_size=serve.page_size,
             max_seq_len=serve.max_seq_len,
             prefix_cache=serve.prefix_cache,
+            layout=layout, n_seqs=serve.n_slots,
             # Shared prefixes end on a page AND prefill-chunk boundary,
             # so a cache-hit request's remaining chunks are the same
             # compiled program at the same pos0 stream as the cold run's
@@ -232,9 +250,13 @@ class Engine:
         self._shed_by_reason: dict[str, int] = {}
         self._rejected = 0
         self._sampled = serve.temperature > 0
-        kw = dict(page_size=serve.page_size, n_pages=serve.n_pages,
-                  impl=serve.attn_impl, temperature=serve.temperature,
+        kw = dict(page_size=serve.page_size, impl=serve.attn_impl,
+                  layout=layout, temperature=serve.temperature,
                   top_k=serve.top_k, top_p=serve.top_p)
+        # The routed layers' counters (tokens routed, tokens a held
+        # expert), summed on the device by every step and fetched only
+        # when somebody asks (moe_counters); None for a dense model.
+        self._stats = init_stats(cfg)
         self._prefill = make_prefill_step(cfg, chunk=serve.prefill_chunk,
                                           **kw)
         self._decode = make_decode_step(cfg, **kw)
@@ -272,6 +294,10 @@ class Engine:
         # host write per join, not a rebuild per decode step.
         self._tables_np = np.zeros(
             (serve.n_slots, self.cache.pages_per_seq), np.int32)
+        # ... and, where sliding layers keep rings, each slot's ring pages
+        self._rings_np = (np.zeros((serve.n_slots, layout.ring_pages),
+                                   np.int32)
+                          if layout.ring_pages else None)
         self._auto_rid = 0
         self._iterations = 0
         self._now = 0.0               # live open-loop clock (last iteration)
@@ -364,28 +390,71 @@ class Engine:
         engine warms every engine sharing its geometry — including the
         whole speculative width ladder, which otherwise compiles lazily
         at the first round that drafts each width."""
+        for step, inputs in self._inert_calls():
+            self._run(step, *inputs)
+
+    def _inert_calls(self) -> list:
+        """(step, inputs) for every compiled program, inputs INERT: no
+        active rows, no valid prefill tokens."""
         b = self.serve.n_slots
         n = self.cache.pages_per_seq
-        key = jax.random.key(0)
-        table = jnp.zeros((n,), jnp.int32)
-        # prefill: zero valid tokens -> every write dropped
-        self.cache.ck, self.cache.cv, _ = self._prefill(
-            self.params, self.cache.ck, self.cache.cv,
-            jnp.zeros((1, self.serve.prefill_chunk), jnp.int32),
-            jnp.int32(0), jnp.int32(0), table, key)
-        tables = jnp.zeros((b, n), jnp.int32)
+        r = self.cache.layout.ring_pages
+        i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+        table = (i32(n), i32(r) if r else None)
+        tables = (i32(b, n), i32(b, r) if r else None)
         idle = jnp.zeros((b,), bool)
         keys = (jax.vmap(jax.random.key)(jnp.zeros((b,), jnp.uint32))
                 if self._sampled else None)
-        self.cache.ck, self.cache.cv, _ = self._decode(
-            self.params, self.cache.ck, self.cache.cv,
-            jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
-            tables, idle, keys)
-        for w in self._verify_widths:
-            self.cache.ck, self.cache.cv, _ = self._verify[w](
-                self.params, self.cache.ck, self.cache.cv,
-                jnp.zeros((b, w), jnp.int32), jnp.zeros((b,), jnp.int32),
-                jnp.ones((b,), jnp.int32), tables, idle, keys)
+        # prefill: zero valid tokens -> every write dropped
+        calls = [(self._prefill, (i32(1, self.serve.prefill_chunk),
+                                  jnp.int32(0), jnp.int32(0), table,
+                                  jax.random.key(0))),
+                 (self._decode, (i32(b), i32(b), tables, idle, keys))]
+        calls += [(self._verify[w], (i32(b, w), i32(b),
+                                     jnp.ones((b,), jnp.int32), tables, idle,
+                                     keys))
+                  for w in self._verify_widths]
+        return calls
+
+    def op_scopes(self, scopes) -> dict:
+        """{module name: {instruction name: scope}} of the engine's
+        compiled programs (``utils/tracing.op_scopes``): which device ops
+        of a profiler trace run under which ``jax.named_scope``
+        (``moe_route``, ``moe_experts``, ``attn_sliding``, ...). Compiles
+        each program once more (from the compile cache where there is
+        one): for a traced run's reader, after its window."""
+        return dict(tracing.op_scopes(
+            step.lower(self.params, self.cache.pools, self._stats,
+                       *inputs).compile().as_text(), scopes)
+            for step, inputs in self._inert_calls())
+
+    def _run(self, step, *inputs):
+        """Dispatch one jitted step on the device state it donates (the
+        cache's pools, the routed layers' counters); returns its tokens."""
+        self.cache.pools, self._stats, out = step(
+            self.params, self.cache.pools, self._stats, *inputs)
+        return out
+
+    def _slot_tables(self, slot=None) -> tuple:
+        """(table, ring) of one slot, or of all of them, for a step."""
+        pick = (lambda a: a) if slot is None else (lambda a: a[slot])
+        return (jnp.asarray(pick(self._tables_np)),
+                jnp.asarray(pick(self._rings_np))
+                if self._rings_np is not None else None)
+
+    def moe_counters(self) -> dict:
+        """The routed layers' counters since the engine was built, fetched
+        from the device (a sync: ask between iterations, not inside one):
+        ``{layer: {"tokens_routed", "held_assignments",
+        "tokens_per_held_expert", "experts_touched"}}`` (the last: held
+        experts that got a token, summed over the layer's calls); empty
+        for a dense model."""
+        return {layer: {"tokens_routed": int(row[-2]),
+                        "held_assignments": int(row[:-2].sum()),
+                        "tokens_per_held_expert": row[:-2].tolist(),
+                        "experts_touched": int(row[-1])}
+                for layer, row in stats_by_layer(self._stats,
+                                                 self.cfg).items()}
 
     # -- submission ---------------------------------------------------------
 
@@ -508,12 +577,27 @@ class Engine:
         along untouched (a queued request that was itself migrated in
         keeps the payload it still carries). Slots and pages return to
         this engine immediately; terminal requests stay for the record.
+
+        Where sliding layers keep rings (``CacheLayout``) there is no run
+        of whole pages to copy: a resident request leaves with its
+        tokens alone and the peer rebuilds its K/V by prefill over
+        prompt + committed tokens, as after a crash (``Request.replay``,
+        serve/journal.py: the last committed token is re-sampled and
+        asserted against the one it carries).
         """
         out: list[Request] = []
+        by_replay = bool(self.cache.layout.ring_pages)
         for req in self._requests:
             if req.done:
                 continue
-            if req.slot is not None:
+            if req.slot is not None and by_replay:
+                req.prefill_cursor = 0
+                req.cached_prompt_tokens = 0
+                req.replay = bool(req.generated)
+                req.state = RequestState.QUEUED
+                self._rtrace(req, "export", pages=0, replay=True,
+                             n_tokens=len(req.prefill_tokens))
+            elif req.slot is not None:
                 if req.state is RequestState.PREFILL:
                     # Positions [0, cursor) are prefilled and written.
                     n_written = req.prefill_cursor
@@ -749,6 +833,8 @@ class Engine:
         admitted = self.sched.admit(now)
         for req in admitted:
             self._tables_np[req.slot] = self.cache.table_array(req.rid)
+            if self._rings_np is not None:
+                self._rings_np[req.slot] = self.cache.ring_array(req.rid)
             if self.meter is not None:
                 # Residency starts here for cold, migrated-in and
                 # crash-replayed admissions alike — each replica bills
@@ -854,13 +940,12 @@ class Engine:
             toks = np.zeros((1, chunk), np.int32)
             toks[0, :n_valid] = seq[lo:lo + n_valid]
             inputs = (jnp.asarray(toks), jnp.int32(lo), jnp.int32(n_valid),
-                      jnp.asarray(self._tables_np[req.slot]),
+                      self._slot_tables(req.slot),
                       jax.random.key(req.seed))
         m = self.meter
         d0 = time.monotonic() if m is not None else 0.0
         with span("prefill_chunk.dispatch", stream=False):
-            self.cache.ck, self.cache.cv, tok = self._prefill(
-                self.params, self.cache.ck, self.cache.cv, *inputs)
+            tok = self._run(self._prefill, *inputs)
         if m is not None:
             # A prefill chunk owns the whole slice: its full dispatch
             # wall bills to this one request (utils/metering.py).
@@ -964,13 +1049,11 @@ class Engine:
             keys = (jax.vmap(jax.random.key)(jnp.asarray(seeds))
                     if self._sampled else None)
             inputs = (jnp.asarray(tokens), jnp.asarray(positions),
-                      jnp.asarray(self._tables_np), jnp.asarray(active),
-                      keys)
+                      self._slot_tables(), jnp.asarray(active), keys)
         m = self.meter
         d0 = time.monotonic() if m is not None else 0.0
         with span("decode_round.dispatch", stream=False):
-            self.cache.ck, self.cache.cv, nxt = self._decode(
-                self.params, self.cache.ck, self.cache.cv, *inputs)
+            nxt = self._run(self._decode, *inputs)
         with span("decode_round.sync", stream=False):
             nxt = np.asarray(jax.device_get(nxt))
         with span("decode_round.commit", stream=False):
@@ -1078,13 +1161,12 @@ class Engine:
             keys = (jax.vmap(jax.random.key)(jnp.asarray(seeds))
                     if self._sampled else None)
             inputs = (jnp.asarray(tokens), jnp.asarray(positions),
-                      jnp.asarray(n_valid), jnp.asarray(self._tables_np),
+                      jnp.asarray(n_valid), self._slot_tables(),
                       jnp.asarray(active), keys)
         m = self.meter
         d0 = time.monotonic() if m is not None else 0.0
         with span("decode_round.dispatch", stream=False):
-            self.cache.ck, self.cache.cv, out = self._verify[width](
-                self.params, self.cache.ck, self.cache.cv, *inputs)
+            out = self._run(self._verify[width], *inputs)
         with span("decode_round.sync", stream=False):
             out = np.asarray(jax.device_get(out))
         with span("decode_round.commit", stream=False):

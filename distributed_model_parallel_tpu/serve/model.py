@@ -13,12 +13,22 @@ Two jitted programs serve every request shape:
   row-independent math, own pages — a mid-batch join decodes bitwise
   what a solo run would.
 
-The block math is ``models/transformer``'s own pieces (``_qkv_proj``,
-``apply_rope``, ``layer_norm``, ``_ffn``, ``unembed``) with the dense
-cache's write/read swapped for the page pool
-(``ops/paged_attention``) — the training/decode definitions stay single-
-source. MoE FFNs are rejected by the engine: expert capacity dropping
-couples co-resident tokens, which would break per-request determinism.
+The block math is ``models/transformer``'s own pieces (``_norm``,
+``_qkv_proj``, ``apply_rope``, ``_ffn``, ``unembed``) with the dense
+cache's write/read swapped for the page pools (``ops/paged_attention``)
+— the training/decode definitions stay single-source, and what a layer
+is (its norm, FFN, window, rotation) comes from the configuration, one
+``LayerKind`` a layer. Routed FFNs are served by the dropless layer
+(``ops/moe.moe_ffn_dropless``): a token's result is its own whatever
+shares the call. The capacity-dropping layer couples co-resident tokens
+and stays refused (serve/engine.py).
+
+Every step takes and returns the device state it donates: ``pools``
+(``PagedKVCache.pools``: the full layers' K and V pools and the sliding
+layers' ring pools, serve/paged_kv.CacheLayout) and ``stats`` (the routed
+layers' counters, summed on the device; None for a dense model).
+``tables`` is ``(table, ring)``: a sequence's pages of the shared pool,
+and its ring pages (None where no layer keeps a ring).
 """
 
 from __future__ import annotations
@@ -29,69 +39,132 @@ import jax
 import jax.numpy as jnp
 
 from distributed_model_parallel_tpu.models.transformer import (
+    LayerKind,
     TransformerConfig,
     _ffn,
+    _norm,
     _qkv_proj,
     apply_rope,
-    layer_norm,
     make_sampler,
+    run_layers,
     unembed,
 )
 from distributed_model_parallel_tpu.ops.paged_attention import (
     paged_attention,
 )
+from distributed_model_parallel_tpu.serve.paged_kv import CacheLayout
 
 
-def paged_block(bp: dict, ck: jax.Array, cv: jax.Array, layer: jax.Array,
-                x: jax.Array, positions: jax.Array, write_pages: jax.Array,
-                write_offsets: jax.Array, tables: jax.Array,
-                lengths: jax.Array, cfg: TransformerConfig, *,
-                impl: str) -> tuple[jax.Array, jax.Array, jax.Array]:
+def paged_block(bp: dict, kind: LayerKind, pools: tuple, where: tuple,
+                x: jax.Array, positions: jax.Array, writes: dict,
+                offsets: jax.Array, lengths: jax.Array, valid: jax.Array,
+                cfg: TransformerConfig, *, impl: str):
     """One transformer block over the paged cache.
 
-    x: [B, C, d]; positions: [B, C] absolute; write_pages/write_offsets:
-    [B, C] physical (page, offset) per token — an out-of-range page id
-    drops the write (idle slots, prompt padding); tables: [B, N];
-    lengths: [B] valid K prefix (after this step's writes); ck/cv:
-    [L, P, page, Hkv, Dh] pools, ``layer`` (traced) selects the slab.
-    The paged counterpart of ``transformer._cached_block``.
+    x: [B, C, d]; positions: [B, C] absolute; pools: (ck, cv, wk, wv),
+    each [L_kind, P, page, Hkv, Dh]; where: (ring, layer): this layer is
+    layer ``layer`` (traced or not) of the ring pools or of the full
+    ones; writes[ring] = (pages [B, C], tables [B, N]): the physical page
+    of each token — an out-of-range id drops the write (idle slots,
+    prompt padding) — and the logical-to-physical table the read follows;
+    offsets: [B, C] within the page; lengths: [B] valid K prefix (after
+    this step's writes); valid: [B, C] tokens that exist (the routed
+    layer sends no other anywhere). Returns ``(x, pools, aux)``. The
+    paged counterpart of ``transformer._cached_block``.
     """
     b, c = x.shape[:2]
-    h = layer_norm(x, bp["ln1_scale"], bp["ln1_bias"])
+    ring, layer = where
+    kpool, vpool = pools[2:] if ring else pools[:2]
+    pages, tables = writes[ring]
+    h = _norm(bp, "ln1", x, cfg)
     q, k, v = _qkv_proj(bp, h, cfg)          # q:[B,C,H,Dh] kv:[B,C,Hkv,Dh]
-    if cfg.pos_embedding == "rope":
+    if kind.rope:
         # Per-row positions: the continuous batch has every row at its
         # own offset. The cache stores rotated keys, like the dense path.
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
-    ck = ck.at[layer, write_pages, write_offsets].set(
-        k.astype(ck.dtype), mode="drop")
-    cv = cv.at[layer, write_pages, write_offsets].set(
-        v.astype(cv.dtype), mode="drop")
-    kp = jax.lax.dynamic_index_in_dim(ck, layer, 0, keepdims=False)
-    vp = jax.lax.dynamic_index_in_dim(cv, layer, 0, keepdims=False)
-    o = paged_attention(q, kp, vp, tables, positions, lengths,
-                        window=cfg.attn_window, impl=impl)
+    kpool = kpool.at[layer, pages, offsets].set(
+        k.astype(kpool.dtype), mode="drop")
+    vpool = vpool.at[layer, pages, offsets].set(
+        v.astype(vpool.dtype), mode="drop")
+    kp = jax.lax.dynamic_index_in_dim(kpool, layer, 0, keepdims=False)
+    vp = jax.lax.dynamic_index_in_dim(vpool, layer, 0, keepdims=False)
+    with jax.named_scope("attn_sliding" if ring else "attn_full"):
+        o = paged_attention(q, kp, vp, tables, positions, lengths,
+                            window=kind.window, impl=impl)
+    pools = pools[:2] + (kpool, vpool) if ring else (kpool, vpool) + pools[2:]
     x = x + o.reshape(b, c, -1) @ bp["wo"]
-    h = layer_norm(x, bp["ln2_scale"], bp["ln2_bias"])
-    h, _ = _ffn(bp, h, cfg, tp_axis=None, ep_axis=None)
-    return x + h, ck, cv
+    h = _norm(bp, "ln2", x, cfg)
+    h, aux = _ffn(bp, h, cfg, tp_axis=None, ep_axis=None, kind=kind,
+                  valid=valid)
+    return x + h, pools, aux
 
 
-def _layers_scan(params: dict, ck, cv, x, positions, write_pages,
-                 write_offsets, tables, lengths, cfg, impl):
-    def layer(carry, xs):
-        x, ck, cv = carry
-        bp, li = xs
-        x, ck, cv = paged_block(bp, ck, cv, li, x, positions, write_pages,
-                                write_offsets, tables, lengths, cfg,
-                                impl=impl)
-        return (x, ck, cv), None
+def _layers(params: dict, pools, stats, x, positions, pages, tables,
+            valid, lengths, cfg, layout: CacheLayout | None, impl: str,
+            page_size: int):
+    """All layers over the paged cache (``run_layers``). pages [B, C]:
+    each token's logical page (an invalid token's is irrelevant);
+    tables: (table [B, N], ring [B, R] or None). Returns ``(x, pools,
+    stats)`` with the routed layers' counters added to ``stats``."""
+    table, ring = tables
+    n = table.shape[1]
+    offsets = positions % page_size
 
-    (x, ck, cv), _ = jax.lax.scan(
-        layer, (x, ck, cv),
-        (params["blocks"], jnp.arange(cfg.n_layers)))
-    return x, ck, cv
+    def physical(tab, pool):
+        got = jnp.take_along_axis(tab, jnp.clip(pages, 0, n - 1), axis=1)
+        return jnp.where(valid, got, pool.shape[1])        # drop invalid
+
+    writes = {False: (physical(table, pools[0]), table)}
+    if ring is not None:
+        # logical page j of a sliding layer lives in ring page j % R
+        ring_table = jnp.take(ring, jnp.arange(n) % ring.shape[1], axis=1)
+        writes[True] = (physical(ring_table, pools[2]), ring_table)
+    bodies = (layout or CacheLayout.all_full(cfg.n_layers)).bodies
+    routed = cfg.moe_dropless
+
+    def layer(bp, kind, body, rep, carry):
+        x, pools = carry
+        is_ring, base, stride = bodies[body]
+        x, pools, aux = paged_block(
+            bp, kind, pools, (is_ring, base + rep * stride), x, positions,
+            writes, offsets, lengths, valid, cfg, impl=impl)
+        return (x, pools), (aux if routed and kind.ffn == "moe" else None)
+
+    (x, pools), counts = run_layers(params, (x, pools), layer, cfg)
+    if stats is not None:
+        stats = jax.tree.map(jnp.add, stats, counts)
+    return x, pools, stats
+
+
+def init_stats(cfg: TransformerConfig):
+    """Zeroed counters of the routed layers in ``run_layers``' order of
+    bodies (None for a model without dropless routed layers): int32
+    [repeats, G + 2] a routed body: tokens a held expert, tokens routed,
+    experts touched a call (ops/moe.moe_ffn_dropless). :func:`stats_by_layer` puts them
+    in layer order."""
+    if not (cfg.moe_dropless and cfg.moe_experts):
+        return None
+    n_lead, period, n_periods = cfg.layer_plan
+    g = cfg.moe.held_range[1]
+    return tuple(
+        jnp.zeros((1 if i < n_lead else n_periods, g + 2), jnp.int32)
+        if cfg.kinds[i].ffn == "moe" else None
+        for i in range(n_lead + period))
+
+
+def stats_by_layer(stats, cfg: TransformerConfig) -> dict:
+    """{layer index: host int array [G + 2]} of the routed layers."""
+    import numpy as np
+
+    n_lead, period, _ = cfg.layer_plan
+    out = {}
+    for body, rows in enumerate(stats or ()):
+        if rows is None:
+            continue
+        for rep, row in enumerate(np.asarray(rows)):
+            out[body + rep * period] = row
+    return dict(sorted(out.items()))
 
 
 def _embed_rows(params: dict, tokens: jax.Array, positions: jax.Array,
@@ -108,57 +181,58 @@ def _embed_rows(params: dict, tokens: jax.Array, positions: jax.Array,
 
 @functools.lru_cache(maxsize=64)
 def make_prefill_step(cfg: TransformerConfig, *, page_size: int,
-                      n_pages: int, chunk: int, impl: str,
+                      chunk: int, impl: str,
+                      layout: CacheLayout | None = None,
                       temperature: float = 0.0, top_k: int | None = None,
                       top_p: float | None = None):
     """One request's prompt chunk against the paged cache.
 
-    Returns ``step(params, ck, cv, tokens [1, chunk], pos0, n_valid,
-    table [N], key) -> (ck, cv, next_token [1])``. ``pos0``/``n_valid``
-    are traced scalars, so every chunk of every prompt length hits one
-    compiled program. The returned token is sampled from the last VALID
-    position's logits — meaningful only on the final chunk (it becomes
-    the request's first generated token, ``generate()``'s ``tok0``);
-    earlier chunks discard it.
+    Returns ``step(params, pools, stats, tokens [1, chunk], pos0,
+    n_valid, tables ([N], [R] | None), key) -> (pools, stats, next_token
+    [1])``. ``pos0``/``n_valid`` are traced scalars, so every chunk of
+    every prompt length hits one compiled program. The returned token is
+    sampled from the last VALID position's logits — meaningful only on
+    the final chunk (it becomes the request's first generated token,
+    ``generate()``'s ``tok0``); earlier chunks discard it.
     """
     sampler = make_sampler(cfg, temperature, top_k, top_p)
     sampled = temperature > 0
 
-    def prefill_step(params, ck, cv, tokens, pos0, n_valid, table, key):
+    def prefill_step(params, pools, stats, tokens, pos0, n_valid, tables,
+                     key):
         positions = (pos0 + jnp.arange(chunk))[None]          # [1, C]
         valid = (jnp.arange(chunk) < n_valid)[None]           # [1, C]
-        pages = table[jnp.clip(positions // page_size, 0,
-                               table.shape[0] - 1)]
-        pages = jnp.where(valid, pages, n_pages)              # drop pads
-        offsets = positions % page_size
         lengths = (pos0 + n_valid)[None]                      # [1]
         x = _embed_rows(params, tokens, positions, cfg)
-        x, ck, cv = _layers_scan(params, ck, cv, x, positions, pages,
-                                 offsets, table[None], lengths, cfg, impl)
+        x, pools, stats = _layers(
+            params, pools, stats, x, positions, positions // page_size,
+            jax.tree.map(lambda t: t[None], tables), valid, lengths, cfg,
+            layout, impl, page_size)
         xl = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
-        logits = unembed(params, xl)[:, 0]                    # [1, V]
+        logits = unembed(params, xl, cfg)[:, 0]               # [1, V]
         sub = (jax.random.fold_in(key, pos0 + n_valid - 1) if sampled
                else key)
-        return ck, cv, sampler(logits, sub)
+        return pools, stats, sampler(logits, sub)
 
     return jax.jit(prefill_step, donate_argnums=(1, 2))
 
 
 @functools.lru_cache(maxsize=64)
 def make_verify_step(cfg: TransformerConfig, *, page_size: int,
-                     n_pages: int, width: int, impl: str,
+                     width: int, impl: str,
+                     layout: CacheLayout | None = None,
                      temperature: float = 0.0, top_k: int | None = None,
                      top_p: float | None = None):
     """Speculative-decoding verification: ``width`` tokens per slot in
     ONE batched forward (the last committed token plus ``width - 1``
     draft tokens), emitting the model's own choice at every position.
 
-    Returns ``step(params, ck, cv, tokens [B, W], positions [B],
-    n_valid [B], tables [B, N], active [B] bool, keys [B]) ->
-    (ck, cv, out_tokens [B, W])`` where ``out_tokens[b, i]`` is the
-    token the model picks for absolute position ``positions[b] + i + 1``
-    given the window prefix through ``i`` — exactly what sequential
-    decode would emit there, because each query row's math is
+    Returns ``step(params, pools, stats, tokens [B, W], positions [B],
+    n_valid [B], tables ([B, N], [B, R] | None), active [B] bool, keys
+    [B]) -> (pools, stats, out_tokens [B, W])`` where ``out_tokens[b, i]``
+    is the token the model picks for absolute position ``positions[b] +
+    i + 1`` given the window prefix through ``i`` — exactly what
+    sequential decode would emit there, because each query row's math is
     position-independent of batch shape and sampling folds the
     per-request key with the query position (the same fold the
     single-token decode step uses). The host-side accept rule
@@ -189,59 +263,54 @@ def make_verify_step(cfg: TransformerConfig, *, page_size: int,
 
         return jax.vmap(row)(logits, keys, positions)
 
-    def verify_step(params, ck, cv, tokens, positions, n_valid, tables,
-                    active, keys):
+    def verify_step(params, pools, stats, tokens, positions, n_valid,
+                    tables, active, keys):
         pos = positions[:, None] + jnp.arange(width)[None]    # [B, W]
         valid = jnp.logical_and(
             jnp.arange(width)[None] < n_valid[:, None],
             active[:, None])                                  # [B, W]
-        pages = jnp.take_along_axis(
-            tables, jnp.clip(pos // page_size, 0, tables.shape[1] - 1),
-            axis=1)
-        pages = jnp.where(valid, pages, n_pages)              # drop invalid
-        offsets = pos % page_size
         lengths = positions + n_valid                         # [B]
         x = _embed_rows(params, tokens, pos, cfg)
-        x, ck, cv = _layers_scan(params, ck, cv, x, pos, pages, offsets,
-                                 tables, lengths, cfg, impl)
-        logits = unembed(params, x)                           # [B, W, V]
-        return ck, cv, window_sample(logits, keys, positions)
+        x, pools, stats = _layers(params, pools, stats, x, pos,
+                                  pos // page_size, tables, valid, lengths,
+                                  cfg, layout, impl, page_size)
+        logits = unembed(params, x, cfg)                      # [B, W, V]
+        return pools, stats, window_sample(logits, keys, positions)
 
     return jax.jit(verify_step, donate_argnums=(1, 2))
 
 
-def decode_logits(params: dict, ck: jax.Array, cv: jax.Array,
-                  tokens: jax.Array, positions: jax.Array,
-                  tables: jax.Array, active: jax.Array,
-                  cfg: TransformerConfig, *, page_size: int, n_pages: int,
-                  impl: str) -> tuple[jax.Array, jax.Array, jax.Array]:
+def decode_logits(params: dict, pools: tuple, stats, tokens: jax.Array,
+                  positions: jax.Array, tables: tuple, active: jax.Array,
+                  cfg: TransformerConfig, *, page_size: int, impl: str,
+                  layout: CacheLayout | None = None):
     """The decode step's forward: feed ``tokens [B]`` at ``positions [B]``
     through the paged cache (idle rows' writes dropped) and return
-    ``(ck, cv, logits [B, V])``. :func:`make_decode_step` samples from
-    these; chip_smoke.py compares them across ``impl`` values."""
+    ``(pools, stats, logits [B, V])``. :func:`make_decode_step` samples
+    from these; chip_smoke.py compares them across ``impl`` values."""
     pos2 = positions[:, None]                                 # [B, 1]
-    pages = jnp.take_along_axis(tables, pos2 // page_size, axis=1)
-    pages = jnp.where(active[:, None], pages, n_pages)        # idle: drop
-    offsets = pos2 % page_size
     lengths = positions + 1
     x = _embed_rows(params, tokens[:, None], pos2, cfg)
-    x, ck, cv = _layers_scan(params, ck, cv, x, pos2, pages, offsets,
-                             tables, lengths, cfg, impl)
-    return ck, cv, unembed(params, x)[:, 0]
+    x, pools, stats = _layers(params, pools, stats, x, pos2,
+                              pos2 // page_size, tables, active[:, None],
+                              lengths, cfg, layout, impl, page_size)
+    return pools, stats, unembed(params, x, cfg)[:, 0]
 
 
 @functools.lru_cache(maxsize=64)
-def make_decode_step(cfg: TransformerConfig, *, page_size: int,
-                     n_pages: int, impl: str, temperature: float = 0.0,
-                     top_k: int | None = None, top_p: float | None = None):
+def make_decode_step(cfg: TransformerConfig, *, page_size: int, impl: str,
+                     layout: CacheLayout | None = None,
+                     temperature: float = 0.0, top_k: int | None = None,
+                     top_p: float | None = None):
     """One token for every slot of the fixed-width decode batch.
 
-    Returns ``step(params, ck, cv, tokens [B], positions [B], tables
-    [B, N], active [B] bool, keys [B]) -> (ck, cv, next_tokens [B])``.
-    Idle slots compute garbage rows (masked writes, outputs ignored) so
-    the program never re-specializes on occupancy. Sampling folds each
-    row's key with its own position — a request's stream is a pure
-    function of (request seed, position), independent of the batch.
+    Returns ``step(params, pools, stats, tokens [B], positions [B],
+    tables ([B, N], [B, R] | None), active [B] bool, keys [B]) ->
+    (pools, stats, next_tokens [B])``. Idle slots compute garbage rows
+    (masked writes, outputs ignored, routed to no expert) so the program
+    never re-specializes on occupancy. Sampling folds each row's key with
+    its own position — a request's stream is a pure function of (request
+    seed, position), independent of the batch.
     """
     sampler = make_sampler(cfg, temperature, top_k, top_p)
     sampled = temperature > 0
@@ -252,11 +321,11 @@ def make_decode_step(cfg: TransformerConfig, *, page_size: int,
         subs = jax.vmap(jax.random.fold_in)(keys, positions)
         return jax.vmap(lambda lg, s: sampler(lg[None], s)[0])(logits, subs)
 
-    def decode_step(params, ck, cv, tokens, positions, tables, active,
-                    keys):
-        ck, cv, logits = decode_logits(
-            params, ck, cv, tokens, positions, tables, active, cfg,
-            page_size=page_size, n_pages=n_pages, impl=impl)
-        return ck, cv, row_sample(logits, keys, positions)
+    def decode_step(params, pools, stats, tokens, positions, tables,
+                    active, keys):
+        pools, stats, logits = decode_logits(
+            params, pools, stats, tokens, positions, tables, active, cfg,
+            page_size=page_size, impl=impl, layout=layout)
+        return pools, stats, row_sample(logits, keys, positions)
 
     return jax.jit(decode_step, donate_argnums=(1, 2))

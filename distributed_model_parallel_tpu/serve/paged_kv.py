@@ -37,6 +37,7 @@ everything (and be quarantined) the moment the export returns.
 
 from __future__ import annotations
 
+import dataclasses
 import math
 from collections import deque
 
@@ -49,6 +50,85 @@ from distributed_model_parallel_tpu.utils import tracing
 class PagePoolError(RuntimeError):
     """A page-accounting invariant was violated (double alloc/free) or an
     allocation exceeded capacity that admission should have checked."""
+
+
+class CacheKindError(NotImplementedError):
+    """Asked of a cache with sliding (ring) layers what only whole
+    contexts can give: prefix sharing (a ring holds no page a second
+    sequence could read) or migration of pages by value. The engine
+    never asks: under ``prefix_cache`` it lays every layer out in whole
+    pages, and it drains a ring cache by replaying tokens."""
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheLayout:
+    """Which pool each layer's K/V lives in, from the model configuration.
+
+    A **full** layer keeps a sequence's whole context: pages from the
+    shared pool, ``ceil(len / page)`` of them, reserved at admission. A
+    **sliding** layer (a window much shorter than ``max_seq_len``) can
+    never read past ``window + span - 1`` keys back from the newest, so
+    it keeps a **ring** of ``ring_pages`` pages a sequence: logical page
+    ``j`` lives in ring page ``j % ring_pages``, and a page is
+    overwritten once every query that could see it is behind. ``span``
+    is the most tokens one program writes before it reads (the prefill
+    chunk, or the verify window).
+
+    Rings are for a model whose layers differ (sliding layers beside
+    full ones). A stack of equal layers keeps whole pages whatever its
+    window, as it always has, and so does every model under
+    ``whole_pages`` (the engine passes ``serve.prefix_cache``: a shared
+    prefix needs every layer's pages whole): such a cache shares
+    prefixes and migrates by page like any other.
+
+    ``bodies``: for each of the ``n_lead + period`` layer bodies of
+    ``cfg.layer_plan`` ``(ring, base, stride)``: the layer of repeat
+    ``rep`` is layer ``base + rep * stride`` of the ring pools (``ring``)
+    or of the full pools.
+    """
+
+    ring_pages: int          # 0: no layer keeps a ring
+    n_full: int
+    n_ring: int
+    bodies: tuple
+
+    @classmethod
+    def of(cls, cfg, *, page_size: int, max_seq_len: int, span: int,
+           whole_pages: bool = False) -> "CacheLayout":
+        n_lead, period, n_periods = cfg.layer_plan
+        kinds = cfg.kinds
+        pages_per_seq = -(-max_seq_len // page_size)
+        whole_pages = whole_pages or cfg.homogeneous
+
+        def ring_of(kind):
+            if whole_pages or kind.window is None:
+                return 0
+            r = ring_pages_for(kind.window, page_size, span)
+            return r if r < pages_per_seq else 0
+
+        rings = [ring_of(k) for k in kinds]
+        ring = [bool(r) for r in rings]
+        in_period = ring[n_lead:n_lead + period]
+        # body j is layer j of the first repeat: as many layers of its
+        # kind lie before it; a repeat later, as many more as a period has
+        bodies = tuple(
+            (ring[j], ring[:j].count(ring[j]),
+             0 if j < n_lead else in_period.count(ring[j]))
+            for j in range(n_lead + period))
+        return cls(ring_pages=max(rings, default=0),
+                   n_full=ring.count(False), n_ring=ring.count(True),
+                   bodies=bodies)
+
+    @classmethod
+    def all_full(cls, n_layers: int) -> "CacheLayout":
+        """Every layer keeps whole contexts: one stack of equal layers."""
+        return cls(0, n_layers, 0, ((False, 0, 1),))
+
+
+def ring_pages_for(window: int, page_size: int, span: int) -> int:
+    """Pages that hold every key the ``span`` newest queries can see
+    under ``window``: ``window + span - 1`` keys, wherever they start."""
+    return -(-(window + span - 1) // page_size) + 1
 
 
 class PagePool:
@@ -153,7 +233,8 @@ class PagedKVCache:
 
     def __init__(self, cfg, *, n_pages: int, page_size: int,
                  max_seq_len: int, prefix_cache: bool = False,
-                 share_granularity: int | None = None):
+                 share_granularity: int | None = None,
+                 layout: CacheLayout | None = None, n_seqs: int = 0):
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         if max_seq_len < 1:
@@ -179,18 +260,66 @@ class PagedKVCache:
             self.prefix = PrefixCache(self.pool, page_size)
         else:
             self.prefix = None
-        shape = (cfg.n_layers, n_pages, page_size, cfg.kv_heads,
+        # Caches by layer kind (CacheLayout): ck/cv hold the full layers,
+        # wk/wv the sliding layers' rings, ``ring_pages`` pages for each
+        # of the ``n_seqs`` sequences that can be resident at once (the
+        # engine's slots), handed out at admission and returned with the
+        # sequence. No layout: every layer is full.
+        if layout is None:
+            layout = CacheLayout.all_full(cfg.n_layers)
+        self.layout = layout
+        if layout.ring_pages and prefix_cache:
+            raise CacheKindError(
+                "prefix sharing needs every layer's whole context in "
+                "shareable pages; this layout keeps a ring a sequence for "
+                "the sliding layers (CacheLayout.of(..., whole_pages=True) "
+                "keeps none; docs/SERVING.md)")
+        shape = (layout.n_full, n_pages, page_size, cfg.kv_heads,
                  cfg.head_dim)
         self.ck = jnp.zeros(shape, cfg.dtype)
         self.cv = jnp.zeros_like(self.ck)
+        self.wk = self.wv = self.ring_pool = None
+        self._rings: dict[object, list[int]] = {}
+        if layout.ring_pages:
+            if n_seqs < 1:
+                raise ValueError("a cache with sliding layers needs n_seqs, "
+                                 "the most sequences resident at once")
+            self.ring_pool = PagePool(n_seqs * layout.ring_pages)
+            self.wk = jnp.zeros((layout.n_ring, self.ring_pool.n_pages)
+                                + shape[2:], cfg.dtype)
+            self.wv = jnp.zeros_like(self.wk)
 
     def pages_needed(self, n_tokens: int) -> int:
         return -(-n_tokens // self.page_size)
 
+    @property
+    def pools(self) -> tuple:
+        """The device state the jitted steps thread (and donate)."""
+        return (self.ck, self.cv, self.wk, self.wv)
+
+    @pools.setter
+    def pools(self, pools) -> None:
+        self.ck, self.cv, self.wk, self.wv = pools
+
     def open(self, sid) -> None:
+        """Start ``sid``'s table; a sequence of a model with sliding
+        layers takes its ring here (admission checked the room)."""
         if sid in self._tables:
             raise PagePoolError(f"sequence {sid!r} is already open")
         self._tables[sid] = []
+        if self.ring_pool is not None:
+            self._rings[sid] = self.ring_pool.alloc(self.layout.ring_pages)
+
+    def ring_room(self) -> bool:
+        """Whether one more sequence's ring fits (always, without
+        sliding layers)."""
+        return (self.ring_pool is None
+                or self.ring_pool.free_pages >= self.layout.ring_pages)
+
+    def ring_array(self, sid) -> np.ndarray:
+        """[ring_pages] int32: ring page ``j % ring_pages`` holds
+        ``sid``'s logical page ``j`` of every sliding layer."""
+        return np.asarray(self._rings[sid], np.int32)
 
     def ensure(self, sid, n_tokens: int) -> None:
         """Grow ``sid``'s table to cover ``n_tokens`` positions. The
@@ -210,6 +339,8 @@ class PagedKVCache:
         (eviction/completion). Shared pages survive under the prefix
         tree's (or another sequence's) reference."""
         self.pool.free(self._tables.pop(sid))
+        if self.ring_pool is not None:
+            self.ring_pool.free(self._rings.pop(sid))
 
     def table_array(self, sid) -> np.ndarray:
         """[pages_per_seq] int32, padded with 0 (masked by length)."""
@@ -268,7 +399,7 @@ class PagedKVCache:
         reservation. Returns the cached token count, or ``None`` when
         the request must keep queuing (no side effects then)."""
         cached, shared, fresh, avail = self._admission(tokens, capacity)
-        if fresh > avail:
+        if fresh > avail or not self.ring_room():
             return None
         self.open(sid)
         if shared:
@@ -349,6 +480,7 @@ class PagedKVCache:
         (serve/engine.py ``drain``). When the caller passes the traced
         ``req`` (and its stream ``sink``), the hop's source half lands
         on the request timeline as an ``export`` rtrace record."""
+        self._whole_contexts_only("export_request")
         table = self._tables[sid]
         n = self.pages_needed(n_tokens)
         if n > len(table):
@@ -377,6 +509,7 @@ class PagedKVCache:
         cold admission that finds no pages. A traced ``req``/``sink``
         records the hop's destination half (an ``import`` rtrace) on
         success only — a bounced import is queue time, not a hop."""
+        self._whole_contexts_only("import_request")
         need = self.pages_needed(capacity)
         avail = self.pool.free_pages
         if self.prefix is not None:
@@ -403,6 +536,13 @@ class PagedKVCache:
             tracing.rtrace(req, "import", sink=sink, pages=n,
                            **(trace_fields or {}))
         return True
+
+    def _whole_contexts_only(self, what: str) -> None:
+        if self.ring_pool is not None:
+            raise CacheKindError(
+                f"{what}: a sliding layer's ring is not a run of whole "
+                f"pages that could be copied by value; migrate such a "
+                f"sequence by replaying its tokens (serve/journal.py)")
 
     def cached_prefix_tokens(self, tokens: list[int]) -> int:
         """Usable cached-prefix length for ``tokens`` (quantized to the
@@ -435,6 +575,12 @@ def memory_gauges(cache: PagedKVCache) -> dict:
         "used_pages": cache.pool.used_pages,
         "prefix_pages": len(cache.prefix) if cache.prefix is not None else 0,
         "free_watermark": cache.pool.free_watermark,
+        # pages held by layer kind: a full layer holds every page of the
+        # shared pool that is in use, a sliding layer the rings
+        "full_layer_pages": cache.pool.used_pages * cache.layout.n_full,
+        "sliding_layer_pages": (cache.ring_pool.used_pages
+                                * cache.layout.n_ring
+                                if cache.ring_pool is not None else 0),
     }
 
 

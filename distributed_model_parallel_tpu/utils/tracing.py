@@ -49,6 +49,8 @@ monotonic-clock duration.
 
 from __future__ import annotations
 
+import re
+
 import itertools
 import os
 import threading
@@ -298,3 +300,26 @@ class span:
         self.attrs.update(attrs)
         if self._ann is not None:
             self._ann.set_metadata(**attrs)
+
+
+# -- device ops by named scope ---------------------------------------------------
+
+_HLO_INSTRUCTION = re.compile(
+    r'^\s*(?:ROOT )?(%[\w.\-]+) = [^\n]*?op_name="([^"]*)"', re.M)
+_HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
+
+
+def op_scopes(hlo_text: str, scopes) -> tuple:
+    """``(module name, {instruction name: scope})`` from a compiled
+    module's text: the instructions whose ``op_name`` (where
+    ``jax.named_scope`` leaves its mark) carries one of ``scopes``, under
+    the first that matches. The profiler's device trace names its op
+    events by instruction (``%fusion.12``) and drops the ``op_name``, so
+    this map is how a trace's ops are told by scope."""
+    m = _HLO_MODULE.match(hlo_text)
+    out = {}
+    for name, path in _HLO_INSTRUCTION.findall(hlo_text):
+        scope = next((s for s in scopes if s in path), None)
+        if scope is not None:
+            out[name] = scope
+    return (m.group(1) if m else ""), out

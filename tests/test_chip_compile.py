@@ -62,8 +62,14 @@ def _compiled_text(fn, *args) -> str:
     # the benchmark's serving cells (chipbench/configs/starcoder2-3b.json
     # under traffic/code-*.json): 32 slots, 256 pages a row, window = cap
     (32, 24, 2, 4096, jnp.bfloat16, 4096),
+    # k-exaone-236b-a23b-ep8 under traffic/mixed-batch.json: 64 slots, 64
+    # query heads over 8 KV heads, 1,024 pages a row; the full layer, and
+    # a sliding layer (its table is the ring's pages, repeated)
+    (64, 64, 8, 16384, jnp.bfloat16, None),
+    (64, 64, 8, 16384, jnp.bfloat16, 128),
 ], ids=["bf16", "f32", "bf16-gqa", "f32-gqa", "bf16-window",
-        "starcoder2-3b-serving"])
+        "starcoder2-3b-serving", "k-exaone-full-layer",
+        "k-exaone-sliding-layer"])
 def test_paged_decode_kernel_compiles(one_chip, b, h, hkv, t, dtype, window):
     """Serving decode shapes: heads x 128, page 16, one pool page for
     every slot's full context."""
@@ -82,6 +88,35 @@ def test_paged_decode_kernel_compiles(one_chip, b, h, hkv, t, dtype, window):
     assert "tpu_custom_call" in text
     # the name the benchmark's readers find the kernel by
     assert "%paged_decode_attention" in text
+
+
+@pytest.mark.parametrize("rows", [4096, 512],
+                         ids=["prefill-chunk-512x8", "decode-round-64x8"])
+def test_grouped_expert_products_compile(one_chip, rows):
+    """The dropless routed layer (ops/moe.moe_ffn_dropless) at
+    K-EXAONE's expert shapes: 16 held experts of 6144 x 2048 out of a
+    router 128 wide, 8 chosen a token, every assignment of a 512-token
+    chunk (or of a 64-row decode round) in one buffer. XLA:TPU has to
+    take ``jax.lax.ragged_dot`` as a grouped kernel (a custom call whose
+    work follows the groups' rows), not as one dense product a group."""
+    from distributed_model_parallel_tpu.ops import moe
+
+    cfg = moe.MoEConfig(num_experts=128, d_model=6144, d_ff=2048, top_k=8,
+                        scoring="sigmoid", routed_scale=2.5, held=(0, 16))
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = {"router": sds((6144, 128)), "router_bias": sds((128,)),
+              "we_g": sds((16, 6144, 2048)), "we_u": sds((16, 6144, 2048)),
+              "we_d": sds((16, 2048, 6144))}
+    text = _compiled_text(
+        lambda p, x, v: moe.moe_ffn_dropless(p, x, cfg, valid=v),
+        params, sds((rows // 8, 6144)), sds((rows // 8,), jnp.bool_))
+    # the name the benchmark's reader finds the grouped products by
+    assert len(re.findall(r"%ragged-dot[\w.-]* = [^\n]*custom-call\(",
+                          text)) >= 3
+    assert "tpu_custom_call" in text
 
 
 def _flash_args(one_chip, t):

@@ -36,10 +36,10 @@ def _last_line(out: str) -> dict:
 def test_tiny_rehearsal_runs_every_phase(capsys):
     assert chip_smoke.main(["--tiny"]) == 0
     out = capsys.readouterr().out
-    for name in ("cnn", "lm", "serve"):
+    for name in ("cnn", "lm", "serve", "serve-routed"):
         assert f"phase {name}: ok" in out, out[-2000:]
     assert out.index("phase cnn: ok") < out.index("phase lm: ok") \
-        < out.index("phase serve: ok")
+        < out.index("phase serve: ok") < out.index("phase serve-routed: ok")
     assert "multichip" not in out
     _last_line(out)
 

@@ -511,17 +511,18 @@ def test_jitted_steps_lower_to_named_modules():
                  slo_metrics=False)
     b, n = 2, eng.cache.pages_per_seq
     i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
-    pools = (eng.params, eng.cache.ck, eng.cache.cv)
+    pools = (eng.params, eng.cache.pools, None)
     idle = jnp.zeros((b,), bool)
     got = [
         module_name(eng._prefill.lower(
-            *pools, i32(1, 4), jnp.int32(0), jnp.int32(0), i32(n),
+            *pools, i32(1, 4), jnp.int32(0), jnp.int32(0), (i32(n), None),
             jax.random.key(0))),
         module_name(eng._decode.lower(
-            *pools, i32(b), i32(b), i32(b, n), idle, None)),
+            *pools, i32(b), i32(b), (i32(b, n), None), idle, None)),
     ] + [
         module_name(eng._verify[w].lower(
-            *pools, i32(b, w), i32(b), i32(b) + 1, i32(b, n), idle, None))
+            *pools, i32(b, w), i32(b), i32(b) + 1, (i32(b, n), None), idle,
+            None))
         for w in eng._verify_widths
     ]
     tx = optax.sgd(0.1)
