@@ -19,10 +19,11 @@ repo benchmarks (depth and step counts cut, weights random from a seed):
    attention, on one batch. The checkpoint ``fit()`` wrote is read back;
 4. **serve** — ``serve.Engine`` on that checkpoint's parameters: requests
    of 64-1500 prompt tokens joining and leaving an 8-slot batch, the
-   compiled paged-decode kernel under ``attn_impl="auto"``. bf16 decode
-   logits against ``attn_impl="xla"``; a float32 engine token-identical
-   to ``"xla"``, and with prefix cache + speculation token-identical to
-   both off.
+   compiled paged-decode and paged-prefill kernels under
+   ``attn_impl="auto"``. bf16 decode logits against ``attn_impl="xla"``;
+   a float32 engine (chunks, decode rounds and verify windows all
+   through the kernels) token-identical to ``"xla"``, and with prefix
+   cache + speculation token-identical to both off.
 
 ``--multichip`` is a separate run for a four-chip host: only the
 cross-chip paths (GSPMD dp 4, shard_map DDP, the four-stage pipeline, LM
@@ -456,6 +457,7 @@ def phase_serve(size: dict, model, params, on_tpu: bool, seed: int) -> None:
     from distributed_model_parallel_tpu.serve.model import (
         decode_logits,
         make_decode_step,
+        make_prefill_step,
     )
 
     kernel = "auto" if on_tpu else "pallas"    # see phase_lm
@@ -489,6 +491,15 @@ def phase_serve(size: dict, model, params, on_tpu: bool, seed: int) -> None:
             jnp.zeros(b, bool), None),
               'attn_impl="auto" decode step lowers to the Pallas '
                    'paged-decode kernel')
+        check(has_custom_call(
+            make_prefill_step(cfg, page_size=size["page"],
+                              chunk=size["chunk"], impl=kernel),
+            params, eng.cache.pools, None,
+            jnp.zeros((1, size["chunk"]), jnp.int32), jnp.int32(0),
+            jnp.int32(1), (jnp.zeros(pages_per_seq, jnp.int32), None),
+            jax.random.key(0)),
+              'attn_impl="auto" prefill step lowers to the Pallas '
+                   'paged-prefill kernel')
     del eng
 
     # (a) bf16 decode logits on the captured batch, kernel vs XLA gather
@@ -512,11 +523,11 @@ def phase_serve(size: dict, model, params, on_tpu: bool, seed: int) -> None:
     del snap, logits
 
     # (b) float32 params and cache, true-f32 matmuls: the paths differ by
-    # rounding only, so greedy tokens are identical. Why f32: on the chip
-    # decode goes through the kernel while prefill chunks and the
-    # speculative verify window go through attend_rows on the XLA path,
-    # so "speculation changes no token" cannot rest on the two being
-    # bit-equal, as it did under the interpreter.
+    # rounding only, so greedy tokens are identical. Why f32: a decode
+    # round, a prompt chunk and the speculative verify window go through
+    # two kernels and, under "xla", attend_rows; they sum in different
+    # orders, so "speculation changes no token" cannot rest on any two
+    # being bit-equal.
     cfg32 = dataclasses.replace(cfg, dtype=jnp.float32)
     params32 = jax.tree.map(lambda x: x.astype(jnp.float32), params)
     with jax.default_matmul_precision("highest"):

@@ -7,8 +7,9 @@
 * ``ring_attention`` — ring + Ulysses sequence-parallel attention
 * ``pallas_attention`` — on-chip blockwise flash attention kernel
 * ``paged_attention`` — the serving engine's paged-KV-cache read: shared
-  attend math, XLA gather fallback, Pallas paged-decode kernel with
-  scalar-prefetched page tables (serve/, docs/SERVING.md)
+  attend math, XLA gather fallback, Pallas paged-decode and
+  paged-prefill kernels with scalar-prefetched page tables, their work
+  following each row's live context (serve/, docs/SERVING.md)
 * ``sparse`` — COO embedding gradients + DDP-style sparse allreduce
 * ``moe`` — top-1 routed mixture-of-experts with expert-parallel all_to_all
 """

@@ -90,6 +90,87 @@ def test_paged_decode_kernel_compiles(one_chip, b, h, hkv, t, dtype, window):
     assert "%paged_decode_attention" in text
 
 
+@pytest.mark.parametrize("h,hkv,n,dtype,window", [
+    # starcoder2-3b under traffic/code-*.json: 256 pages a row, window =
+    # the context cap
+    (24, 2, 256, jnp.bfloat16, 4096),
+    # k-exaone-236b-a23b-ep8 under traffic/mixed-batch.json: 1,024 pages
+    # a row; the full layer, and a sliding layer (its table is the ring's
+    # pages, repeated)
+    (64, 8, 1024, jnp.bfloat16, None),
+    (64, 8, 1024, jnp.bfloat16, 128),
+    (8, 2, 128, jnp.float32, None),
+], ids=["starcoder2-3b-serving", "k-exaone-full-layer",
+        "k-exaone-sliding-layer", "f32-gqa"])
+def test_paged_prefill_kernel_compiles(one_chip, h, hkv, n, dtype, window):
+    """The serving cells' prefill chunk: 512 query tokens of one row,
+    heads x 128, page 16, within the default scoped VMEM."""
+    c, dh, page = 512, 128, 16
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = sds((32 * n, page, hkv, dh), dtype)
+    text = _compiled_text(
+        functools.partial(pa.paged_prefill_attention, window=window,
+                          interpret=False),
+        sds((1, c, h, dh), dtype), pool, pool, sds((1, n), jnp.int32),
+        sds((1,), jnp.int32), sds((1,), jnp.int32))
+    assert "tpu_custom_call" in text
+    # the name a trace lists the kernel under, and not the decode
+    # kernel's, which the benchmark's decode readers match
+    assert "%paged_prefill_attention" in text
+    assert "%paged_decode_attention" not in text
+    # the pools reach the kernel as views of themselves
+    assert not re.search(r"= bf16\[\d+,\d+,128\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+def test_prefill_step_holds_the_kernel_and_no_score_tensor(
+        one_chip, monkeypatch, impl):
+    """A small ``jit_prefill_step`` compiled for the chip. Through the
+    kernel it holds ``%paged_prefill_attention`` and no float32 array of
+    chunk x max_seq_len elements a head: the gather path's scores, which
+    the same step under ``impl="xla"`` does hold (the control: the
+    pattern finds what it looks for)."""
+    from distributed_model_parallel_tpu.models import transformer as tfm
+    from distributed_model_parallel_tpu.serve.model import make_prefill_step
+
+    # the kernel asks jax.devices() whether to interpret; here it
+    # compiles for the described chip
+    monkeypatch.setattr(
+        pa, "paged_prefill_attention",
+        functools.partial(pa.paged_prefill_attention, interpret=False))
+    chunk, max_seq, page = 128, 2048, 16
+    cfg = tfm.TransformerConfig(
+        vocab_size=1024, d_model=256, n_heads=4, n_kv_heads=2, d_head=128,
+        n_layers=2, d_ff=512, max_seq_len=max_seq, dtype=jnp.bfloat16,
+        pos_embedding="rope")
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda: tfm.init_params(jax.random.key(0), cfg)))
+    pool = sds((cfg.n_layers, 512, page, 2, 128), jnp.bfloat16)
+    text = make_prefill_step(
+        cfg, page_size=page, chunk=chunk, impl=impl).lower(
+        params, (pool, pool, None, None), None, sds((1, chunk), jnp.int32),
+        sds((), jnp.int32), sds((), jnp.int32),
+        (sds((max_seq // page,), jnp.int32), None), None
+    ).compile().as_text()
+    assert text.startswith("HloModule jit_prefill_step")
+    scores = re.findall(rf"f32\[(?:\d+,)*{chunk},{max_seq}\]", text)
+    if impl == "pallas":
+        assert re.search(r"%paged_prefill_attention[\w.]* = .*custom-call\(",
+                         text)
+        assert not scores
+    else:
+        assert "%paged_prefill_attention" not in text
+        assert scores
+
+
 @pytest.mark.parametrize("rows", [4096, 512],
                          ids=["prefill-chunk-512x8", "decode-round-64x8"])
 def test_grouped_expert_products_compile(one_chip, rows):

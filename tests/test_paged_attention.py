@@ -3,13 +3,16 @@ cache.
 
 The contract (docs/SERVING.md): the XLA gather path produces BITWISE the
 dense-cache result — paging is an indirection, never a numeric change.
-The Pallas kernel streams the row's pages through an online softmax, so
-it sums in another order and agrees to rounding: float32 within a few
-ulp of the row, bfloat16 within one bf16 ulp of each output (exact
-greedy-token identity between the two is pinned at engine level,
-tests/test_serve.py and tests/test_prefix_cache.py). Stale page contents
+The Pallas kernels (decode: one query token a row; prefill: a chunk of
+them) stream the row's pages through an online softmax, so they sum in
+another order and agree to rounding: float32 within a few ulp of the
+row, bfloat16 within one bf16 ulp of each output (exact greedy-token
+identity between the two is pinned at engine level, tests/test_serve.py
+and tests/test_prefix_cache.py). Stale page contents
 are unreachable on both paths (masked to exact zeros), so a request's
 values cannot depend on who held its pages before."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -50,7 +53,7 @@ def _dense(q, kp, vp, tables, positions, window=None):
                           window)
 
 
-def _assert_rounding_close(out, ref):
+def _assert_rounding_close(out, ref, f32_ulps=4):
     """The kernel-vs-dense tolerance, set from the dtype: float32 within
     4 ulp of the row's largest output (the row = one head's [Dh] vector),
     bfloat16 within one bf16 ulp of each output."""
@@ -61,7 +64,7 @@ def _assert_rounding_close(out, ref):
         tol = 2.0 ** (np.floor(np.log2(np.maximum(
             np.abs(r), np.finfo(np.float32).tiny))) - 7)
     else:
-        tol = 4 * np.finfo(np.float32).eps * np.abs(r).max(
+        tol = f32_ulps * np.finfo(np.float32).eps * np.abs(r).max(
             axis=-1, keepdims=True)
     assert (np.abs(o - r) <= tol).all(), float(np.abs(o - r).max())
 
@@ -186,14 +189,157 @@ def test_kernel_block_loop_edges(edge):
     _assert_rounding_close(out, ref)
 
 
-def test_prefill_chunk_matches_whole_prompt():
+# The prefill kernel: a chunk of C queries a row from pos0, against the
+# live blocks of the row's pages. Sizes in units the code derives (bk =
+# keys a block), so the edges stay edges if the block size changes. Each
+# case: a function of (bk, page) giving the chunk, the rows' first
+# positions and valid counts, the heads, the window and the dtype.
+def _prefill(c, pos0, n_valid=None, h=4, hkv=2, window=None,
+             dtype=jnp.float32, tile_bytes=None):
+    return dict(c=c, pos0=pos0, n_valid=n_valid or [c] * len(pos0), h=h,
+                hkv=hkv, window=window, dtype=dtype, tile_bytes=tile_bytes)
+
+
+_PREFILL_CASES = {
+    "verify-window-of-4-rows-at-differing-pos0":
+        lambda bk, page: _prefill(4, [0, page + 3, bk - 2], [4, 4, 3]),
+    "chunk-of-32-from-position-0":
+        lambda bk, page: _prefill(32, [0]),
+    "pos0-inside-a-page":
+        lambda bk, page: _prefill(32, [bk + page + 3]),
+    "pos0-on-a-block-boundary":
+        lambda bk, page: _prefill(32, [bk, 2 * bk]),
+    "chunk-crosses-a-block-edge":
+        lambda bk, page: _prefill(48, [bk - 20]),
+    "padded-tail":
+        lambda bk, page: _prefill(32, [bk + 5], [9]),
+    "row-of-length-0-beside-a-live-one":
+        lambda bk, page: _prefill(8, [0, 11], [0, 8]),
+    "query-tiles-of-16-the-last-partial-and-wholly-padded":
+        lambda bk, page: _prefill(40, [bk - 9], [20], tile_bytes=1),
+    "one-kv-head":
+        lambda bk, page: _prefill(32, [bk - 7], h=4, hkv=1),
+    "eight-kv-heads":
+        lambda bk, page: _prefill(32, [bk - 7], h=16, hkv=8),
+    "window-narrower-than-the-context":
+        lambda bk, page: _prefill(32, [2 * bk + 5],
+                                  window=bk + 2 * page + 3),
+    "window-narrower-than-the-chunk":
+        lambda bk, page: _prefill(32, [bk - 10, 3], window=8),
+    "window-and-query-tiles":
+        lambda bk, page: _prefill(48, [bk + 1], [41], window=20,
+                                  tile_bytes=1),
+    "bfloat16-two-kv-heads":
+        lambda bk, page: _prefill(32, [bk - 7], [30], dtype=jnp.bfloat16),
+    "bfloat16-eight-kv-heads-windowed":
+        lambda bk, page: _prefill(32, [bk - 7], h=16, hkv=8,
+                                  window=bk // 2, dtype=jnp.bfloat16),
+    "bfloat16-one-kv-head":
+        lambda bk, page: _prefill(32, [bk + 3], h=4, hkv=1,
+                                  dtype=jnp.bfloat16),
+}
+
+
+def _prefill_inputs(case, page, dh, seed):
+    """Pools whose page 0 is NaN throughout, a table of every row's own
+    pages (``ids``), and the same table with page 0 wherever the kernel
+    has no business (``live``): left of the band's first page, past the
+    row's length."""
+    b, c, hkv, window = (len(case["pos0"]), case["c"], case["hkv"],
+                         case["window"])
+    pos0 = np.asarray(case["pos0"])
+    lengths = pos0 + np.asarray(case["n_valid"])
+    n = -(-(int(pos0.max()) + c) // page) + 1
+    kp, vp = _pool(seed, p=1 + b * n, page=page, hkv=hkv, dh=dh,
+                   dtype=case["dtype"])
+    # Non-negative V, as in the decode kernel's edge cases above.
+    kp, vp = kp.at[0].set(jnp.nan), jnp.abs(vp).at[0].set(jnp.nan)
+    ids = 1 + np.random.default_rng(seed).permutation(b * n).reshape(b, n)
+    live = np.asarray([[pa._first_page(p, page, window) <= j < -(-ln // page)
+                        for j in range(n)] for p, ln in zip(pos0, lengths)])
+    q = jax.random.normal(jax.random.key(seed + 1),
+                          (b, c, case["h"], dh), case["dtype"])
+    return (q, kp, vp, jnp.asarray(ids, jnp.int32),
+            jnp.asarray(np.where(live, ids, 0), jnp.int32),
+            jnp.asarray(pos0, jnp.int32), jnp.asarray(lengths, jnp.int32))
+
+
+def _interpreter(dtype):
+    """Scratch the kernel has not written reads as NaN, so a buffer row
+    the loop left stale and the band did not mask turns the output NaN.
+    That interpreter has no 16-bit-to-word view of a buffer
+    (``_head_rows``); bfloat16 cases take the plain one."""
+    if dtype == jnp.bfloat16:
+        return True
+    return pltpu.InterpretParams(uninitialized_memory="nan")
+
+
+@pytest.mark.parametrize("name", list(_PREFILL_CASES))
+def test_prefill_kernel_matches_attend_rows(name, monkeypatch):
+    """The prefill kernel in interpret mode against ``attend_rows`` on
+    the gathered view, on every valid query (a padded query's output is
+    finite and compared with nothing). A page the loop copied and the
+    mask let through, or a table entry read outside the band, is NaN."""
+    page, dh = 8, 16
+    case = _PREFILL_CASES[name](pa._PREFILL_BLOCK_KEYS, page)
+    if case["tile_bytes"]:
+        # the smallest query tile the kernel cuts: several tiles a chunk
+        monkeypatch.setattr(pa, "_PREFILL_TILE_BYTES", case["tile_bytes"])
+        assert pa._prefill_query_tile(case["c"], 2, dh, 4) < case["c"]
+    q, kp, vp, ids, live, pos0, lengths = _prefill_inputs(case, page, dh, 31)
+    out = pa.paged_prefill_attention(
+        q, kp, vp, live, pos0, lengths, window=case["window"],
+        interpret=_interpreter(case["dtype"]))
+    positions = pos0[:, None] + jnp.arange(case["c"])[None]
+    ref = pa.paged_attention_xla(q, kp, vp, ids, positions, lengths,
+                                 case["window"])
+    valid = np.arange(case["c"])[None] < np.asarray(case["n_valid"])[:, None]
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    assert np.isfinite(np.asarray(ref, np.float32)[valid]).all()
+    # float32: 16 ulp of the row, not the decode cases' 4. A chunk is
+    # the worst of a hundred times as many outputs, each over hundreds
+    # of keys; against a float64 softmax the kernel reads 7-10 ulp at
+    # the worst and the gather path 3.5-6.
+    _assert_rounding_close(out[valid], ref[valid], f32_ulps=16)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_prefill_kernel_stale_pages_unreachable(dtype):
+    """The prefill kernel's side of ``test_stale_page_contents_
+    unreachable``: every pool position at or past a row's length, on its
+    own pages and on everybody else's, rewritten with NaN and with large
+    values, changes no bit of any query's output."""
+    page, dh = 8, 16
+    bk = pa._PREFILL_BLOCK_KEYS
+    case = _prefill(24, [bk - 5, 2], [13, 24], window=bk, dtype=dtype)
+    q, kp, vp, ids, _, pos0, lengths = _prefill_inputs(case, page, dh, 41)
+    kp, vp = kp.at[0].set(0.5), vp.at[0].set(0.5)
+    run = functools.partial(pa.paged_prefill_attention, window=bk,
+                            interpret=_interpreter(dtype))
+    clean = run(q, kp, vp, ids, pos0, lengths)
+    written = np.zeros(kp.shape[:2], bool)
+    for row, ln in zip(np.asarray(ids), np.asarray(lengths)):
+        for j, pid in enumerate(row):
+            written[pid, :max(0, min(page, ln - j * page))] = True
+    for poison in (np.nan, 3e38 if dtype == jnp.float32 else 3e4):
+        kn = jnp.where(written[:, :, None, None], kp, poison)
+        vn = jnp.where(written[:, :, None, None], vp, poison)
+        out = run(q, kn, vn, ids, pos0, lengths)
+        assert np.isfinite(np.asarray(out, np.float32)).all()
+        assert (out == clean).all()
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_prefill_chunk_matches_whole_prompt(impl):
     """A C-token chunk read of the paged cache scores what the same
     positions score in a single whole-prompt pass (intra-chunk causality
-    comes from the shared band mask). XLA's CPU dot picks its blocking
-    from the operand shapes, so an 8-row chunk and the 24-row prompt
-    contract in different orders: equal to float32 rounding, not bitwise.
-    What serving needs from it — chunking changes no greedy token — is
-    asserted on the engine below."""
+    comes from the shared band mask), on the gather path and through the
+    prefill kernel (``impl="pallas"``, interpreted). XLA's CPU dot picks
+    its blocking from the operand shapes, so an 8-row chunk and the
+    24-row prompt contract in different orders: equal to float32
+    rounding, not bitwise. What serving needs from it — chunking changes
+    no greedy token — is asserted on the engine below."""
     kp, vp = _pool(3)
     table = jnp.asarray([[5, 2, 11, 4]], jnp.int32)
     t0 = 24
@@ -202,9 +348,10 @@ def test_prefill_chunk_matches_whole_prompt():
         q, kp, vp, table, jnp.arange(t0)[None], jnp.asarray([t0]))
     chunk = 8
     parts = [
-        pa.paged_attention_xla(
+        pa.paged_attention(
             q[:, lo:lo + chunk], kp, vp, table,
-            (lo + jnp.arange(chunk))[None], jnp.asarray([lo + chunk]))
+            (lo + jnp.arange(chunk))[None], jnp.asarray([lo + chunk]),
+            impl=impl)
         for lo in range(0, t0, chunk)
     ]
     _assert_rounding_close(jnp.concatenate(parts, axis=1), whole)
@@ -221,11 +368,40 @@ def test_prefill_chunk_matches_whole_prompt():
     for prefill_chunk in (chunk, 32):
         eng = Engine(params, cfg, ServeConfig(
             n_slots=2, page_size=8, n_pages=32, max_seq_len=64,
-            prefill_chunk=prefill_chunk))
+            prefill_chunk=prefill_chunk, attn_impl=impl))
         req = eng.submit(prompt, 16)
         eng.run()
         tokens.append(req.generated)
     assert tokens[0] == tokens[1]
+
+
+def test_dispatch_sends_chunks_to_the_prefill_kernel(monkeypatch):
+    """``impl="pallas"`` runs a multi-token call through the prefill
+    kernel; pools it cannot take apart (float16, three KV heads) stay on
+    the gather path, by the shape."""
+    q, kp, vp, tables, positions = _case()
+    q4 = jax.random.normal(jax.random.key(8), (3, 4, *q.shape[2:]))
+    pos = positions[:, None] - 3 + jnp.arange(4)[None]
+    calls = []
+    real = pa.paged_prefill_attention
+    monkeypatch.setattr(pa, "paged_prefill_attention",
+                        lambda *a, **k: calls.append(1) or real(*a, **k))
+    out = pa.paged_attention(q4, kp, vp, tables, pos, positions + 1,
+                             impl="pallas")
+    ref = pa.paged_attention(q4, kp, vp, tables, pos, positions + 1,
+                             impl="xla")
+    assert calls == [1]
+    _assert_rounding_close(out, ref)
+    assert pa.prefill_kernel_takes(4, kp.astype(jnp.bfloat16))
+    assert not pa.prefill_kernel_takes(1, kp)
+    assert not pa.prefill_kernel_takes(4, kp.astype(jnp.float16))
+    odd = jnp.zeros((4, 8, 3, 16), jnp.bfloat16)
+    assert not pa.prefill_kernel_takes(4, odd)
+    with pytest.raises(ValueError, match="even number of KV heads"):
+        pa.paged_prefill_attention(
+            jnp.zeros((1, 4, 6, 16), jnp.bfloat16), odd, odd,
+            jnp.zeros((1, 2), jnp.int32), jnp.zeros(1, jnp.int32),
+            jnp.ones(1, jnp.int32), interpret=True)
 
 
 def test_dispatch_rejects_unknown_impl_and_multi_token_kernel():

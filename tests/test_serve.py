@@ -130,10 +130,9 @@ def test_mid_batch_join_matches_solo_run(model):
 
 
 def test_forced_pallas_impl_decodes_identical_tokens(model):
-    """attn_impl='pallas' forces the paged kernel for the decode steps
-    (interpret mode on CPU) while prefill chunks stay on the gather path
-    — the engine must complete and produce the auto path's tokens
-    bitwise."""
+    """attn_impl='pallas' forces the paged kernels, decode rounds and
+    prefill chunks alike (interpret mode on CPU) — the engine must
+    complete and produce the auto path's tokens bitwise."""
     cfg, params = model
     ref = Engine(params, cfg, _serve())
     refs = [ref.submit(p, g) for p, g in zip(PROMPTS, GENS)]
