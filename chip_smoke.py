@@ -2,8 +2,9 @@
 """Does the system still start on the chip? The quickest proof, end to end.
 
 ``python chip_smoke.py`` drives the main paths once on ONE TPU chip through
-the entry points a user would call, at the full width of the models the
-repo benchmarks (depth and step counts cut, weights random from a seed):
+the entry points a user would call, at widths that fill the chip's
+kernels (depth and step counts cut, weights random from a seed; the
+benchmark's own models are ``chipbench/configs/``):
 
 1. **device** — one ``jax.devices()``; anything but a TPU is refused;
 2. **cnn** — ``train.trainer.Trainer`` as ``scripts/train_data_parallel.py
@@ -12,7 +13,7 @@ repo benchmarks (depth and step counts cut, weights random from a seed):
    the telemetry header names the device. Once more with
    ``OptimizerConfig(fused=True)``: the compiled fused-SGD kernel's
    parameters after the same steps against the optax chain's;
-3. **lm** — ``train.lm_trainer.LMTrainer`` on ``bench.py``'s model (vocab
+3. **lm** — ``train.lm_trainer.LMTrainer`` on a long-context LM (vocab
    32 000, d_model 1024, 8 heads x 128, 8 layers, RoPE, bf16) at seq 8192,
    ``attn_impl="auto"``: the dispatch table picks the compiled flash
    forward and FA2 backward. Two steps at seq 2048, flash against XLA
@@ -56,7 +57,7 @@ import sys
 import tempfile
 import time
 
-# Sizes. FULL is the width the repo benchmarks; TINY is the rehearsal.
+# Sizes. FULL is the width the chip is proven at; TINY is the rehearsal.
 FULL = dict(
     cnn=dict(model="mobilenetv2", batch=512, steps_per_epoch=4, epochs=2),
     lm=dict(vocab=32_000, d_model=1024, heads=8, layers=8, d_ff=4096,
@@ -275,7 +276,7 @@ def phase_cnn(size: dict, workdir: str, dev) -> None:
 
 
 def lm_model(size: dict, seq: int, attn_impl: str, **kw):
-    """bench.py's LM (build_lm_bench), or its toy at --tiny."""
+    """The smoke's LM at ``size``: FULL's, or its toy at --tiny."""
     import jax.numpy as jnp
 
     from distributed_model_parallel_tpu.models import transformer as tfm
@@ -300,7 +301,7 @@ def lm_config(model, workdir: str, name: str, *, batch: int, seq: int,
 
 
 def two_steps(trainer) -> tuple[float, float]:
-    """Two optimizer steps on ONE batch, as bench.py drives the trainer.
+    """Two optimizer steps on ONE batch, through ``trainer._step``.
     The second loss depends on the first step's gradients, so it checks
     the backward pass too (at a random init the first loss is ~ln(vocab)
     whatever the attention computes)."""

@@ -8,8 +8,8 @@ alpha-beta comm/compute cost model whose comm terms are the SAME
 ring-model estimators ``ops/collectives.py`` accounts into telemetry at
 trace time and whose compute term reuses the public
 ``parallel/auto_partition`` compiled-FLOPs contract (cost_model.py),
-(3) optionally validates the analytic top-K with short measured steps
-through bench.py's shared workload builders (measure.py +
+(3) optionally validates the analytic top-K with short measured steps of
+an LMTrainer on each plan's mesh (measure.py, driven by
 scripts/dmp_plan.py), and (4) emits the chosen layout as a typed ``plan``
 telemetry record (planner.py).
 
@@ -30,6 +30,7 @@ from distributed_model_parallel_tpu.autotune.cost_model import (  # noqa: F401
     plan_cost,
 )
 from distributed_model_parallel_tpu.autotune.measure import (  # noqa: F401
+    lm_step_for_plan,
     measure_plans,
     time_step_fn,
 )

@@ -2,26 +2,25 @@
 
 The analytic ranking is only as good as its coefficients, so the planner
 can close the loop with measurements: ``scripts/dmp_plan.py --measure K``
-builds each of the analytic top-K plans through **bench.py's shared
-workload builders** (``build_lm_bench`` with a per-plan mesh override —
-the measured program IS the bench program, so the numbers are comparable
-with the bench's) and times a handful of dispatched steps with the same
-fetch-bracketed discipline as ``utils/profiling.time_step`` (see that
-module's docstring).
-
-This module holds only the timing harness; the bench-builder plumbing
-lives in ``scripts/dmp_plan.py`` (the repo-root ``bench`` module is a
-script, not a package member).
+builds each of the analytic top-K plans as an ``LMTrainer`` on the plan's
+own mesh (:func:`lm_step_for_plan`) and times a handful of dispatched
+steps with the same fetch-bracketed discipline as
+``utils/profiling.time_step`` (see that module's docstring).
 """
 
 from __future__ import annotations
 
+import os
+import tempfile
 import time
 from typing import Callable, Sequence
 
-from distributed_model_parallel_tpu.autotune.plan import ParallelPlan
+from distributed_model_parallel_tpu.autotune.plan import (
+    ParallelPlan,
+    mesh_from_plan,
+)
 
-__all__ = ["measure_plans", "time_step_fn"]
+__all__ = ["lm_step_for_plan", "measure_plans", "time_step_fn"]
 
 
 def time_step_fn(step: Callable[[], object], *, warmup: int = 1,
@@ -44,6 +43,43 @@ def time_step_fn(step: Callable[[], object], *, warmup: int = 1,
         out = step()
     fetch(out)
     return max(1e-9, time.perf_counter() - t0 - t_fetch) / max(1, iters)
+
+
+def lm_step_for_plan(model, plan: ParallelPlan, *, batch: int,
+                     seq: int) -> Callable[[], object]:
+    """``step()`` running one LM train step of ``model`` under ``plan``:
+    an ``LMTrainer`` on the plan's mesh (tensor/sequence/expert axes
+    switched on in the model config as the plan says), one sampled batch
+    fed to every call. ``step()`` returns the step's device metrics."""
+    import jax.numpy as jnp
+
+    from distributed_model_parallel_tpu.autotune.planner import (
+        lm_model_for_plan,
+    )
+    from distributed_model_parallel_tpu.train.lm_trainer import (
+        LMTrainConfig,
+        LMTrainer,
+    )
+
+    tmp = tempfile.gettempdir()
+    t = LMTrainer(LMTrainConfig(
+        model=lm_model_for_plan(model, plan),
+        batch_size=batch, seq_len=seq, n_tokens=4 * batch * (seq + 1),
+        # No held-out eval: at small batch the default 10% tail cannot
+        # fit one seq_len eval window.
+        eval_batches=0,
+        mesh=mesh_from_plan(plan),
+        num_microbatches=plan.num_microbatches,
+        log_dir=os.path.join(tmp, "dmp_plan_log"),
+        checkpoint_dir=os.path.join(tmp, "dmp_plan_ckpt")))
+    toks, tgts = (jnp.asarray(a) for a in t.sample_batch())
+
+    def step():
+        t.params, t.opt_state, m = t._step(t.params, t.opt_state,
+                                           toks, tgts)
+        return m
+
+    return step
 
 
 def measure_plans(plans: Sequence[ParallelPlan],

@@ -9,8 +9,7 @@ and the microbatch count when a pipeline axis is active. The same payload
 shape appears in three places so artifacts stay joinable:
 
 * the ``plan`` telemetry record (autotune/planner.emit_plan_record);
-* bench.py's headline JSON (every BENCH_*/MULTICHIP_* record embeds the
-  active plan, so artifacts are self-describing);
+* the trainers' ``/statusz`` payload (utils/statusz.register_trainer);
 * ``scripts/dmp_plan.py``'s ranked output.
 """
 
@@ -55,7 +54,7 @@ class ParallelPlan:
         return f"{self.strategy}[{degrees}]{tail}"
 
     def payload(self) -> dict:
-        """JSON payload shared by telemetry/bench/CLI (module docstring)."""
+        """JSON payload shared by telemetry/statusz/CLI (module docstring)."""
         return {"strategy": self.strategy, "axes": self.axes(),
                 "num_microbatches": self.num_microbatches}
 
@@ -78,10 +77,9 @@ def mesh_from_plan(plan: ParallelPlan,
 
 def plan_payload(mesh: MeshConfig, strategy: str, *,
                  num_microbatches: int = 1) -> dict:
-    """The plan payload for a run that already HAS a mesh (bench.py's
-    headline records): same shape as ``ParallelPlan.payload`` so the
-    planner's measured-validation records and the bench artifacts are one
-    schema."""
+    """The plan payload for a run that already HAS a mesh (a trainer's
+    ``/statusz`` entry): same shape as ``ParallelPlan.payload`` so the
+    planner's records and a live run's description are one schema."""
     return ParallelPlan(
         strategy=strategy, dp=mesh.data, pp=mesh.stage, tp=mesh.model,
         sp=mesh.seq, ep=mesh.expert,

@@ -315,7 +315,7 @@ class DevicePrefetchLoader:
     to a PrefetchLoader worker / generator source. Per-iteration transfer
     stats land in :attr:`last_stats` (``puts`` issued, ``max_lead`` =
     the largest number of uploaded-but-unconsumed batches observed) — the
-    no-silent-fallback proof bench.py's ``step_phase`` record carries.
+    proof that batches really were in flight (tests/test_perf_pipeline.py).
     """
 
     def __init__(self, loader: Iterable, put_fn, depth: int = 2):

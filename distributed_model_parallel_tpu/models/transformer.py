@@ -852,8 +852,8 @@ def _cached_block(bp: dict, ck: jax.Array, cv: jax.Array, layer: jax.Array,
 
 
 # Decode read-boundary segment size: each segment's scan reads the cache
-# prefix up to the next multiple of this. Shared with bench.py's decode
-# byte model — tune here and the published roofline stays honest.
+# prefix up to the next multiple of this, so a decode step's K/V bytes
+# follow the live length in steps of this size, not max_seq_len.
 DECODE_READ_SEG = 256
 
 

@@ -42,9 +42,8 @@ __all__ = [
 
 def compiled_flops_probe(fn, *args) -> float | None:
     """XLA's FLOP estimate for ``fn(*args)``, or None if unavailable
-    (loop bodies counted once, custom calls zero — see
-    ``utils/profiling.compiled_cost_analysis`` for the blind spots; valid
-    for the loop-free per-unit programs this module costs)."""
+    (loop bodies counted once, custom calls zero: valid for the
+    loop-free, kernel-free per-unit programs this module costs)."""
     try:
         analysis = jax.jit(fn).lower(*args).compile().cost_analysis()
         flops = analysis.get("flops", None)

@@ -41,7 +41,7 @@ package turns them into a serving engine:
   flash crowd, adversarial flood, mixed tenants) and the virtual
   :class:`~serve.traffic.SimClock` the chaos scenarios replay on.
 
-See docs/SERVING.md for the anatomy, the BENCH_serve recipe, the fleet
+See docs/SERVING.md for the anatomy, the fleet
 kill-drill recipe and the scenario catalog.
 """
 

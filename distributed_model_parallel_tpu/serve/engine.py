@@ -695,9 +695,9 @@ class Engine:
         """Drive the loop until every submitted request is terminal (or
         ``max_iterations``). Returns the summary dict (also emitted as
         the ``serve`` summary telemetry record unless
-        ``record_summary=False`` — multi-wave drivers like BENCH_serve's
-        chat mode run() per wave and record ONE campaign summary at the
-        end instead of one per wave)."""
+        ``record_summary=False`` — a multi-wave driver (a chat campaign,
+        one wave a turn) calls run() per wave and records ONE campaign
+        summary at the end instead of one per wave)."""
         t0 = self._clock()
         try:
             # Spans from the loop (prefill chunks, decode rounds,
@@ -749,7 +749,7 @@ class Engine:
             raise EngineKilled(
                 f"engine died at iteration {self._iterations}; "
                 f"in-flight requests marked failed") from e
-        # Accumulate: a multi-turn driver (BENCH_serve chat mode) calls
+        # Accumulate: a multi-turn driver (a chat campaign) calls
         # run() per wave and reads one whole-campaign summary at the end.
         self._wall_s += self._clock() - t0
         return self.summary(record=record_summary)
@@ -1499,7 +1499,7 @@ class Engine:
                 / (self._decode_steps * self.serve.n_slots)
                 if self._decode_steps else None),
             # Prefix-cache reuse + speculative decoding (docs/SERVING.md;
-            # BENCH_serve chat mode gates on these).
+            # tests/test_spec_decode.py reads these).
             "prefix_cache": self.serve.prefix_cache,
             "spec_k": self.serve.spec_k,
             "cache_hit_rate": self.cache_hit_rate,

@@ -29,7 +29,7 @@ calls :meth:`kill_replica`), the replica is **drained, not killed**:
 Because a request's tokens are a pure function of (prompt, seed) — the
 engine's pinned determinism contract — a migrated request's remaining
 tokens are **bitwise identical** to an unmigrated run, and the chaos
-drill (tests/test_fleet.py, BENCH_serve fleet mode) asserts exactly
+drill (tests/test_fleet.py, scripts/dmp_soak.py) asserts exactly
 that. Once the sentinel reinstates the devices (or ``revive_after``
 rounds pass in drill mode), the replica **grows back**: it re-claims its
 exact device slice (``DevicePool.assign_ids``) and the router resumes
@@ -123,7 +123,7 @@ class ServeFleet:
         if serve.policy != "continuous":
             raise ValueError(
                 "the fleet runs continuous-batching replicas; the static "
-                "baseline exists for single-engine BENCH_serve comparisons")
+                "baseline exists for single-engine comparisons")
         if pool is None:
             from distributed_model_parallel_tpu.orchestrator.scheduler import (
                 DevicePool,
@@ -287,9 +287,9 @@ class ServeFleet:
         self._kills = 0
         # Hard-crash accounting (serve/journal.py crash recovery):
         # crashes fired, requests re-admitted from the journal, and the
-        # cumulative monotonic recovery-pass duration — the
-        # ``recovery_time_s`` BENCH_serve crash drills gate on
-        # (utils/baseline.py GATE_METRICS, lower-better).
+        # cumulative monotonic recovery-pass duration
+        # (``recovery_time_s``, which the crash-recovery drill of
+        # scripts/dmp_soak.py reports).
         self._crashes = 0
         self._crash_recovered = 0
         self.recovery_time_s = 0.0

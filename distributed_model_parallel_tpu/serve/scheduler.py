@@ -21,7 +21,7 @@ is exactly the backpressure the queue-wait histogram measures.
 Head-of-line blocking (rather than skipping to a smaller request) keeps
 admission deterministic and starvation-free.
 
-``policy="static"`` is the baseline BENCH_serve compares against: the
+``policy="static"`` is the baseline to compare against: the
 same engine, but admission only refills when the **whole** batch has
 drained — a finished sequence's slot idles until the last co-resident
 request completes. The throughput gap between the two policies on the
@@ -413,7 +413,7 @@ class Scheduler:
             if req.t_admitted is None:
                 # First admission only: a migrated request keeps its
                 # original admission stamp — queue-wait and the
-                # pre/post-kill TTFT split in BENCH_serve fleet mode
+                # pre/post-kill TTFT split of a fleet kill drill
                 # both mean "when did this request first get a slot",
                 # not "when did it land on its latest replica".
                 req.t_admitted = now
@@ -501,7 +501,7 @@ class Scheduler:
 
 def summarize(values: list[float]) -> dict[str, Any]:
     """p50/p99/mean/max over a host-side sample list (exact, sorted —
-    the SLO numbers BENCH_serve publishes; registry histograms carry the
+    the SLO numbers ``Engine.summary`` reports; registry histograms carry the
     same samples as bucketed estimates for the telemetry stream)."""
     if not values:
         return {"count": 0}

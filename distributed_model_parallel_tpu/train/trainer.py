@@ -655,7 +655,7 @@ class Trainer:
             # prefetcher keeping depth extra batches resident, that is
             # the difference between depth+1 and 2*depth live batches.
             # utils/profiling.assert_donation is the trace-time proof the
-            # state aliasing actually held (perf smoke + bench.py).
+            # state aliasing actually held (tests/test_perf_pipeline.py).
             self._train_step = jax.jit(
                 make_train_step(self.model, self.tx, ema_decay=ema,
                                 augment=config.data.augment, **kw),

@@ -1,10 +1,10 @@
 """SLO burn-rate alerts: declarative rules over the live telemetry.
 
-The regression gate (utils/baseline.py) judges a *finished* run; an
-operator watching a fleet needs the same judgment *while it runs*. This
-module evaluates declarative rules against the records a campaign is
-writing right now — fed either directly (:meth:`AlertEngine.observe`)
-or by live-tailing streams across rotations
+A report judges a *finished* run; an operator watching a fleet needs a
+judgment *while it runs*. This module evaluates declarative rules
+against the records a campaign is writing right now — fed either
+directly (:meth:`AlertEngine.observe`) or by live-tailing streams across
+rotations
 (:meth:`AlertEngine.watch` + :meth:`AlertEngine.poll`, built on
 :class:`~.telemetry.StreamFollower`) — and emits **deduplicated typed
 ``alert`` records**: one ``firing`` record when a rule first breaches,
@@ -14,9 +14,8 @@ Rules (each scoped per subject — per tenant for step/serve signals —
 so one slow tenant cannot hide behind a fast fleet median):
 
 * :class:`StepTimeDrift` — recent step-time p50 vs a reference: the
-  baseline-ledger band when the run has history
-  (:func:`step_time_reference_from_ledger`, the PR-11 ledger), else a
-  self-baseline from the run's own first healthy window. Fires when
+  rule's ``reference_s`` when the caller gives one, else a self-baseline
+  from the run's own first healthy window. Fires when
   ``p50 > max(ref * factor, ref + min_drift_s)`` (the absolute floor
   keeps millisecond CPU jitter from ever firing).
 * :class:`BurnRate` — classic multiwindow burn rate over serve SLOs
@@ -52,25 +51,7 @@ __all__ = [
     "HealthFloor",
     "StepTimeDrift",
     "default_rules",
-    "step_time_reference_from_ledger",
 ]
-
-
-def step_time_reference_from_ledger(path: str,
-                                    key: str | None = None) -> float | None:
-    """A step-time reference from the PR-11 baseline ledger
-    (utils/baseline.py): the median ``step_time_p50_s`` over the last 8
-    green entries (of ``key`` when given, any key otherwise). None when
-    the ledger has no usable history — the drift rule then falls back
-    to its self-baseline."""
-    from distributed_model_parallel_tpu.utils.baseline import load_ledger
-
-    vals = [e["metrics"]["step_time_p50_s"]
-            for e in load_ledger(path)
-            if e.get("green") and (key is None or e.get("key") == key)
-            and isinstance((e.get("metrics") or {}).get("step_time_p50_s"),
-                           (int, float))]
-    return median(vals[-8:]) if vals else None
 
 
 # ---------------------------------------------------------------------------
@@ -87,7 +68,7 @@ class StepTimeDrift:
     baseline_n: int = 4           # self-baseline: first N samples' median
     factor: float = 3.0           # fire when p50 > ref * factor ...
     min_drift_s: float = 0.05     # ... and p50 > ref + this (jitter floor)
-    reference_s: float | None = None    # ledger band override
+    reference_s: float | None = None    # given band, else self-baseline
 
     def make_state(self) -> dict:
         return {"recent": deque(maxlen=self.window), "baseline": []}
@@ -229,15 +210,10 @@ class HealthFloor:
             "device": worst_id}
 
 
-def default_rules(*, ledger_path: str | None = None,
-                  ledger_key: str | None = None) -> list:
-    """The orchestrator's default rule set. With a ledger path, the
-    drift rule anchors to the committed baseline band instead of the
-    run's own first window."""
-    ref = (step_time_reference_from_ledger(ledger_path, ledger_key)
-           if ledger_path else None)
+def default_rules() -> list:
+    """The orchestrator's default rule set."""
     return [
-        StepTimeDrift(reference_s=ref),
+        StepTimeDrift(),
         BurnRate(metric="ttft_s"),
         BurnRate(metric="token_latency_s", target_s=0.2),
         GaugeCeiling(),

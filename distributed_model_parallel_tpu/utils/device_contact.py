@@ -1,5 +1,5 @@
-"""First device contact, shared by chip_smoke.py, bench.py and the CLI
-drivers: one attempt, and no run without the device it was meant for.
+"""First device contact, shared by chip_smoke.py and the CLI drivers:
+one attempt, and no run without the device it was meant for.
 
 With no chip and ``JAX_PLATFORMS`` unset, JAX quietly hands out the CPU,
 and a trainer or a benchmark would carry on there and print numbers that

@@ -28,8 +28,8 @@ import numpy as np
 
 
 # Published bf16 peak matmul throughput per chip (FLOP/s), keyed by
-# device_kind prefix. Used to turn measured step time + XLA cost-analysis
-# FLOPs into model-FLOPs-utilization (MFU) — an absolute efficiency number,
+# device_kind prefix. Used to turn measured step time + a step's FLOPs
+# into model-FLOPs-utilization (MFU) — an absolute efficiency number,
 # unlike throughput ratios against a historical baseline.
 # Source: Google Cloud TPU documentation, one page per generation; the
 # chip this repo is measured on is "TPU v5e": 197 TFLOP/s bf16, 819 GB/s
@@ -82,65 +82,20 @@ def match_device_kind(table: dict, device=None, *, kind: str | None = None):
     return None
 
 
-def _chip_peak(table: dict, device, what: str) -> float | None:
-    """``table``'s row for ``device`` (default: devices()[0]). None on the
+def peak_flops_per_chip(device=None) -> float | None:
+    """bf16 peak FLOP/s for ``device`` (default: devices()[0]). None on the
     CPU only, where no utilization is reported; a TPU that is not in the
     table is an error, never a default."""
     device = device if device is not None else jax.devices()[0]
     if device.platform == "cpu":
         return None
-    peak = match_device_kind(table, device)
+    peak = match_device_kind(TPU_PEAK_FLOPS, device)
     if peak is None:
         raise ValueError(
-            f"no published {what} for device_kind "
+            f"no published bf16 peak FLOP/s for device_kind "
             f"{device.device_kind!r} ({device.platform}); add its row, "
             f"with the source, to utils/profiling.py")
     return peak
-
-
-def peak_flops_per_chip(device=None) -> float | None:
-    """bf16 peak FLOP/s for ``device`` (see :func:`_chip_peak`)."""
-    return _chip_peak(TPU_PEAK_FLOPS, device, "bf16 peak FLOP/s")
-
-
-def compiled_cost_analysis(jitted: Callable, *args) -> dict:
-    """XLA cost analysis of the compiled program for ``jitted(*args)``
-    (client-side on the HLO — no execution, no donation). One AOT compile
-    serves every metric read from it.
-
-    Two blind spots make the numbers unusable for programs that contain
-    loops or pallas kernels (both verified on v5e, see the round-3 notes
-    in bench.py):
-
-    * ``lax.scan`` / ``while`` bodies are counted ONCE, not trip-count
-      times — a stacked-blocks decoder reports 1/L of its dense math, a
-      scanned multi-step program reports 1 step.
-    * Custom calls (pallas kernels) have no registered cost and
-      contribute zero — flash attention's score/value matmuls vanish.
-
-    Use it only on loop-free, kernel-free programs (e.g. the CNN single
-    train step), or as a lower-bound cross-check next to an analytic
-    count such as :func:`lm_model_flops`."""
-    return cost_analysis_of(jitted.lower(*args).compile())
-
-
-def cost_analysis_of(compiled) -> dict:
-    """Cost analysis of an already-compiled program (see
-    :func:`compiled_cost_analysis` for the blind spots). A compile or
-    analysis failure propagates: a metric that cannot be computed is an
-    error on the measurement path, not an empty dict."""
-    return dict(compiled.cost_analysis() or {})
-
-
-def compiled_flops(jitted: Callable, *args) -> float | None:
-    """Total FLOPs per :func:`compiled_cost_analysis` (see its caveats)."""
-    flops = compiled_cost_analysis(jitted, *args).get("flops")
-    return float(flops) if flops else None
-
-
-def peak_hbm_bytes_per_chip(device=None) -> float | None:
-    """HBM bandwidth (bytes/s) for ``device`` (see :func:`_chip_peak`)."""
-    return _chip_peak(TPU_PEAK_HBM_BYTES, device, "HBM bandwidth")
 
 
 # ---------------------------------------------------------------------------
@@ -171,8 +126,7 @@ _DROPPED_AVAL_RE = re.compile(r"\b[a-z]+\d*\[[\d,]*\]")
 
 def aot_compile(jitted: Callable, *args, **kwargs):
     """``jitted.lower(*args).compile()`` with lowering warnings captured:
-    returns ``(compiled, warnings_list)``. One AOT compile serves cost
-    analysis AND the donation report (bench.py does both from it).
+    returns ``(compiled, warnings_list)`` for :func:`donation_report`.
     ``args`` may be concrete arrays or ``jax.ShapeDtypeStruct``s."""
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
@@ -255,11 +209,9 @@ def demand_frac_of_peak(bytes_per_s: float | None,
     """Demand-side bytes rate as a fraction of the physical HBM peak —
     or ``(None, reason)`` when the fraction exceeds 1.0: a demand
     estimate above the DMA ceiling is an op-level byte-accounting
-    overcount (VMEM-reused values billed once per use — see
-    :func:`bytes_accessed_of`), not a measurement, and must not be
-    published as one.
-    The single policy point for bench.py AND scripts/dmp_report.py, so
-    the threshold and explanation cannot drift apart. The GB/s demand
+    overcount (XLA's op-level "bytes accessed" bills a value kept in
+    VMEM once per use), not a measurement, and must not be published as
+    one. The policy point of scripts/dmp_report.py. The GB/s demand
     number stays honest as *demand*; only the roofline *position* is
     refused."""
     if not bytes_per_s or not peak_bytes_per_s:
@@ -269,24 +221,8 @@ def demand_frac_of_peak(bytes_per_s: float | None,
         return None, (f"demand {bytes_per_s / 1e9:.0f} GB/s exceeds the "
                       f"{peak_bytes_per_s / 1e9:.0f} GB/s physical peak "
                       f"({frac:.2f}x): op-level byte accounting overcount, "
-                      f"not a DMA rate — see benchmarks/run_step_profile.py "
-                      f"for the measured-timeline roofline")
+                      f"not a DMA rate")
     return round(frac, 3), None
-
-
-def bytes_accessed_of(ca: dict) -> float | None:
-    """"bytes accessed" from a :func:`compiled_cost_analysis` dict.
-
-    Same caveats as the flops count (scan bodies counted once, custom
-    calls zero), plus one of its own: "bytes accessed" is the op-level
-    sum over the optimized HLO — post-fusion, so fused producers don't
-    round-trip HBM in the count, but values XLA keeps in registers/VMEM
-    across ops still count once per use. Treat it as the demand-side
-    estimate a bandwidth roofline needs, not a hardware counter — on the
-    32px CNN step it EXCEEDS the HBM peak, which is itself the proof the
-    step is bandwidth-saturated."""
-    val = ca.get("bytes accessed")
-    return float(val) if val else None
 
 
 def lm_model_flops(cfg, batch: int, seq: int, causal: bool = True) -> float:
@@ -294,8 +230,8 @@ def lm_model_flops(cfg, batch: int, seq: int, causal: bool = True) -> float:
     train step at ``batch`` sequences of ``seq`` tokens.
 
     XLA's cost analysis cannot produce this number for the real program
-    (scan bodies counted once, pallas custom calls counted zero — see
-    :func:`compiled_flops`), so MFU uses the standard analytic count:
+    (scan bodies counted once, pallas custom calls counted zero), so MFU
+    uses the standard analytic count:
 
     * dense matmuls: ``6 * N_mm * tokens`` where ``N_mm`` is the matmul
       parameter count touched per token (q/kv/o projections, MLP or the

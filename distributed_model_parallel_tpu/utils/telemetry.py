@@ -87,14 +87,9 @@ span       name, t0 (wall-clock start), dur_s (monotonic duration),
            and decode rounds, orchestrator rounds; ``ts`` is the
            wall-clock end. scripts/dmp_trace.py renders these as a
            zoomable Chrome/Perfetto timeline
-gate       ok, regressions [{metric, value, baseline, tolerance}],
-           attribution {span|phase, share, baseline_share} — one
-           cross-run perf-regression-gate verdict (utils/baseline.py,
-           scripts/dmp_gate.py) comparing this run's headline metrics
-           against the baseline ledger's noise band
 alert      rule, subject, state (firing | resolved), value, threshold,
            plus per-rule detail — one DEDUPLICATED SLO-alert transition
-           (utils/alerts.py): step-time drift vs the baseline band,
+           (utils/alerts.py): step-time drift vs the run's own first window,
            serve burn rate, page saturation, health floor; written by
            the orchestrator's control loop, fsync'd on write
 postmortem reason, bundle (directory path), n_records, error — the
@@ -970,7 +965,7 @@ class TelemetryRun:
         self._bytes = 0
 
     def step(self, **fields) -> None:
-        """One training/bench step (or drain window) worth of timings.
+        """One training step (or drain window) worth of timings.
         Conventional keys: epoch, step, step_time_s, data_time_s, loss,
         samples_per_s or tokens_per_s. Step times also feed a run-local
         ``step_time_s`` histogram, so the final metrics record carries

@@ -11,9 +11,9 @@ One command over the autotuner core (``autotune/``, docs/AUTOTUNE.md):
   record when the constraints admit no layout — the CI smoke pins both
   contracts (tests/test_autotune.py).
 * ``--measure K`` — additionally time the analytic top-K candidates with
-  short real steps through **bench.py's shared workload builders**
-  (``build_lm_bench`` with per-plan mesh overrides), letting the
-  measurement overrule the model. Needs the devices to actually exist
+  short real steps of an ``LMTrainer`` on each plan's own mesh
+  (``autotune/measure.lm_step_for_plan``), letting the measurement
+  overrule the model. Needs the devices to actually exist
   (``--devices`` spawns virtual CPU devices via scripts/_cpu_devices.py
   when JAX_PLATFORMS=cpu).
 
@@ -69,8 +69,8 @@ def parse_args(argv=None) -> argparse.Namespace:
     p.add_argument("--dry-run", action="store_true",
                    help="analytic only — no device programs built")
     p.add_argument("--measure", type=int, default=0, metavar="K",
-                   help="time the analytic top-K through bench.py's "
-                        "builders; measured-best wins")
+                   help="time the analytic top-K with short real steps; "
+                        "measured-best wins")
     p.add_argument("--measure-steps", type=int, default=2)
     return p.parse_args(argv)
 
@@ -96,22 +96,16 @@ def _build_workload(args):
 
 
 def _lm_measure_fn(args, model_cfg):
-    """Per-plan measured seconds/step through bench.build_lm_bench — the
-    planner's measured validation rides the SAME builder the BENCH_lm
-    artifacts come from (module docstring)."""
-    import bench
+    """Per-plan measured seconds/step of an LMTrainer on the plan's mesh
+    (autotune/measure.lm_step_for_plan)."""
     from distributed_model_parallel_tpu.autotune import (
-        lm_model_for_plan,
-        mesh_from_plan,
+        lm_step_for_plan,
         time_step_fn,
     )
 
     def measure(plan):
-        _, step, _ = bench.build_lm_bench(
-            mesh=mesh_from_plan(plan), model=lm_model_for_plan(model_cfg,
-                                                               plan),
-            batch=args.batch, seq=args.seq, steps=args.measure_steps,
-            num_microbatches=plan.num_microbatches)
+        step = lm_step_for_plan(model_cfg, plan, batch=args.batch,
+                                seq=args.seq)
         return time_step_fn(step, warmup=1, iters=args.measure_steps)
 
     return measure
@@ -136,8 +130,8 @@ def main(argv=None) -> None:
     if args.measure > 0:
         if args.workload != "lm":
             raise SystemExit(
-                "--measure currently drives bench.build_lm_bench; use "
-                "--workload lm (the cnn path ranks analytically)")
+                "--measure times LM candidates only; use --workload lm "
+                "(the cnn path ranks analytically)")
         import jax
 
         if len(jax.devices()) < args.devices:

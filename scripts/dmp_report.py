@@ -2,11 +2,11 @@
 """Run report: one command that answers "why is this step slow".
 
 Joins a run's telemetry JSONL (utils/telemetry.TelemetryRun — written by
-every trainer via RunLogger and by bench.py) with an optional xplane trace
-directory (utils/xplane op breakdown) and prints:
+every trainer via RunLogger and by the serving engine) with an optional
+xplane trace directory (utils/xplane op breakdown) and prints:
 
 * step-time percentiles (p50/p90/p99) and throughput from ``step`` records;
-* step phase breakdown (``step_phase`` records from bench.py): host-input /
+* step phase breakdown (``step_phase`` records): host-input /
   h2d / device seconds per step + the pipeline-active proof (device
   prefetch lead, donation aliases, grad bucketing, fused optimizer) —
   "phase timing unavailable" on runs that could not attribute (CPU);
@@ -16,7 +16,7 @@ directory (utils/xplane op breakdown) and prints:
 * serving SLOs (``serve`` records from serve/engine.py — per-request
   TTFT / queue-wait / per-token-latency percentiles, tokens/s, slot
   utilization, page-pool occupancy per engine run) on streams written by
-  BENCH_serve or any engine with a telemetry stream attached;
+  any engine with a telemetry stream attached;
 * MFU against the profiling.py peak tables — or an honest "MFU unavailable"
   line when the device has no peak entry (CPU) or the run recorded no FLOPs;
 * HBM-roofline position when the run recorded demand bytes;
@@ -26,10 +26,8 @@ directory (utils/xplane op breakdown) and prints:
 * the parallelism-plan timeline (``plan`` records from the autotuner,
   autotune/planner.py): chosen layout, cost breakdown, alternatives, and
   the global step each (re-)plan landed at;
-* the span-time rollup (``span`` records, utils/tracing.py) and the
-  latest regression-gate verdict (``gate`` records, utils/baseline.py)
-  — the zoomable versions are scripts/dmp_trace.py and
-  scripts/dmp_gate.py (docs/TRACING.md);
+* the span-time rollup (``span`` records, utils/tracing.py) — the
+  zoomable version is scripts/dmp_trace.py (docs/TRACING.md);
 * device memory watermarks and recompilation counts;
 * the failure/recovery/divergence timeline (injected faults, non-finite
   restores, stall escalations, torn-checkpoint fallbacks, cross-replica
@@ -139,8 +137,8 @@ def _mfu_section(lines: list[str], meta: dict, device: dict,
     kind = device.get("device_kind", "") or device.get("platform", "?")
     n_dev = max(1, int(device.get("n_devices", 1) or 1))
     peak = match_device_kind(TPU_PEAK_FLOPS, kind=kind)
-    # Global analytic FLOPs (trainer/LM-bench meta) or per-device
-    # cost-analysis FLOPs (CNN bench "cost_analysis" record).
+    # Global analytic FLOPs (the LM trainer's run meta) or per-device
+    # cost-analysis FLOPs (a "cost_analysis" record).
     flops_global = meta.get("model_flops_per_step")
     ca = (by_kind.get("cost_analysis") or [{}])[-1]
     flops_device = ca.get("device_flops_per_step")
@@ -151,7 +149,7 @@ def _mfu_section(lines: list[str], meta: dict, device: dict,
                      f"device_kind={kind!r} — expected on CPU)")
     elif not (flops_global or flops_device):
         lines.append("MFU unavailable (run recorded no FLOPs-per-step; the "
-                     "LM trainer and bench.py record them)")
+                     "LM trainer records them)")
     else:
         t50 = percentile(times, 50)
         per_chip = (flops_device if flops_device
@@ -171,9 +169,8 @@ def _mfu_section(lines: list[str], meta: dict, device: dict,
         frac, frac_err = demand_frac_of_peak(rate, hbm_peak)
         if frac_err:
             # A fraction of the physical peak > 1 is not a roofline
-            # position, it is proof the measurement overcounted
-            # (BENCH_r04 published 1.457x as fact) — the shared policy
-            # in utils/profiling.demand_frac_of_peak refuses it.
+            # position, it is proof the measurement overcounted — the
+            # policy in utils/profiling.demand_frac_of_peak refuses it.
             lines.append(f"HBM roofline: MEASUREMENT ERROR — {frac_err}")
         else:
             lines.append(
@@ -187,7 +184,7 @@ def _mfu_section(lines: list[str], meta: dict, device: dict,
 
 
 def _phase_section(lines: list[str], by_kind: dict) -> None:
-    """Step phase breakdown (bench.py ``step_phase`` records): where a
+    """Step phase breakdown (``step_phase`` records): where a
     step's wall time goes — host batch assembly, host→device transfer,
     device compute — plus the no-silent-fallback proof that the raw-speed
     levers (device prefetch, donation, bucketed grads, fused optimizer)
@@ -230,8 +227,8 @@ def _phase_section(lines: list[str], by_kind: dict) -> None:
         lines.append("phase timing unavailable"
                      + (f" ({r.get('reason')})" if r.get("reason") else ""))
         return
-    # Training records carry host-input/h2d/device; the decode bench's
-    # record carries prefill/decode_token/sample — render whatever
+    # Training records carry host-input/h2d/device; a decode record
+    # carries prefill/decode_token/sample — render whatever
     # ``*_s`` phases the record holds, in record order.
     keys = [k for k in phases
             if k.endswith("_s") and isinstance(phases.get(k), (int, float))]
@@ -255,8 +252,8 @@ def _serving_section(lines: list[str], by_kind: dict) -> None:
     (serve/engine.py): per-request TTFT / queue wait / per-token latency
     percentiles over the completed requests, failures, and each engine
     run's summary line (policy, tokens/s, slot utilization, page-pool
-    occupancy) — BENCH_serve writes one summary per policy, so the
-    continuous-vs-static comparison reads directly off this section."""
+    occupancy) — one summary per engine run, so runs of two policies on
+    one stream read side by side."""
     recs = by_kind.get("serve") or []
     sheds = by_kind.get("shed") or []
     brownouts = by_kind.get("brownout") or []
@@ -293,9 +290,9 @@ def _serving_section(lines: list[str], by_kind: dict) -> None:
             last[str(r.get("replica"))] = str(r.get("state"))
         lines.append("breaker: " + f"{opens} opens   " + "  ".join(
             f"{k}={v}" for k, v in sorted(last.items())))
-    # One percentile block PER POLICY: BENCH_serve writes both the
-    # continuous and the static runs' per-request records onto one
-    # stream, and a blended percentile would describe neither run.
+    # One percentile block PER POLICY: a stream may hold the
+    # per-request records of a continuous and a static run, and a
+    # blended percentile would describe neither.
     policies = sorted({str(r.get("policy")) for r in completed})
     for policy in policies:
         rows = [r for r in completed if str(r.get("policy")) == policy]
@@ -610,33 +607,6 @@ def _spans_section(lines: list[str], by_kind: dict) -> None:
                  "python scripts/dmp_trace.py <stream> -o trace.json)")
 
 
-def _gate_section(lines: list[str], by_kind: dict) -> None:
-    """Regression-gate verdicts (``gate`` records, utils/baseline.py +
-    scripts/dmp_gate.py): pass/fail per headline metric against the
-    baseline ledger's noise band, with the span/phase attribution."""
-    gates = by_kind.get("gate") or []
-    if not gates:
-        return
-    r = gates[-1]
-    regs = r.get("regressions") or []
-    lines.append(f"== regression gate "
-                 f"({'PASS' if r.get('ok') else 'REGRESSION'}, "
-                 f"{len(r.get('verdicts') or [])} metrics checked vs "
-                 f"{r.get('ledger')}) ==")
-    for v in regs:
-        lines.append(f"  REGRESSED {v.get('metric')}: {v.get('value')} vs "
-                      f"baseline {v.get('baseline')} "
-                      f"± {v.get('tolerance')}")
-        attr = v.get("attribution") or {}
-        where = attr.get("span") or attr.get("phase")
-        if where:
-            lines.append(f"      -> {where!r} grew "
-                         f"{attr.get('baseline_share')} -> "
-                         f"{attr.get('share')} of the run")
-    for key in r.get("no_baseline") or []:
-        lines.append(f"  (no baseline for {key} — first run of this key)")
-
-
 def _comm_section(lines: list[str], by_kind: dict) -> None:
     snaps = by_kind.get("metrics") or []
     counters = snaps[-1].get("counters", {}) if snaps else {}
@@ -820,7 +790,6 @@ def build_report(records: list[dict], *, trace_dir: str | None = None,
     _rtrace_section(lines, by_kind)
     _plan_section(lines, by_kind)
     _spans_section(lines, by_kind)
-    _gate_section(lines, by_kind)
     _comm_section(lines, by_kind)
     _memory_section(lines, by_kind)
     _resilience_section(lines, by_kind)
@@ -863,7 +832,7 @@ def build_report_data(records: list[dict]) -> dict:
     """The report as one JSON-ready dict — sections as keys — so CI and
     the cockpit consume reports without screen-scraping. The section
     keys and the inner shapes of ``headline`` / ``resilience`` /
-    ``serving`` / ``gate`` are a pinned schema
+    ``serving`` are a pinned schema
     (tests/test_report_json.py): additions are fine, renames and
     removals are breaking."""
     by_kind = _by_kind(records)
@@ -919,15 +888,6 @@ def build_report_data(records: list[dict]) -> dict:
         "brownout": by_kind.get("brownout") or [],
         "breaker": by_kind.get("breaker") or [],
     }
-    gates = by_kind.get("gate") or []
-    gate = None
-    if gates:
-        g = gates[-1]
-        gate = {"ok": g.get("ok"),
-                "regressions": g.get("regressions") or [],
-                "verdicts": g.get("verdicts") or [],
-                "no_baseline": g.get("no_baseline") or [],
-                "ledger": g.get("ledger")}
     spans: dict[str, dict] = {}
     for r in by_kind.get("span") or []:
         d = r.get("dur_s")
@@ -947,7 +907,6 @@ def build_report_data(records: list[dict]) -> dict:
         "serving": serving,
         "rtrace": _rtrace_summary(by_kind),
         "capacity": _capacity_data(records, by_kind),
-        "gate": gate,
         "plan": by_kind.get("plan") or [],
         "spans": spans,
         "alerts": alerts,
@@ -1208,7 +1167,7 @@ def main(argv=None) -> None:
         description="Render a run report from a telemetry JSONL stream")
     p.add_argument("jsonl", nargs="+",
                    help="telemetry stream(s) (RunLogger's "
-                        "{log_dir}/{name}.jsonl or DMP_TELEMETRY); several "
+                        "{log_dir}/{name}.jsonl); several "
                         "streams (or --fleet) render the merged "
                         "multi-tenant fleet report")
     p.add_argument("--fleet", action="store_true",
@@ -1222,7 +1181,7 @@ def main(argv=None) -> None:
     p.add_argument("--json", action="store_true",
                    help="emit the report as machine-readable JSON "
                         "(sections as keys; stable schema for the "
-                        "headline/resilience/serving/gate sections) "
+                        "headline/resilience/serving sections) "
                         "instead of the text renderer")
     args = p.parse_args(argv)
     for path in args.jsonl:

@@ -11,7 +11,7 @@ One zoomable timeline from the typed records the stack already writes:
   queue → prefill → decode segments reconstructed from the record's
   queue_wait/ttft/wall accounting, one row per request;
 * point records (failure, recovery, fault, consistency, resume, tenant,
-  health, gate) become instant events on their lane;
+  health, plan) become instant events on their lane;
 * ``step`` records become counter tracks (step_time_ms, throughput).
 
 Lanes: one Chrome "process" per tenant (untagged records share the
@@ -50,7 +50,6 @@ INSTANT_KINDS = {
     "tenant": "event",
     "health": "event",
     "event": "message",
-    "gate": "ok",
     "plan": "strategy",
 }
 

@@ -263,6 +263,41 @@ SLOW_TESTS = {
 }
 
 
+# ---------------------------------------------------------------------------
+# The benchmark's own tests ride with this suite. chipbench/tests guards
+# what decides every PR (the fault and control tests of ``correct``, the
+# cells-as-files contract, the FLOP and byte functions, the trace readers),
+# and the tier-1 command names ``tests/`` only: a run that names this
+# directory whole collects that one too. A run of single files does not.
+# ---------------------------------------------------------------------------
+
+_TESTS_DIR = os.path.dirname(os.path.abspath(__file__))
+CHIPBENCH_TESTS = os.path.join(os.path.dirname(_TESTS_DIR), "chipbench",
+                               "tests")
+
+# Left out of such a run, not of ``pytest chipbench/tests``: both compare
+# whatever a 0.5-1 s window of serving finished, and beside six busy workers
+# that is other (or too few) tokens, so each failed once in three runs and
+# passed alone. Their repair is a benchmark PR's (PERF.md section 7).
+UNSTEADY_UNDER_LOAD = (
+    "chipbench/tests/test_faults.py"
+    "::test_int8_control_reads_above_the_program_serving",
+    "chipbench/tests/test_model_types.py"
+    "::test_counters_of_the_routed_layers_reach_the_run",
+)
+
+
+def pytest_configure(config):
+    # xdist workers get the controller's args and options, these included.
+    named = [os.path.abspath(os.path.join(str(config.invocation_params.dir),
+                                          a.split("::")[0]))
+             for a in config.args]
+    if _TESTS_DIR in named and CHIPBENCH_TESTS not in named:
+        config.args.append(CHIPBENCH_TESTS)
+        config.option.deselect = [*(config.option.deselect or ()),
+                                  *UNSTEADY_UNDER_LOAD]
+
+
 def pytest_collection_modifyitems(config, items):
     for item in items:
         fname = item.path.name

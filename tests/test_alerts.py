@@ -1,9 +1,7 @@
 """utils/alerts.py: the SLO alert engine — rule semantics (step-time
 drift fire/resolve, multiwindow burn rate, gauge ceiling, health
 floor), dedup (one record per transition), per-tenant scoping, the
-stream live-tail ingest, and the ledger-anchored drift reference."""
-
-import json
+stream live-tail ingest, and a given drift reference."""
 
 import pytest
 
@@ -84,20 +82,11 @@ def test_drift_is_per_tenant():
     assert [(e["subject"], e["state"]) for e in ev] == [("slow", "firing")]
 
 
-def test_drift_uses_ledger_reference_when_given(tmp_path):
-    ledger = tmp_path / "ledger.jsonl"
-    with open(ledger, "w") as f:
-        for v in (0.10, 0.11, 0.09):
-            f.write(json.dumps({"green": True, "key": "k",
-                                "metrics": {"step_time_p50_s": v}}) + "\n")
-        f.write(json.dumps({"green": False, "key": "k",
-                            "metrics": {"step_time_p50_s": 9.0}}) + "\n")
-    ref = alerts.step_time_reference_from_ledger(str(ledger))
-    assert ref == 0.10                        # median of GREEN entries only
-    eng = AlertEngine([StepTimeDrift(window=2, reference_s=ref,
+def test_drift_uses_given_reference():
+    eng = AlertEngine([StepTimeDrift(window=2, reference_s=0.1,
                                      factor=2.0, min_drift_s=0.05)])
     ts = 0.0
-    for t in (0.5, 0.5):                      # 5x the committed band
+    for t in (0.5, 0.5):                      # 5x the given band
         ts += 1
         _step(eng, ts, t)
     ev = eng.tick()

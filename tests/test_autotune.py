@@ -524,8 +524,8 @@ def test_dmp_plan_cnn_dry_run():
 
 @pytest.mark.slow
 def test_dmp_plan_measured_validation(devices):
-    """--measure K drives bench.build_lm_bench per candidate (mesh
-    override) and the measured-best wins — the acceptance mechanism for
+    """--measure K times an LMTrainer step per candidate (on the plan's
+    own mesh) and the measured-best wins — the acceptance mechanism for
     'analytic top-1 agrees with the measured-best of its top-3'."""
     out = _run_cli(["--workload", "lm", "--devices", "8", "--batch", "8",
                     "--seq", "16", "--d-model", "32", "--heads", "2",

@@ -201,7 +201,7 @@ def test_grouped_expert_products_compile(one_chip, rows):
 
 
 def _flash_args(one_chip, t):
-    # bench.py's LM shape: batch 2, 8 heads x 128, bf16.
+    # chip_smoke.py's LM shape: batch 2, 8 heads x 128, bf16.
     x = jax.ShapeDtypeStruct((2, t, 8, 128), jnp.bfloat16, sharding=one_chip)
     return x, x, x
 

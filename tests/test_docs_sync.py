@@ -13,13 +13,12 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parent.parent
 
 # Everywhere TelemetryRun records are emitted from: the package itself,
-# the bench/report/soak drivers, and the benchmark harnesses.
+# the report/soak drivers, and the benchmark harnesses.
 EMITTING_ROOTS = (
     REPO / "distributed_model_parallel_tpu",
     REPO / "scripts",
     REPO / "benchmarks",
 )
-EMITTING_FILES = (REPO / "bench.py",)
 
 RECORD_RE = re.compile(r'\.record\(\s*"([a-z_]+)"')
 METRIC_RE = re.compile(r'\.(?:counter|gauge|histogram)\(\s*"([a-z_0-9]+)"')
@@ -27,8 +26,7 @@ WALLCLOCK_RE = re.compile(r"time\.time\(\)")
 
 
 def _emitting_files() -> list[Path]:
-    files = [p for root in EMITTING_ROOTS for p in root.rglob("*.py")]
-    return files + list(EMITTING_FILES)
+    return [p for root in EMITTING_ROOTS for p in root.rglob("*.py")]
 
 
 def _emitted_kinds() -> set[str]:
@@ -47,9 +45,8 @@ def _emitted_metric_names() -> set[str]:
 
 def _documented_kinds() -> set[str]:
     """Kind names from the first column of the record-schema table in
-    docs/OBSERVABILITY.md (rows like ``| `step` | ... |``; combined rows
-    like ``| `bench` / `cost_analysis` / `profile` | ... |`` list several
-    kinds in one cell)."""
+    docs/OBSERVABILITY.md (rows like ``| `step` | ... |``; a cell may
+    list several kinds)."""
     doc = (REPO / "docs" / "OBSERVABILITY.md").read_text()
     kinds: set[str] = set()
     for line in doc.splitlines():
@@ -85,8 +82,8 @@ def test_every_emitted_record_kind_is_documented():
 
 def test_every_metric_name_is_documented():
     """Same contract, one level down: every literal registry metric name
-    (``counter(``/``gauge(``/``histogram(``) the package, scripts and
-    bench can emit must appear (backticked) somewhere in
+    (``counter(``/``gauge(``/``histogram(``) the package and scripts
+    can emit must appear (backticked) somewhere in
     docs/OBSERVABILITY.md — the per-tenant counter semantics and the
     report both lean on these names, so an undocumented one is a wire
     format nobody can consume."""

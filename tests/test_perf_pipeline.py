@@ -26,7 +26,6 @@ from distributed_model_parallel_tpu.train.trainer import Trainer
 from distributed_model_parallel_tpu.utils.profiling import (
     DonationError,
     assert_donation,
-    donation_audit,
 )
 
 from tests.conftest import tiny_train_config
@@ -250,26 +249,3 @@ def test_batch_donation_warning_suppressed(tmp_path):
         f.lower(jnp.zeros((7, 3), jnp.float32),
                 jnp.zeros((2, 2), jnp.float32)).compile()
     assert [w for w in caught if "donated buffers" in str(w.message)]
-
-
-# ---------------------------------------------------------------------------
-# bench step_phase record (the attribution contract on CPU CI)
-# ---------------------------------------------------------------------------
-
-def test_bench_step_phase_record_proves_pipeline_active(tmp_path):
-    """The record BENCH_r06+ attribution rides on: pipeline flags prove
-    donation + device prefetch are active (no silent fallback), and on
-    CPU the phase timings are honestly unavailable."""
-    import bench
-
-    t = Trainer(tiny_train_config(tmp_path, epochs=1))
-    audit = donation_audit(
-        t._train_step, t.state, jax.random.key(0),
-        *t._shard_batch(t.train_ds.images[:32], t.train_ds.labels[:32]))
-    rec = bench.step_phase_record(t, audit)
-    pipe = rec["pipeline"]
-    assert pipe["device_prefetch_depth"] == 2
-    assert pipe["device_prefetch_max_lead"] >= 2
-    assert pipe["donation_aliases"] >= 1
-    assert pipe["grad_reduction"].startswith("xla-inferred")
-    assert rec["phases"] is None and "cpu" in rec["reason"]

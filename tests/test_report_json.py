@@ -1,6 +1,6 @@
 """scripts/dmp_report.py --json: the machine-readable report. Pins the
 section keys and the inner shapes of the headline / resilience /
-serving / gate sections (the schema CI and the cockpit consume —
+serving sections (the schema CI and the cockpit consume —
 additive changes only), the fleet --json variant, and a
 scripts/dmp_top.py --once rendering smoke."""
 
@@ -47,10 +47,8 @@ def stream(tmp_path_factory):
     run.record("serve", event="summary", policy="continuous",
                tokens_generated=8, tokens_per_s=100.0,
                page_occupancy={"mean": 0.4, "max": 0.6})
-    run.record("gate", ok=False,
-               regressions=[{"metric": "x:throughput", "value": 1.0,
-                             "baseline": 2.0, "tolerance": 0.1}],
-               verdicts=[], no_baseline=["k2"], ledger="L.jsonl")
+    # A kind no section reads (older streams hold such records).
+    run.record("gate", ok=False, regressions=[], ledger="L.jsonl")
     run.record("alert", rule="step_time_drift", subject="demo",
                state="firing", value=0.5, threshold=0.1)
     run.record("postmortem", reason="test", bundle="/tmp/pm", n_records=3)
@@ -61,8 +59,8 @@ def stream(tmp_path_factory):
 def test_report_json_section_keys_are_stable(stream):
     report = _load("dmp_report")
     data = report.build_report_data(telemetry.read_records(stream))
-    assert {"run", "headline", "resilience", "serving", "capacity",
-            "gate", "plan", "spans", "alerts", "counters", "epochs",
+    assert {"run", "headline", "resilience", "serving", "rtrace",
+            "capacity", "plan", "spans", "alerts", "counters", "epochs",
             "wall_s"} <= set(data)
     # No meter/utilization records in this stream: the capacity
     # observatory stays out of the way.
@@ -110,15 +108,14 @@ def test_serving_section_schema(stream):
     assert len(s["summaries"]) == 1
 
 
-def test_gate_section_schema(stream):
+def test_record_kinds_without_a_section_are_ignored(stream):
+    """The stream holds a ``gate`` record, a kind no section reads: it
+    becomes no key of the JSON report and no section of the text one."""
     report = _load("dmp_report")
-    data = report.build_report_data(telemetry.read_records(stream))
-    g = data["gate"]
-    assert {"ok", "regressions", "verdicts", "no_baseline",
-            "ledger"} == set(g)
-    assert g["ok"] is False
-    assert g["regressions"][0]["metric"] == "x:throughput"
-    assert g["no_baseline"] == ["k2"]
+    records = telemetry.read_records(stream)
+    assert any(r.get("kind") == "gate" for r in records)
+    assert "gate" not in report.build_report_data(records)
+    assert "regression gate" not in report.build_report(records)
 
 
 def test_capacity_section_schema(tmp_path):
@@ -157,13 +154,12 @@ def test_capacity_section_schema(tmp_path):
     assert cap["sustainable_tokens_per_s"] > cap["tokens_per_s"] == 8.0
 
 
-def test_gate_none_when_no_gate_records(tmp_path):
+def test_bare_stream_has_empty_sections(tmp_path):
     report = _load("dmp_report")
     path = str(tmp_path / "bare.jsonl")
     telemetry.TelemetryRun(path, run="bare", track_compiles=False,
                            device={"platform": "cpu"}).finish()
     data = report.build_report_data(telemetry.read_records(path))
-    assert data["gate"] is None
     assert data["headline"]["step_time_s"] is None
     assert data["serving"]["completed"] == 0
 

@@ -10,7 +10,7 @@ The load-bearing properties (docs/SERVING.md):
   mid-batch join, and the static-policy baseline all decode identical
   tokens, and the engine matches ``transformer.generate`` greedy;
 * continuous batching beats static batching on slot utilization on a
-  mixed-length workload (the timing-free form of the BENCH_serve gate);
+  mixed-length workload (a timing-free comparison of the two policies);
 * a killed engine reports every in-flight/queued request as a typed
   failure — nothing is silently dropped (chaos tier).
 """
@@ -148,7 +148,7 @@ def test_forced_pallas_impl_decodes_identical_tokens(model):
 def test_static_policy_decodes_identical_tokens(model):
     """Scheduling policy moves throughput, never tokens: the static
     baseline must produce bitwise the continuous schedule's output for
-    every request (that is what makes BENCH_serve's comparison fair)."""
+    every request (that is what makes a comparison of the two fair)."""
     cfg, params = model
     outs = []
     for policy in ("continuous", "static"):
@@ -236,7 +236,7 @@ def test_engine_rejects_unsupported_models(model):
 
 
 def test_continuous_beats_static_slot_utilization(model):
-    """The timing-free form of the BENCH_serve gate: on a mixed-length
+    """A timing-free comparison of the two policies: on a mixed-length
     burst, continuous batching completes the same tokens in fewer decode
     steps (higher slot utilization) than the static baseline."""
     cfg, params = model

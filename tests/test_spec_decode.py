@@ -307,43 +307,3 @@ def test_chat_trace_smoke_determinism_trio(model):
     assert on_sum["prefill_tokens_saved"] > 0
     assert on_sum["draft_tokens_proposed"] > 0
     assert on_sum["decode_steps"] <= off_sum["decode_steps"]
-
-
-def test_bench_chat_trace_replay_deterministic(monkeypatch):
-    """BENCH_serve's own chat-trace machinery (build_serve_chat_trace +
-    _replay_chat), downscaled: the seeded trace is reproducible, the
-    cache+spec replay decodes the baseline engine's tokens bitwise, and
-    the hit/accept fields the headline gates on are populated."""
-    import importlib
-    import os
-    import sys
-
-    for k, v in (("CHAT_CONVS", "2"), ("CHAT_TURNS", "2"),
-                 ("CHAT_SYSTEM", "16"), ("CHAT_USER", "4"),
-                 ("CHAT_GEN", "8"), ("CHAT_STAGGER_S", "0"),
-                 ("DMODEL", "32"), ("DFF", "64"), ("LAYERS", "2"),
-                 ("VOCAB", "64")):
-        monkeypatch.setenv(f"DMP_BENCH_SERVE_{k}", v)
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    monkeypatch.syspath_prepend(repo)
-    bench = importlib.import_module("bench")
-    importlib.reload(bench)
-    chat, cfg = bench.build_serve_chat_trace()
-    chat2, _ = bench.build_serve_chat_trace()
-    assert chat == chat2, "trace generation must be seeded-deterministic"
-    params = tfm.init_params(jax.random.key(0), cfg)
-    pages = -(-cfg.max_seq_len // 8)
-
-    def run(on):
-        eng = Engine(params, cfg, ServeConfig(
-            n_slots=2, page_size=8, n_pages=8 * pages,
-            max_seq_len=cfg.max_seq_len, prefill_chunk=8,
-            prefix_cache=on, spec_k=3 if on else 0))
-        return bench._replay_chat(chat, eng), eng.summary(record=False)
-
-    on_turns, on_sum = run(True)
-    off_turns, off_sum = run(False)
-    assert on_turns == off_turns
-    assert on_sum["cache_hit_rate"] > 0
-    assert on_sum["prefill_tokens_saved"] > 0
-    sys.modules.pop("bench", None)   # leave no env-specialized module

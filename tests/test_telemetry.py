@@ -1,10 +1,6 @@
 """utils/telemetry.py: registry semantics, the JSONL event stream, the
 collectives comm accounting, trainer integration on a tiny CPU run, and a
 scripts/dmp_report.py smoke test over the resulting stream.
-
-Also pins the bench.py failure contract (ISSUE 1 acceptance): with
-JAX_PLATFORMS pointed at an unreachable backend, bench.py must exit 0 with
-ONE parseable JSON failure record on stdout — no traceback.
 """
 
 import importlib.util
@@ -424,26 +420,6 @@ def test_lm_trainer_stream_has_tokens_and_flops(tmp_path):
     assert len(steps) == 2
     for rec in steps:
         assert rec["tokens_per_s"] > 0 and rec["step_time_s"] > 0
-
-
-# ---------------------------------------------------------------------------
-# bench.py failure contract
-# ---------------------------------------------------------------------------
-
-def test_bench_unreachable_backend_exits_nonzero():
-    # "cuda" fails fast in this image (no GPU plugin) while exercising the
-    # exact unreachable-backend path; JAX_PLATFORMS=tpu also lands here but
-    # libtpu's own metadata retries make it minutes-slow.
-    env = dict(os.environ, JAX_PLATFORMS="cuda")
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        capture_output=True, text=True, timeout=300, env=env, cwd=REPO)
-    assert proc.returncode != 0
-    assert proc.stdout.strip() == "", proc.stdout   # no result, no record
-    assert "[bench] no usable accelerator" in proc.stderr
-    assert "cuda" in proc.stderr
-    assert "Traceback" not in proc.stderr
 
 
 # ---------------------------------------------------------------------------

@@ -1,8 +1,6 @@
 """utils/xplane.py: profiler-trace parsing against synthetic XSpace protos.
 
-The real capture path needs a TPU (exercised by
-benchmarks/run_step_profile.py, whose committed artifact is the
-evidence); these tests pin the PARSING semantics — envelope exclusion,
+The real capture path needs a TPU; these tests pin the PARSING semantics — envelope exclusion,
 zero-valued stat presence, fusion classification from HLO text — on
 hand-built protos, so a regression fails fast on CPU. The proto-building
 tests skip when tensorflow is absent (module-scoped ``tf_pb2`` fixture);
