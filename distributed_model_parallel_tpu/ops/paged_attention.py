@@ -119,9 +119,11 @@ def paged_attention_xla(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                         window: int | None = None) -> jax.Array:
     """Pure-XLA paged attention: gather then :func:`attend_rows`.
 
-    q: [B, C, H, Dh]; k_pool/v_pool: [P, page, Hkv, Dh] (ONE layer's
-    slab); tables: [B, N] physical page ids (rows padded with any
-    in-range id — padded pages are masked by ``lengths``); positions:
+    q: [B, C, H, Dh]; k_pool/v_pool: [P, page, Hkv, Dh]: one layer's
+    slab, or the layers' slabs end to end with ``tables`` moved to the
+    layer's (:func:`paged_attention`); tables: [B, N] physical page ids
+    (rows padded with any in-range id — padded pages are masked by
+    ``lengths``); positions:
     [B, C]; lengths: [B]. Materializes the gathered [B, N*page, Hkv, Dh]
     view and the float32 scores over it in HBM — fine off-TPU; on a TPU
     the decode round wants :func:`paged_attention_kernel` and a chunk
@@ -304,7 +306,9 @@ def paged_attention_kernel(q: jax.Array, k_pool: jax.Array,
                            window: int | None = None,
                            interpret: bool | None = None) -> jax.Array:
     """Pallas paged decode attention. q: [B, 1, H, Dh] (decode is one
-    token per row); pools [P, page, Hkv, Dh]; tables [B, N]; positions
+    token per row); pools [P, page, Hkv, Dh] (one slab, or a stack of
+    them end to end under a table of the layer's ids:
+    :func:`paged_attention`); tables [B, N]; positions
     [B] (the query token's absolute position; the row attends positions
     [0, pos], band-clamped under ``window``). Returns [B, 1, H, Dh].
 
@@ -543,7 +547,9 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
                             interpret: bool | None = None) -> jax.Array:
     """Pallas paged prefill attention. q: [B, C, H, Dh], row ``b``'s
     queries at positions ``pos0[b] + arange(C)`` (a prompt chunk, or the
-    speculative verify window); pools [P, page, Hkv, Dh]; tables [B, N];
+    speculative verify window); pools [P, page, Hkv, Dh] (one slab, or a
+    stack of them end to end under a table of the layer's ids:
+    :func:`paged_attention`); tables [B, N];
     lengths [B] valid K prefix per row (``pos0 + n_valid``: queries past
     it are the chunk's padding; what they return is finite and
     meaningless). Returns [B, C, H, Dh], :func:`attend_rows`' result to
@@ -609,9 +615,19 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     tables: jax.Array, positions: jax.Array,
                     lengths: jax.Array, window: int | None = None,
-                    impl: str = "auto") -> jax.Array:
+                    impl: str = "auto", layer=None) -> jax.Array:
     """Dispatch: the Pallas kernels on a TPU, the XLA gather path
-    everywhere else. ``impl``: "auto" | "xla" | "pallas". "auto" takes
+    everywhere else. Pools ``[P, page, Hkv, Dh]`` are one slab; pools
+    ``[L, P, page, Hkv, Dh]`` are the layers' slabs stacked, read at
+    ``layer`` (an index, traced or not) without cutting the slab out:
+    the stack is seen as ``[L * P, page, Hkv, Dh]``, its own memory
+    order, and the table's page ids move by ``layer * P``. All three
+    read paths follow that one rule; a step's pools reach the kernels as
+    views of the buffers the step carries (a slab sliced out under a
+    traced ``layer`` is a copy of the slab, 67 MB a pool and layer in
+    the serving cells: PERF.md section 6, PR 31).
+
+    ``impl``: "auto" | "xla" | "pallas". "auto" takes
     the kernels on a TPU backend and the gather path off it; "pallas"
     forces the kernels anywhere (interpreted off the chip); "xla" is the
     gather path. One query token a row (``C == 1``) is the decode kernel;
@@ -626,6 +642,10 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     if impl not in ("auto", "xla", "pallas"):
         raise ValueError(f"unknown paged-attention impl {impl!r}; "
                          f"known: auto, xla, pallas")
+    if k_pool.ndim == 5:
+        tables = tables + layer * k_pool.shape[1]
+        k_pool = k_pool.reshape(-1, *k_pool.shape[2:])
+        v_pool = v_pool.reshape(-1, *v_pool.shape[2:])
     c = q.shape[1]
     if impl == "pallas" or (impl == "auto"
                             and jax.devices()[0].platform == "tpu"):

@@ -69,8 +69,12 @@ def paged_block(bp: dict, kind: LayerKind, pools: tuple, where: tuple,
     prompt padding) — and the logical-to-physical table the read follows;
     offsets: [B, C] within the page; lengths: [B] valid K prefix (after
     this step's writes); valid: [B, C] tokens that exist (the routed
-    layer sends no other anywhere). Returns ``(x, pools, aux)``. The
-    paged counterpart of ``transformer._cached_block``.
+    layer sends no other anywhere). The layer's K and V are written in
+    place into its pool (the donated carry), and the pool goes to the
+    attention read whole, with ``layer``: the read finds the layer's
+    pages inside the stack (``paged_attention``), and no slab is cut out
+    for it. Returns ``(x, pools, aux)``. The paged counterpart of
+    ``transformer._cached_block``.
     """
     b, c = x.shape[:2]
     ring, layer = where
@@ -87,11 +91,9 @@ def paged_block(bp: dict, kind: LayerKind, pools: tuple, where: tuple,
         k.astype(kpool.dtype), mode="drop")
     vpool = vpool.at[layer, pages, offsets].set(
         v.astype(vpool.dtype), mode="drop")
-    kp = jax.lax.dynamic_index_in_dim(kpool, layer, 0, keepdims=False)
-    vp = jax.lax.dynamic_index_in_dim(vpool, layer, 0, keepdims=False)
     with jax.named_scope("attn_sliding" if ring else "attn_full"):
-        o = paged_attention(q, kp, vp, tables, positions, lengths,
-                            window=kind.window, impl=impl)
+        o = paged_attention(q, kpool, vpool, tables, positions, lengths,
+                            window=kind.window, impl=impl, layer=layer)
     pools = pools[:2] + (kpool, vpool) if ring else (kpool, vpool) + pools[2:]
     x = x + o.reshape(b, c, -1) @ bp["wo"]
     h = _norm(bp, "ln2", x, cfg)

@@ -125,6 +125,14 @@ def test_paged_prefill_kernel_compiles(one_chip, h, hkv, n, dtype, window):
     assert not re.search(r"= bf16\[\d+,\d+,128\]\S* copy\(", text)
 
 
+def _compile_kernels(monkeypatch):
+    """The paged kernels ask jax.devices() whether to interpret; here
+    they compile for the described chip."""
+    for name in ("paged_attention_kernel", "paged_prefill_attention"):
+        monkeypatch.setattr(
+            pa, name, functools.partial(getattr(pa, name), interpret=False))
+
+
 @pytest.mark.parametrize("impl", ["pallas", "xla"])
 def test_prefill_step_holds_the_kernel_and_no_score_tensor(
         one_chip, monkeypatch, impl):
@@ -136,11 +144,7 @@ def test_prefill_step_holds_the_kernel_and_no_score_tensor(
     from distributed_model_parallel_tpu.models import transformer as tfm
     from distributed_model_parallel_tpu.serve.model import make_prefill_step
 
-    # the kernel asks jax.devices() whether to interpret; here it
-    # compiles for the described chip
-    monkeypatch.setattr(
-        pa, "paged_prefill_attention",
-        functools.partial(pa.paged_prefill_attention, interpret=False))
+    _compile_kernels(monkeypatch)
     chunk, max_seq, page = 128, 2048, 16
     cfg = tfm.TransformerConfig(
         vocab_size=1024, d_model=256, n_heads=4, n_kv_heads=2, d_head=128,
@@ -169,6 +173,138 @@ def test_prefill_step_holds_the_kernel_and_no_score_tensor(
     else:
         assert "%paged_prefill_attention" not in text
         assert scores
+
+
+def _pool_ops(text: str, pool: tuple) -> tuple:
+    """``(slabs, pools)``: the instructions of a compiled program, as
+    ``(opcode, line)``, that yield one layer's slab of the stacked pool
+    ``[L, P, page, Hkv, Dh]`` (``[1,]P,page,Hkv,Dh``, or the kernels'
+    view ``P,page*Hkv,Dh``) and those that yield the whole of it
+    (``L,P,...`` or ``L*P,...``). What moves nothing is left out: a
+    parameter, a bitcast, an element of a tuple."""
+    n_layers, p, page, hkv, dh = pool
+    assert n_layers > 1     # or a slab is the pool, and the write yields it
+
+    def yielding(*rows):
+        shapes = "|".join(f"{r},(?:{page},{hkv}|{page * hkv}),{dh}"
+                          for r in rows)
+        ops = re.finditer(
+            rf"^\s*(?:ROOT )?%\S+ = bf16\[(?:{shapes})\]\S* ([\w-]+)\(.*$",
+            text, re.M)
+        return [(m.group(1), m.group(0)) for m in ops if m.group(1)
+                not in ("parameter", "bitcast", "get-tuple-element")]
+
+    return (yielding(f"1,{p}", p),
+            yielding(f"{n_layers},{p}", n_layers * p))
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode", "verify"])
+@pytest.mark.parametrize("layers", ["equal-layers", "mixed-kinds"])
+def test_serving_steps_cut_no_slab_out_of_the_pools(
+        one_chip, monkeypatch, layers, step):
+    """``jit_prefill_step``, ``jit_decode_step`` and ``jit_verify_step``
+    compiled for the chip with pools of the serving cells' size (shapes
+    only: nothing is allocated, and pools small enough for fast memory
+    are prefetched there whole, which no deployment's are). Under four
+    equal layers the layer index is traced (``run_layers``' scan); under
+    full and sliding layers mixed in one period it is static, and the
+    sliding layers keep rings. Either way the stacked pools reach the
+    kernels as views of the buffers the step carries: no op cuts one
+    layer's slab out, and the only ops that yield a pool are the writes
+    in place."""
+    from distributed_model_parallel_tpu.models import transformer as tfm
+    from distributed_model_parallel_tpu.serve import model as sm
+    from distributed_model_parallel_tpu.serve.paged_kv import CacheLayout
+
+    _compile_kernels(monkeypatch)
+    page, chunk, width, max_seq, n_pages, slots = 16, 512, 4, 4096, 8192, 64
+    kw = dict(vocab_size=1024, d_model=256, n_heads=16, n_kv_heads=8,
+              d_head=128, d_ff=512, max_seq_len=max_seq, dtype=jnp.bfloat16,
+              pos_embedding="rope")
+    if layers == "equal-layers":
+        cfg = tfm.TransformerConfig(n_layers=4, **kw)
+        assert cfg.layer_plan == (0, 1, 4)
+    else:
+        full = tfm.LayerKind(None, False, "dense")
+        sliding = tfm.LayerKind(128, True, "dense")
+        cfg = tfm.TransformerConfig(
+            n_layers=5, layer_kinds=(full, sliding, sliding, full, sliding),
+            **kw)
+        assert cfg.layer_plan == (0, 5, 1)
+    layout = CacheLayout.of(cfg, page_size=page, max_seq_len=max_seq,
+                            span=chunk)
+    rings = layout.ring_pages
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda: tfm.init_params(jax.random.key(0), cfg)))
+    full = sds((layout.n_full, n_pages, page, 8, 128), jnp.bfloat16)
+    ring = (sds((layout.n_ring, slots * rings, page, 8, 128), jnp.bfloat16)
+            if rings else None)
+    pools = (full, full, ring, ring)
+    assert (layout.n_full, layout.n_ring) == ((2, 3) if rings else (4, 0))
+    n = max_seq // page
+    table = (sds((n,)), sds((rings,)) if rings else None)
+    tables = (sds((slots, n)), sds((slots, rings)) if rings else None)
+    active = sds((slots,), jnp.bool_)
+    kws = dict(page_size=page, impl="pallas", layout=layout)
+    lowered = {
+        "prefill": lambda: sm.make_prefill_step(cfg, chunk=chunk, **kws).lower(
+            params, pools, None, sds((1, chunk)), sds(()), sds(()), table,
+            None),
+        "decode": lambda: sm.make_decode_step(cfg, **kws).lower(
+            params, pools, None, sds((slots,)), sds((slots,)), tables,
+            active, None),
+        "verify": lambda: sm.make_verify_step(cfg, width=width, **kws).lower(
+            params, pools, None, sds((slots, width)), sds((slots,)),
+            sds((slots,)), tables, active, None),
+    }[step]()
+    text = lowered.compile().as_text()
+    assert text.startswith(f"HloModule jit_{step}_step")
+    kernel = "decode" if step == "decode" else "prefill"
+    assert len(re.findall(
+        rf"%paged_{kernel}_attention[\w.]* = .*custom-call\(", text)
+    ) == (1 if layers == "equal-layers" else 5)
+    for pool in filter(None, (full, ring)):
+        slabs, whole = _pool_ops(text, pool.shape)
+        assert not slabs, slabs[0][1][:300]
+        moved = [line for op, line in whole if not (
+            op == "scatter" or (op == "fusion" and "/scatter\"" in line))]
+        assert not moved, moved[0][:300]
+        assert whole                       # the pattern reads this program
+
+
+def test_a_slab_cut_out_under_a_scan_is_found(one_chip):
+    """The control of the test above: a layer's slab taken with
+    ``dynamic_index_in_dim`` under a scan (how ``paged_block`` handed the
+    kernels their pools before PR 31) is an op of its own in the compiled
+    program, 67 MB written a pool and layer, and ``_pool_ops`` finds
+    it."""
+    n_layers, n_pages, page, hkv, dh, slots, n = 4, 8192, 16, 8, 128, 64, 256
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def read_all(q, kpool, vpool, tables, positions):
+        def layer(q, i):
+            k, v = (jax.lax.dynamic_index_in_dim(pool, i, 0, keepdims=False)
+                    for pool in (kpool, vpool))
+            return pa.paged_attention_kernel(q, k, v, tables, positions,
+                                             interpret=False), None
+
+        return jax.lax.scan(layer, q, jnp.arange(n_layers))[0]
+
+    shape = (n_layers, n_pages, page, hkv, dh)
+    pool = sds(shape, jnp.bfloat16)
+    text = _compiled_text(
+        read_all, sds((slots, 1, 16, dh), jnp.bfloat16), pool, pool,
+        sds((slots, n), jnp.int32), sds((slots,), jnp.int32))
+    assert "%paged_decode_attention" in text
+    slabs, _ = _pool_ops(text, shape)
+    assert len(slabs) >= 2                         # K's and V's
 
 
 @pytest.mark.parametrize("rows", [4096, 512],

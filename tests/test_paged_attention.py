@@ -426,3 +426,55 @@ def test_bfloat16_kernel_parity():
                                   interpret=True)
     assert x.dtype == jnp.bfloat16
     _assert_rounding_close(k, x)
+
+
+@pytest.mark.parametrize("ring", [False, True], ids=["full", "window-ring"])
+@pytest.mark.parametrize("c", [1, 4], ids=["decode", "chunk"])
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_stacked_pool_reads_one_layers_pages(impl, c, ring):
+    """A pool ``[L, P, page, Hkv, Dh]`` read at ``layer`` is the read of
+    that layer's slab alone, bit for bit on every path (the same pages
+    through the same sums), for the first, a middle and the last layer,
+    with ``layer`` static and traced under ``lax.scan`` (how the serving
+    steps pass it). Every other layer's slab holds large finite garbage:
+    a page id that missed its layer's offset would bring it in. Under a
+    window the table is a sliding layer's: ring pages, repeated."""
+    n_layers, window = 4, (8 if ring else None)
+    q, _, _, tables, positions = _case()
+    if ring:
+        tables = jnp.take(tables[:, :3], jnp.arange(tables.shape[1]) % 3,
+                          axis=1)
+    q = jax.random.normal(jax.random.key(8), (3, c, *q.shape[2:]))
+    pos = positions[:, None] - (c - 1) + jnp.arange(c)[None]
+    # Non-negative V, as in the kernels' edge cases above.
+    slabs = [(kp, jnp.abs(vp))
+             for kp, vp in map(_pool, range(50, 50 + n_layers))]
+    read = functools.partial(pa.paged_attention, q, tables=tables,
+                             positions=pos, lengths=positions + 1,
+                             window=window, impl=impl)
+
+    @jax.jit
+    def read_layers(kstack, vstack, layers):
+        return jax.lax.scan(
+            lambda _, i: (None, read(k_pool=kstack, v_pool=vstack, layer=i)),
+            None, layers)[1]
+
+    for layer in (0, 2, n_layers - 1):
+        kp, vp = slabs[layer]
+        kstack, vstack = (
+            jnp.full((n_layers, *slab.shape), 3e38).at[layer].set(slab)
+            for slab in (kp, vp))
+        alone = read(k_pool=kp, v_pool=vp)
+        assert np.isfinite(np.asarray(alone)).all()
+        assert (read(k_pool=kstack, v_pool=vstack, layer=layer)
+                == alone).all()
+        assert (read_layers(kstack, vstack, jnp.asarray([layer]))[0]
+                == alone).all()
+        _assert_rounding_close(alone, pa.paged_attention_xla(
+            q, kp, vp, tables, pos, positions + 1, window),
+            f32_ulps=4 if c == 1 else 16)
+    # every layer its own contents, one traced read after another
+    kstack, vstack = (jnp.stack(pools) for pools in zip(*slabs))
+    outs = read_layers(kstack, vstack, jnp.arange(n_layers))
+    for layer, (kp, vp) in enumerate(slabs):
+        assert (outs[layer] == read(k_pool=kp, v_pool=vp)).all()
