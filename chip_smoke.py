@@ -26,6 +26,13 @@ benchmark's own models are ``chipbench/configs/``):
    through the kernels) token-identical to ``"xla"``, and with prefix
    cache + speculation token-identical to both off.
 
+5. **serve-routed**, **serve-hybrid** — the same engine on the two
+   other block families at toy depth: sigmoid-routed dropless experts
+   with sliding layers in rings, and gated-delta linear-attention layers
+   3:1 with full attention (a recurrent state a slot): the rule's decode
+   kernel and chunked form against the recurrence at the published
+   widths, then kernels against the XLA forms, token for token.
+
 ``--multichip`` is a separate run for a four-chip host: only the
 cross-chip paths (GSPMD dp 4, shard_map DDP, the four-stage pipeline, LM
 dp2 x tp2, LM pp2 1F1B), each against its one-device step, with every
@@ -67,6 +74,10 @@ FULL = dict(
     routed=dict(vocab=8192, d_model=512, heads=8, kv_heads=2, head_dim=128,
                 d_ff=1024, d_expert=256, experts=16, held=(4, 4), top_k=4,
                 window=128),
+    # the linear layers' published widths (30 heads of 96 x 192) and the
+    # full layers' 30 heads with no grouping, at a small hidden size
+    hybrid=dict(vocab=8192, d_model=512, heads=30, head_dim=128, d_ff=1024,
+                lin_heads=30, lin_dk=96, lin_dv=192, rule_tokens=512),
     multi=dict(lm_seq=2048, lm_batch=16, loss_chunk=512),
 )
 TINY = dict(
@@ -78,6 +89,8 @@ TINY = dict(
     routed=dict(vocab=256, d_model=64, heads=4, kv_heads=2, head_dim=32,
                 d_ff=128, d_expert=32, experts=16, held=(4, 4), top_k=4,
                 window=16),
+    hybrid=dict(vocab=256, d_model=64, heads=4, head_dim=32, d_ff=128,
+                lin_heads=4, lin_dk=8, lin_dv=64, rule_tokens=80),
     multi=dict(lm_seq=128, lm_batch=16, loss_chunk=0),
 )
 
@@ -105,6 +118,12 @@ FUSED_UPDATE_ULPS, FUSED_LOSS_RTOL = 8, 2e-2
 # O(1). Not per leaf: a conv bias ahead of a BatchNorm has a true
 # gradient of zero, so its update is rounding noise on both sides.
 DP_LOSS_RTOL, DP_UPDATE_RTOL = 1e-4, 2e-2
+# The gated delta rule's forms on the same float32 inputs under true
+# float32 products: the decode kernel against the step (the same sums in
+# another order), the chunked form against the recurrence token by token
+# (a solve and six products a sub-chunk for 64 rank-one updates): values
+# of order 1, so a few hundred ulps.
+RULE_ATOL = 2e-4
 
 
 def log(msg: str) -> None:
@@ -612,6 +631,125 @@ def phase_serve_routed(size: dict, block: dict, on_tpu: bool,
           "f32 routed engine: greedy tokens identical, kernel vs xla")
 
 
+def phase_serve_hybrid(size: dict, block: dict, on_tpu: bool,
+                       seed: int) -> None:
+    """The gated-delta layers: the rule's three forms against one another
+    at the block's widths (the decode kernel on a state pool against the
+    step on the layer's slab; the chunked form against the recurrence),
+    then two periods of linear, linear, linear, full through the engine
+    (norms on the sublayers' outputs, whole-vector QK-norm, no rotation;
+    the full layers' KV heads stored as the pool pads them), kernels
+    against the XLA forms."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_model_parallel_tpu.models import transformer as tfm
+    from distributed_model_parallel_tpu.ops import gated_delta as gd
+    from distributed_model_parallel_tpu.serve import ServeConfig
+    from distributed_model_parallel_tpu.serve.paged_kv import (
+        memory_gauges,
+        stored_kv_heads,
+    )
+
+    kernel = "auto" if on_tpu else "pallas"
+    h, dk, dv = block["lin_heads"], block["lin_dk"], block["lin_dv"]
+    n, t = size["n_slots"], block["rule_tokens"]
+    ks = jax.random.split(jax.random.key(seed), 8)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], (n, t, h, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(ks[1], (n, t, h, dk))
+             + jax.random.normal(ks[2], (n, 1, h, dk)))
+    v = jax.random.normal(ks[3], (n, t, h, dv))
+    log_alpha = -jax.random.uniform(ks[4], (n, t, h), minval=1e-3, maxval=0.3)
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[5], (n, t, h)))
+    state = jax.random.normal(ks[6], (n, h, dk, dv))
+    with jax.default_matmul_precision("highest"):
+        # one decode round on layer 1 of a pool of three, row 0 idle
+        check(gd.decode_kernel_takes(h, dv), "the decode kernel takes the "
+              f"block's heads ({h} x {dv})")
+        pool = jnp.stack([gd.pool_state(state * s) for s in (1.0, 0.5, 2.0)])
+        alpha1 = jnp.exp(log_alpha[:, 0]).at[0].set(1.0)
+        beta1 = beta[:, 0].at[0].set(0.0)
+        args = (jnp.int32(1), q[:, 0], k[:, 0], v[:, 0], alpha1, beta1)
+        decode = jax.jit(gd.gated_delta_decode, static_argnames=("impl",))
+        o_k, pool_k = decode(pool, *args, impl=kernel)
+        o_x, pool_x = decode(pool, *args, impl="xla")
+        if on_tpu:
+            check(has_custom_call(jax.jit(lambda p: gd.gated_delta_decode(
+                p, *args, impl=kernel)), pool),
+                "the decode round's state update is the compiled kernel")
+        worst = max(float(jnp.max(jnp.abs(o_k - o_x))),
+                    float(jnp.max(jnp.abs(pool_k - pool_x))))
+        check(worst <= RULE_ATOL, f"gated-delta decode kernel vs the step "
+              f"on the slab: within {worst:.2e} (limit {RULE_ATOL:.0e})")
+        check(bool(jnp.array_equal(pool_k[1, 0], pool[1, 0]))
+              and bool(jnp.array_equal(pool_k[::2], pool[::2])),
+              "an idle row's state and the other layers' stay bit for bit")
+        # the chunked form against the recurrence, rows of unequal length
+        valid = jnp.arange(t)[None, :] < (t - 7 * jnp.arange(n))[:, None]
+        o_c, s_c = jax.jit(gd.gated_delta_chunk)(q, k, v, log_alpha, beta,
+                                                 state, valid)
+
+        def token(s, xs):
+            q, k, v, la, b, ok = xs
+            o, s = gd.gated_delta_step(
+                q, k, v, jnp.where(ok[:, None], jnp.exp(la), 1.0),
+                jnp.where(ok[:, None], b, 0.0), s)
+            return s, o
+
+        rows = lambda x: jnp.moveaxis(x, 1, 0)
+        s_r, o_r = jax.jit(lambda *xs: jax.lax.scan(token, state, xs))(
+            *(rows(x) for x in (q, k, v, log_alpha, beta, valid)))
+        worst = max(float(jnp.max(jnp.abs(s_c - s_r))), float(jnp.max(
+            jnp.where(valid[..., None, None], jnp.abs(o_c - rows(o_r)), 0))))
+        check(worst <= RULE_ATOL, f"gated-delta chunked form ({t} tokens) "
+              f"vs the recurrence: within {worst:.2e} (limit "
+              f"{RULE_ATOL:.0e})")
+    # as the engine runs them (the chip's default products): logged only
+    o_d, s_d = jax.jit(gd.gated_delta_chunk)(q, k, v, log_alpha, beta, state,
+                                             valid)
+    log(f"chunked form at default precision: state within "
+        f"{float(jnp.max(jnp.abs(s_d - s_r))):.2e} of the recurrence")
+
+    lin, full = tfm.LayerKind(mixer="gated_delta"), tfm.LayerKind()
+    cfg = tfm.TransformerConfig(
+        vocab_size=block["vocab"], d_model=block["d_model"],
+        n_heads=block["heads"], n_kv_heads=block["heads"],
+        d_head=block["head_dim"], n_layers=8, d_ff=block["d_ff"],
+        max_seq_len=size["max_seq"], dtype=jnp.float32,
+        pos_embedding="rope", norm="rmsnorm", norm_eps=1e-6, ffn="swiglu",
+        qk_norm_whole=True, norm_placement="post",
+        layer_kinds=(lin, lin, lin, full) * 2, lin_key_heads=h,
+        lin_value_heads=h, lin_key_dim=dk, lin_value_dim=dv,
+        lin_neg_eigval=True)
+    params = tfm.init_params(jax.random.key(seed), cfg)
+    params["embed"] = params["embed"] * 50.0       # unit embeddings
+    pages_per_seq = -(-size["max_seq"] // size["page"])
+    geometry = dict(n_slots=size["n_slots"], page_size=size["page"],
+                    n_pages=(size["n_slots"] + 1) * pages_per_seq,
+                    max_seq_len=size["max_seq"], prefill_chunk=size["chunk"])
+    requests = make_requests(size, cfg.vocab_size, seed)
+    with jax.default_matmul_precision("highest"):
+        runs = {}
+        for name, impl in (("kernel", kernel), ("xla", "xla")):
+            runs[name], eng, _ = run_engine(
+                params, cfg, ServeConfig(attn_impl=impl, **geometry),
+                requests)
+            lay = eng.cache.layout
+            check((lay.n_state, lay.n_full, lay.n_ring) == (6, 2, 0)
+                  and eng.cache.ck.shape[3] == stored_kv_heads(cfg.kv_heads),
+                  f"six state layers hold no page; two full layers store "
+                  f"{eng.cache.ck.shape[3]} KV heads for {cfg.kv_heads}")
+            gauges = memory_gauges(eng.cache)
+            check(gauges["state_slots"] == 0 and eng.cache.pool.free_pages
+                  == eng.cache.pool.n_pages and eng.moe_counters() == {},
+                  "slots and page pool back to empty")
+            del eng
+    check(runs["kernel"] == runs["xla"],
+          "f32 hybrid engine: greedy tokens identical, kernels vs xla")
+
+
 def run_single(sizes: dict, workdir: str, meter: CompileMeter, dev,
                seed: int) -> None:
     on_tpu = dev.platform == "tpu"
@@ -623,6 +761,8 @@ def run_single(sizes: dict, workdir: str, meter: CompileMeter, dev,
         phase_serve(sizes["serve"], model, params, on_tpu, seed)
     with phase("serve-routed", meter):
         phase_serve_routed(sizes["serve"], sizes["routed"], on_tpu, seed)
+    with phase("serve-hybrid", meter):
+        phase_serve_hybrid(sizes["serve"], sizes["hybrid"], on_tpu, seed)
 
 
 # ---------------------------------------------------------------------------

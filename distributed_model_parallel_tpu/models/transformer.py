@@ -19,11 +19,14 @@ Pre-LN, GELU MLP, learned positional embeddings, weight-tied LM head kept
 separate (simplicity > tying) is the default block. Other blocks are read
 from ``TransformerConfig`` fields, never from a model's name: the norm
 (``norm``), the dense FFN (``ffn``), a head size of its own (``d_head``),
-QK-norm, and one ``LayerKind`` a layer where layers differ (window or none,
-rotated or not, dense or routed FFN: ``layer_kinds``, arranged by
-``layer_plan`` as leading layers and a scanned period). ``block_apply``
-and the serving engine's paged steps (serve/model.py) take every kind;
-``generate`` and its dense cache keep to the default block.
+QK-norm (a head or the whole vector), where the norms sit
+(``norm_placement``), and one ``LayerKind`` a layer where layers differ
+(what mixes the tokens: softmax attention, with a window or none, rotated
+or not, or the gated delta rule's recurrent state, ops/gated_delta.py;
+dense or routed FFN: ``layer_kinds``, arranged by ``layer_plan`` as
+leading layers and a scanned period). ``block_apply`` and the serving
+engine's steps (serve/model.py) take every kind; ``generate`` and its
+dense cache keep to the default block.
 """
 
 from __future__ import annotations
@@ -42,13 +45,16 @@ from distributed_model_parallel_tpu.ops.ring_attention import (
 
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
-    """What one layer is, beyond the widths all layers share: its
-    attention (``window`` keys back, None = causal over everything;
-    ``rope`` = rotate q and k) and its FFN (``"dense"`` or ``"moe"``)."""
+    """What one layer is, beyond the widths all layers share: what mixes
+    its tokens (``mixer``: ``"attention"``, softmax attention ``window``
+    keys back, None = causal over everything, ``rope`` = rotate q and k;
+    or ``"gated_delta"``, the linear-attention layer of ``lin_*`` widths,
+    which reads neither) and its FFN (``"dense"`` or ``"moe"``)."""
 
     window: int | None = None
     rope: bool = False
     ffn: str = "dense"
+    mixer: str = "attention"
 
 
 # Length of the MoE stats vector every block's aux channel carries:
@@ -142,6 +148,23 @@ class TransformerConfig:
     # bias-free.
     ffn: str = "gelu"
     qk_norm: bool = False          # RMSNorm over each head of q and of k
+    # ... or over the whole vector of q and of k, all heads, before the
+    # split into heads (scales [H, Dh] and [Hkv, Dh]).
+    qk_norm_whole: bool = False
+    # Where a sublayer's norm sits: "pre" (on its input, x + f(norm(x)))
+    # or "post" (on its output, x + norm(f(x)): nothing normalises what
+    # the sublayer reads). The leaves are ln1/ln2 either way.
+    norm_placement: str = "pre"
+    # The gated-delta layers (LayerKind.mixer == "gated_delta"): key and
+    # value heads (values a multiple of keys: a key head serves a group),
+    # their sizes, the short convolution's length, and whether beta spans
+    # (0, 2) (a state transition with negative eigenvalues) or (0, 1).
+    lin_key_heads: int = 0
+    lin_value_heads: int = 0
+    lin_key_dim: int = 0
+    lin_value_dim: int = 0
+    lin_conv: int = 4
+    lin_neg_eigval: bool = False
     # One LayerKind a layer where layers differ (window or none, rotated
     # or not, dense or routed FFN); None = every layer alike, from
     # attn_window / pos_embedding / moe_experts above.
@@ -166,6 +189,17 @@ class TransformerConfig:
             raise ValueError(f"unknown norm {self.norm!r}")
         if self.ffn not in ("gelu", "swiglu"):
             raise ValueError(f"unknown ffn {self.ffn!r}")
+        if self.norm_placement not in ("pre", "post"):
+            raise ValueError(
+                f"unknown norm_placement {self.norm_placement!r}")
+        if any(k.mixer == "gated_delta" for k in self.layer_kinds or ()):
+            if min(self.lin_key_heads, self.lin_key_dim,
+                   self.lin_value_dim) < 1 or (
+                    self.lin_value_heads % max(1, self.lin_key_heads)):
+                raise ValueError(
+                    "gated-delta layers need lin_key_heads, lin_key_dim, "
+                    "lin_value_dim and lin_value_heads, a multiple of "
+                    "lin_key_heads")
         if (self.layer_kinds is not None
                 and len(self.layer_kinds) != self.n_layers):
             raise ValueError(
@@ -183,6 +217,12 @@ class TransformerConfig:
     def head_dim(self) -> int:
         return (self.d_head if self.d_head is not None
                 else self.d_model // self.n_heads)
+
+    @property
+    def lin_channels(self) -> int:
+        """q | k | v of a gated-delta layer: the convolution's channels."""
+        return (2 * self.lin_key_heads * self.lin_key_dim
+                + self.lin_value_heads * self.lin_value_dim)
 
     @property
     def kinds(self) -> tuple:
@@ -238,27 +278,17 @@ class TransformerConfig:
                          held=self.moe_experts_held)
 
 
-def _init_blocks(k, cfg: TransformerConfig, kind: LayerKind, L: int) -> dict:
-    """``L`` layers of one kind, stacked on a leading axis. ``k``: 8 keys
-    (the default block draws from them as it always has)."""
-    d, f = cfg.d_model, cfg.d_ff
-    dt = cfg.dtype
+def _init_attention(k, cfg: TransformerConfig, stack, L: int) -> dict:
+    """The attention leaves of ``L`` stacked layers."""
+    d, dt = cfg.d_model, cfg.dtype
     hd = cfg.n_heads * cfg.head_dim
-
-    def stack(key, shape, fan_in):
-        return jax.random.normal(key, (L,) + shape, dt) * (fan_in ** -0.5)
-
-    blocks = {
-        "ln1_scale": jnp.ones((L, d), dt),
-        "wo": stack(k[3], (hd, d), hd),
-        "ln2_scale": jnp.ones((L, d), dt),
-    }
-    if cfg.norm == "layernorm":
-        blocks["ln1_bias"] = jnp.zeros((L, d), dt)
-        blocks["ln2_bias"] = jnp.zeros((L, d), dt)
-    if cfg.qk_norm:
-        blocks["q_norm"] = jnp.ones((L, cfg.head_dim), dt)
-        blocks["k_norm"] = jnp.ones((L, cfg.head_dim), dt)
+    out = {"wo": stack(k[3], (hd, d), hd)}
+    if cfg.qk_norm_whole:
+        out["q_norm"] = jnp.ones((L, cfg.n_heads, cfg.head_dim), dt)
+        out["k_norm"] = jnp.ones((L, cfg.kv_heads, cfg.head_dim), dt)
+    elif cfg.qk_norm:
+        out["q_norm"] = jnp.ones((L, cfg.head_dim), dt)
+        out["k_norm"] = jnp.ones((L, cfg.head_dim), dt)
     if cfg.gqa:
         if not (1 <= cfg.kv_heads <= cfg.n_heads):
             raise ValueError(f"n_kv_heads={cfg.kv_heads} must be in "
@@ -266,13 +296,64 @@ def _init_blocks(k, cfg: TransformerConfig, kind: LayerKind, L: int) -> dict:
         if cfg.n_heads % cfg.kv_heads:
             raise ValueError(f"n_kv_heads={cfg.kv_heads} must divide "
                              f"n_heads={cfg.n_heads}")
-        blocks["wq"] = stack(k[2], (d, cfg.n_heads, cfg.head_dim), d)
-        blocks["wkv"] = stack(jax.random.fold_in(k[2], 1),
-                              (d, cfg.kv_heads, 2 * cfg.head_dim), d)
+        out["wq"] = stack(k[2], (d, cfg.n_heads, cfg.head_dim), d)
+        out["wkv"] = stack(jax.random.fold_in(k[2], 1),
+                           (d, cfg.kv_heads, 2 * cfg.head_dim), d)
     else:
         # [d, H, 3*Dh]: head dim explicit so tensor parallelism shards
         # whole heads (column-parallel over the H axis).
-        blocks["wqkv"] = stack(k[2], (d, cfg.n_heads, 3 * cfg.head_dim), d)
+        out["wqkv"] = stack(k[2], (d, cfg.n_heads, 3 * cfg.head_dim), d)
+    return out
+
+
+def _init_gated_delta(key, cfg: TransformerConfig, stack, L: int) -> dict:
+    """The gated-delta leaves of ``L`` stacked layers: the projections to
+    q | k | v (``lin_wqkv``, the convolution's channels), to the output
+    gate, and to the decay's and the write strength's inputs, a head
+    each; the depthwise convolution; ``A_log`` and ``dt_bias`` of the
+    decay ``exp(-exp(A_log) softplus(a + dt_bias))``, drawn as the rule's
+    authors do (``A`` uniform in (0, 16), ``dt`` log-uniform in (0.001,
+    0.1)); the gated norm's scale over a value head; the way out."""
+    d, dt = cfg.d_model, cfg.dtype
+    hv, ch = cfg.lin_value_heads, cfg.lin_channels
+    ks = jax.random.split(key, 8)
+    a = jax.random.uniform(ks[5], (L, hv), jnp.float32, 1e-3, 16.0)
+    step = jnp.exp(jax.random.uniform(ks[6], (L, hv), jnp.float32,
+                                      jnp.log(1e-3), jnp.log(0.1)))
+    return {
+        "lin_wqkv": stack(ks[0], (d, ch), d),
+        "lin_wgate": stack(ks[1], (d, hv * cfg.lin_value_dim), d),
+        "lin_wa": stack(ks[2], (d, hv), d),
+        "lin_wb": stack(ks[3], (d, hv), d),
+        "lin_conv": stack(ks[4], (cfg.lin_conv, ch), cfg.lin_conv),
+        "lin_A_log": jnp.log(a),
+        "lin_dt_bias": step + jnp.log(-jnp.expm1(-step)),   # softplus^-1
+        "lin_norm": jnp.ones((L, cfg.lin_value_dim), dt),
+        "lin_wo": stack(ks[7], (hv * cfg.lin_value_dim, d),
+                        hv * cfg.lin_value_dim),
+    }
+
+
+def _init_blocks(k, cfg: TransformerConfig, kind: LayerKind, L: int) -> dict:
+    """``L`` layers of one kind, stacked on a leading axis. ``k``: 8 keys
+    (the default block draws from them as it always has)."""
+    d, f = cfg.d_model, cfg.d_ff
+    dt = cfg.dtype
+
+    def stack(key, shape, fan_in):
+        return jax.random.normal(key, (L,) + shape, dt) * (fan_in ** -0.5)
+
+    blocks = {
+        "ln1_scale": jnp.ones((L, d), dt),
+        "ln2_scale": jnp.ones((L, d), dt),
+    }
+    if cfg.norm == "layernorm":
+        blocks["ln1_bias"] = jnp.zeros((L, d), dt)
+        blocks["ln2_bias"] = jnp.zeros((L, d), dt)
+    if kind.mixer == "gated_delta":
+        blocks.update(_init_gated_delta(k[2], cfg, stack, L))
+    else:
+        blocks.update(_init_attention(k, cfg, stack, L))
     if kind.ffn == "moe" and cfg.moe_dropless:
         E, fe = cfg.moe_experts, cfg.moe_d_ff or f
         G = cfg.moe_experts_held[1] if cfg.moe_experts_held else E
@@ -430,7 +511,13 @@ def _qkv_proj(bp: dict, h: jax.Array, cfg: TransformerConfig):
     else:
         qkv = jnp.einsum("btd,dhx->bthx", h, bp["wqkv"])
         q, k, v = jnp.split(qkv, 3, axis=-1)
-    if cfg.qk_norm:
+    if cfg.qk_norm_whole:
+        # over all heads at once: the mean runs over the whole vector
+        whole = lambda x, g: rms_norm(                        # noqa: E731
+            x.reshape(*x.shape[:-2], -1), g.reshape(-1),
+            cfg.norm_eps).reshape(x.shape)
+        q, k = whole(q, bp["q_norm"]), whole(k, bp["k_norm"])
+    elif cfg.qk_norm:
         q = rms_norm(q, bp["q_norm"], cfg.norm_eps)
         k = rms_norm(k, bp["k_norm"], cfg.norm_eps)
     return q, k, v
@@ -475,6 +562,95 @@ def _attention(q, k, v, cfg: TransformerConfig, window: int | None):
     return full_attention(q, k, v, causal=True)
 
 
+def sublayer_in(bp: dict, name: str, x, cfg: TransformerConfig):
+    """What a sublayer reads: the normed stream, or under
+    ``norm_placement="post"`` the stream as it arrives."""
+    return x if cfg.norm_placement == "post" else _norm(bp, name, x, cfg)
+
+
+def sublayer_out(bp: dict, name: str, y, cfg: TransformerConfig):
+    """What a sublayer adds to the stream: its result, or under
+    ``norm_placement="post"`` the norm of it."""
+    return _norm(bp, name, y, cfg) if cfg.norm_placement == "post" else y
+
+
+def gated_delta_inputs(bp: dict, h: jax.Array, cfg: TransformerConfig,
+                       tail: jax.Array, n_valid: jax.Array):
+    """A gated-delta layer up to its rule, for h [B, C, d]: the six
+    projections, the short causal convolution over q | k | v (``tail``
+    [B, K - 1, ch]: the inputs before this call's; ``n_valid`` [B]: how
+    many of the C tokens exist), unit keys and queries (``q / sqrt(dk)``
+    besides), the decay and the write strength a head. Returns ``(q, k
+    [B, C, Hv, dk], v [B, C, Hv, dv], log_alpha, beta [B, C, Hv]
+    float32, gate [B, C, Hv * dv], tail1)``; a key head's q and k are
+    repeated for the value heads it serves. One definition for the full
+    forward (``block_apply``) and the serving steps."""
+    from distributed_model_parallel_tpu.ops.gated_delta import causal_conv
+
+    b, c = h.shape[:2]
+    hk, hv = cfg.lin_key_heads, cfg.lin_value_heads
+    dk, dv = cfg.lin_key_dim, cfg.lin_value_dim
+    f32 = jnp.float32
+    with jax.named_scope("linattn_proj"):
+        u = h @ bp["lin_wqkv"]
+        gate = h @ bp["lin_wgate"]
+        a = jnp.einsum("bcd,dh->bch", h, bp["lin_wa"],
+                       preferred_element_type=f32)
+        bb = jnp.einsum("bcd,dh->bch", h, bp["lin_wb"],
+                        preferred_element_type=f32)
+    with jax.named_scope("linattn_conv"):
+        cv, tail1 = causal_conv(u, bp["lin_conv"], tail, n_valid)
+        q, k, v = jnp.split(cv, [hk * dk, 2 * hk * dk], axis=-1)
+        unit = lambda x: x / (jnp.sqrt(jnp.sum(                # noqa: E731
+            jnp.square(x), axis=-1, keepdims=True)) + 1e-6)
+        q = unit(q.reshape(b, c, hk, dk)) * dk ** -0.5
+        k = unit(k.reshape(b, c, hk, dk))
+        if hv != hk:
+            q = jnp.repeat(q, hv // hk, axis=2)
+            k = jnp.repeat(k, hv // hk, axis=2)
+        v = v.reshape(b, c, hv, dv)
+        log_alpha = -jnp.exp(bp["lin_A_log"].astype(f32)) * jax.nn.softplus(
+            a + bp["lin_dt_bias"].astype(f32))
+        beta = jax.nn.sigmoid(bb) * (2.0 if cfg.lin_neg_eigval else 1.0)
+    dt = h.dtype
+    return (q.astype(dt), k.astype(dt), v.astype(dt), log_alpha, beta,
+            gate, tail1)
+
+
+def gated_delta_output(bp: dict, o: jax.Array, gate: jax.Array,
+                       cfg: TransformerConfig) -> jax.Array:
+    """A gated-delta layer after its rule: o [B, C, Hv, dv] float32
+    through the gated norm (RMSNorm over a head, times ``silu(gate)``)
+    and the way out, -> [B, C, d]."""
+    b, c = o.shape[:2]
+    with jax.named_scope("linattn_gate"):
+        o = rms_norm(o, bp["lin_norm"], cfg.norm_eps) * jax.nn.silu(
+            gate.reshape(o.shape).astype(jnp.float32))
+    with jax.named_scope("linattn_proj"):
+        return o.reshape(b, c, -1).astype(gate.dtype) @ bp["lin_wo"]
+
+
+def _gated_delta_full(bp: dict, h: jax.Array, cfg: TransformerConfig):
+    """The gated-delta mixing of whole sequences h [B, T, d], each from
+    its own start: no tail, a zero state."""
+    from distributed_model_parallel_tpu.ops.gated_delta import (
+        gated_delta_chunk,
+    )
+
+    b, t = h.shape[:2]
+    q, k, v, log_alpha, beta, gate, _ = gated_delta_inputs(
+        bp, h, cfg,
+        jnp.zeros((b, cfg.lin_conv - 1, cfg.lin_channels), h.dtype),
+        jnp.full((b,), t, jnp.int32))
+    with jax.named_scope("linattn_rule"):
+        o, _ = gated_delta_chunk(
+            q, k, v, log_alpha, beta,
+            jnp.zeros((b, cfg.lin_value_heads, cfg.lin_key_dim,
+                       cfg.lin_value_dim), jnp.float32),
+            jnp.ones((b, t), bool))
+    return gated_delta_output(bp, o, gate, cfg)
+
+
 def block_apply(bp: dict, x: jax.Array, cfg: TransformerConfig,
                 kind: LayerKind | None = None
                 ) -> tuple[jax.Array, jax.Array]:
@@ -491,21 +667,28 @@ def block_apply(bp: dict, x: jax.Array, cfg: TransformerConfig,
     b, t, d = x.shape
     kind = cfg.kinds[0] if kind is None else kind
 
-    h = _norm(bp, "ln1", x, cfg)
-    q, k, v = _qkv_proj(bp, h, cfg)          # q:[B,T,H,Dh] kv:[B,T,Hkv,Dh]
-    if kind.rope:
-        q, k = _rope_qk(q, k, cfg)
-    k, v = _repeat_kv(k, q), _repeat_kv(v, q)
-    o = _attention(q, k, v, cfg, kind.window)  # [B,T,H_local,Dh]
-    o = o.reshape(b, t, -1) @ bp["wo"]       # row-parallel: partial sums
-    if cfg.tp_axis is not None:
-        o = jax.lax.psum(o, cfg.tp_axis)
-    x = x + o
+    h = sublayer_in(bp, "ln1", x, cfg)
+    if kind.mixer == "gated_delta":
+        if cfg.tp_axis is not None or cfg.sp_axis is not None:
+            raise NotImplementedError(
+                "the gated-delta layer runs on one chip: its tensor- and "
+                "sequence-parallel forms are not written (ROADMAP M6)")
+        o = _gated_delta_full(bp, h, cfg)
+    else:
+        q, k, v = _qkv_proj(bp, h, cfg)      # q:[B,T,H,Dh] kv:[B,T,Hkv,Dh]
+        if kind.rope:
+            q, k = _rope_qk(q, k, cfg)
+        k, v = _repeat_kv(k, q), _repeat_kv(v, q)
+        o = _attention(q, k, v, cfg, kind.window)  # [B,T,H_local,Dh]
+        o = o.reshape(b, t, -1) @ bp["wo"]   # row-parallel: partial sums
+        if cfg.tp_axis is not None:
+            o = jax.lax.psum(o, cfg.tp_axis)
+    x = x + sublayer_out(bp, "ln1", o, cfg)
 
-    h = _norm(bp, "ln2", x, cfg)
+    h = sublayer_in(bp, "ln2", x, cfg)
     h, aux = _ffn(bp, h, cfg, tp_axis=cfg.tp_axis, ep_axis=cfg.ep_axis,
                   kind=kind)
-    return x + h, aux
+    return x + sublayer_out(bp, "ln2", h, cfg), aux
 
 
 def _gated(h, wg, wu, wd):
@@ -766,7 +949,9 @@ def _require_default_block(cfg: TransformerConfig, what: str) -> None:
     (LayerNorm, the GELU or capacity-routed FFN, every layer alike); the
     other kinds are served through ``serve.Engine`` (ROADMAP M1)."""
     if (not cfg.homogeneous or cfg.norm != "layernorm" or cfg.ffn != "gelu"
-            or cfg.qk_norm or cfg.moe_dropless):
+            or cfg.qk_norm or cfg.qk_norm_whole or cfg.moe_dropless
+            or cfg.norm_placement != "pre"
+            or cfg.kinds[0].mixer != "attention"):
         raise NotImplementedError(
             f"{what} runs the default block only (LayerNorm, GELU or "
             f"capacity-routed FFN, all layers alike); serve this "

@@ -157,14 +157,25 @@ def paged_attention_xla(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
 # Pallas kernel (decode: one query token per row)
 # ---------------------------------------------------------------------------
 
+# Keys a turn of the decode kernel handles at the least, however many KV
+# heads a page holds: at 32 stored heads 512 rows are ONE page of 16 keys,
+# and a turn's fixed costs (two copies to issue and wait for, a block
+# update) were paid 82 times a row and layer at a context of 1,300
+# (PERF.md section 6, PR 32); 64 keys are 2,048 rows, 0.5 MB a buffer. At
+# 2 and at 8 KV heads the 512 rows already hold 256 and 64 keys.
+_DECODE_MIN_BLOCK_KEYS = 64
+
+
 def _pages_per_block(page: int, hkv: int, dh: int, n: int) -> int:
     """Pages one loop turn of the decode kernel handles, from the shapes
     alone: a block is 512 rows of the pool's ``[P, page * Hkv, Dh]`` view
     while a head is at most 128 wide (256 keys at two KV heads: 128 KB of
-    bf16 for K, as much for V), fewer rows for wider heads; at least one
-    page, never more than a row's table holds."""
+    bf16 for K, as much for V), fewer rows for wider heads, but
+    ``_DECODE_MIN_BLOCK_KEYS`` keys at the least; at least one page, never
+    more than a row's table holds."""
     rows = min(512, max(256, 65536 // dh))
-    return max(1, min(n, rows // (page * hkv)))
+    return max(1, min(n, max(rows // (page * hkv),
+                             _DECODE_MIN_BLOCK_KEYS // page)))
 
 
 def _page_copies(tables_ref, b, pools, bufs, sems, first, last, blk, slot,
@@ -372,6 +383,11 @@ def paged_attention_kernel(q: jax.Array, k_pool: jax.Array,
 # statistics, so 512 keys a turn read 13 % faster than 256 at 4,096 keys
 # and 2 KV heads and 25 % at 8 (PERF.md section 6, PR 29).
 _PREFILL_BLOCK_KEYS = 512
+# ... unless that many keys of all KV heads, in two buffers a pool, pass
+# this much fast memory: a page is copied as it lies in the pool, every KV
+# head's rows, so at 32 stored heads a turn handles 128 keys (at 2 and at
+# 8 heads the 512 stand).
+_PREFILL_BLOCK_BYTES = 4 << 20
 # Fast memory one grid step may hold in its query and output tiles and
 # its carry, of the 16 MiB a kernel gets by default; the rest is for two
 # K/V blocks and a block's scores.
@@ -571,7 +587,9 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
         interpret = jax.devices()[0].platform != "tpu"
     g = h // hkv
     tq = _prefill_query_tile(c, g, dh, q.dtype.itemsize)
-    ppb = max(1, min(tables.shape[1], _PREFILL_BLOCK_KEYS // page))
+    ppb = max(1, min(tables.shape[1], _PREFILL_BLOCK_KEYS // page,
+                     _PREFILL_BLOCK_BYTES
+                     // (4 * page * hkv * dh * k_pool.dtype.itemsize)))
 
     def q_map(bi, kvh, qi, tables_ref, pos0_ref, len_ref):
         return (bi, qi, kvh)

@@ -39,6 +39,7 @@ from distributed_model_parallel_tpu.serve.model import (
     stats_by_layer,
 )
 from distributed_model_parallel_tpu.serve.paged_kv import (
+    CacheKindError,
     CacheLayout,
     PagedKVCache,
     PagePoolError,
@@ -205,11 +206,22 @@ class Engine:
         # layer whole contexts in the shared pool. A stack of equal
         # layers keeps whole pages as it always has, and so does every
         # model under the prefix cache (a shared prefix needs every
-        # layer's pages whole).
+        # layer's pages whole). A gated-delta layer holds no K/V at all:
+        # one recurrent state a slot, in pools indexed by slot. Such a
+        # model cannot share prefixes (PagedKVCache raises) nor speculate.
         layout = CacheLayout.of(
             cfg, page_size=serve.page_size, max_seq_len=serve.max_seq_len,
             span=max(serve.prefill_chunk, serve.spec_k + 1),
             whole_pages=serve.prefix_cache)
+        if layout.n_state and serve.spec_k:
+            raise CacheKindError(
+                "speculation verifies a window of drafts in one forward and "
+                "drops the rejected ones; a state layer's recurrent state "
+                "has then already taken them in. It would take a verify "
+                "step that keeps the state at each position of the window "
+                "and rolls back to the last accepted one "
+                "(serve/model.make_verify_step); run this model with "
+                "spec_k=0")
         self.cache = PagedKVCache(
             cfg, n_pages=serve.n_pages, page_size=serve.page_size,
             max_seq_len=serve.max_seq_len,
@@ -352,6 +364,15 @@ class Engine:
             # prefix sharing + speculative decoding, live
             "prefix_cache": self.serve.prefix_cache,
             "spec_k": self.serve.spec_k,
+            # the cache by layer kind, and what a model with state layers
+            # is refused (CacheKindError at construction, or on the call)
+            "layers_by_cache_kind": {
+                "full": self.cache.layout.n_full,
+                "ring": self.cache.layout.n_ring,
+                "state": self.cache.layout.n_state},
+            "refused_for_state_layers": (
+                ["prefix_cache", "spec_k", "export_request",
+                 "import_request"] if self.cache.layout.n_state else []),
             "cache_hit_rate": self.cache_hit_rate,
             "shared_pages": self.cache.shared_pages,
             "cached_prefix_pages": (len(self.cache.prefix)
@@ -401,6 +422,8 @@ class Engine:
         r = self.cache.layout.ring_pages
         i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
         table = (i32(n), i32(r) if r else None)
+        if self.cache.layout.n_state:
+            table += (jnp.int32(0),)
         tables = (i32(b, n), i32(b, r) if r else None)
         idle = jnp.zeros((b,), bool)
         keys = (jax.vmap(jax.random.key)(jnp.zeros((b,), jnp.uint32))
@@ -436,11 +459,16 @@ class Engine:
         return out
 
     def _slot_tables(self, slot=None) -> tuple:
-        """(table, ring) of one slot, or of all of them, for a step."""
+        """(table, ring) of one slot, or of all of them, for a step; with
+        the slot itself where state layers keep a state a slot (the
+        prefill step has to be told; a decode row is its slot)."""
         pick = (lambda a: a) if slot is None else (lambda a: a[slot])
-        return (jnp.asarray(pick(self._tables_np)),
-                jnp.asarray(pick(self._rings_np))
-                if self._rings_np is not None else None)
+        out = (jnp.asarray(pick(self._tables_np)),
+               jnp.asarray(pick(self._rings_np))
+               if self._rings_np is not None else None)
+        if slot is not None and self.cache.layout.n_state:
+            out += (jnp.int32(slot),)
+        return out
 
     def moe_counters(self) -> dict:
         """The routed layers' counters since the engine was built, fetched
@@ -578,15 +606,17 @@ class Engine:
         keeps the payload it still carries). Slots and pages return to
         this engine immediately; terminal requests stay for the record.
 
-        Where sliding layers keep rings (``CacheLayout``) there is no run
-        of whole pages to copy: a resident request leaves with its
+        Where sliding layers keep rings or state layers a recurrent state
+        (``CacheLayout``) there is no run of whole pages to copy: a
+        resident request leaves with its
         tokens alone and the peer rebuilds its K/V by prefill over
         prompt + committed tokens, as after a crash (``Request.replay``,
         serve/journal.py: the last committed token is re-sampled and
         asserted against the one it carries).
         """
         out: list[Request] = []
-        by_replay = bool(self.cache.layout.ring_pages)
+        by_replay = bool(self.cache.layout.ring_pages
+                         or self.cache.layout.n_state)
         for req in self._requests:
             if req.done:
                 continue

@@ -1,6 +1,7 @@
 """Paged prefill/decode forward over ``models/transformer`` params.
 
-Two jitted programs serve every request shape:
+Two jitted programs serve every request shape (a third, the verify
+step, where the engine speculates):
 
 * the **prefill step** runs one fixed-size chunk of one request's prompt
   against the growing paged cache (the final partial chunk is padded and
@@ -17,18 +18,25 @@ The block math is ``models/transformer``'s own pieces (``_norm``,
 ``_qkv_proj``, ``apply_rope``, ``_ffn``, ``unembed``) with the dense
 cache's write/read swapped for the page pools (``ops/paged_attention``)
 — the training/decode definitions stay single-source, and what a layer
-is (its norm, FFN, window, rotation) comes from the configuration, one
-``LayerKind`` a layer. Routed FFNs are served by the dropless layer
-(``ops/moe.moe_ffn_dropless``): a token's result is its own whatever
-shares the call. The capacity-dropping layer couples co-resident tokens
-and stays refused (serve/engine.py).
+is (its norm and where it sits, FFN, window, rotation, or no attention
+at all) comes from the configuration, one ``LayerKind`` a layer. Two
+block bodies: :func:`paged_block` (softmax attention over pages or a
+ring) and :func:`state_block` (the gated delta rule over a recurrent
+state a slot, ``ops/gated_delta``). Routed FFNs are served by the
+dropless layer (``ops/moe.moe_ffn_dropless``): a token's result is its
+own whatever shares the call. The capacity-dropping layer couples
+co-resident tokens and stays refused (serve/engine.py).
 
 Every step takes and returns the device state it donates: ``pools``
-(``PagedKVCache.pools``: the full layers' K and V pools and the sliding
-layers' ring pools, serve/paged_kv.CacheLayout) and ``stats`` (the routed
-layers' counters, summed on the device; None for a dense model).
-``tables`` is ``(table, ring)``: a sequence's pages of the shared pool,
-and its ring pages (None where no layer keeps a ring).
+(``PagedKVCache.pools``, six arrays by layer kind: the full layers' K and
+V pools, the sliding layers' ring pools, the state layers' recurrent
+states and convolution tails, serve/paged_kv.CacheLayout; None where the
+model has no layer of the kind) and ``stats`` (the routed layers'
+counters, summed on the device; None for a dense model). ``tables`` is
+``(table, ring)``: a sequence's pages of the shared pool and its ring
+pages (None where no layer keeps a ring); the prefill step of a model
+with state layers takes ``(table, ring, slot)``: which slot's state the
+row is (the decode batch's row ``i`` is slot ``i``).
 """
 
 from __future__ import annotations
@@ -42,12 +50,21 @@ from distributed_model_parallel_tpu.models.transformer import (
     LayerKind,
     TransformerConfig,
     _ffn,
-    _norm,
     _qkv_proj,
     apply_rope,
+    gated_delta_inputs,
+    gated_delta_output,
     make_sampler,
     run_layers,
+    sublayer_in,
+    sublayer_out,
     unembed,
+)
+from distributed_model_parallel_tpu.ops.gated_delta import (
+    gated_delta_chunk,
+    gated_delta_decode,
+    pool_state,
+    unpool_state,
 )
 from distributed_model_parallel_tpu.ops.paged_attention import (
     paged_attention,
@@ -61,11 +78,11 @@ def paged_block(bp: dict, kind: LayerKind, pools: tuple, where: tuple,
                 cfg: TransformerConfig, *, impl: str):
     """One transformer block over the paged cache.
 
-    x: [B, C, d]; positions: [B, C] absolute; pools: (ck, cv, wk, wv),
-    each [L_kind, P, page, Hkv, Dh]; where: (ring, layer): this layer is
-    layer ``layer`` (traced or not) of the ring pools or of the full
-    ones; writes[ring] = (pages [B, C], tables [B, N]): the physical page
-    of each token — an out-of-range id drops the write (idle slots,
+    x: [B, C, d]; positions: [B, C] absolute; pools: (ck, cv, wk, wv,
+    ...), the first four each [L_kind, P, page, Hkv, Dh]; where: (ring,
+    layer): this layer is layer ``layer`` (traced or not) of the ring
+    pools or of the full ones; writes[ring] = (pages [B, C], tables
+    [B, N]): the physical page of each token — an out-of-range id drops the write (idle slots,
     prompt padding) — and the logical-to-physical table the read follows;
     offsets: [B, C] within the page; lengths: [B] valid K prefix (after
     this step's writes); valid: [B, C] tokens that exist (the routed
@@ -78,15 +95,24 @@ def paged_block(bp: dict, kind: LayerKind, pools: tuple, where: tuple,
     """
     b, c = x.shape[:2]
     ring, layer = where
-    kpool, vpool = pools[2:] if ring else pools[:2]
+    at = 2 if ring else 0
+    kpool, vpool = pools[at:at + 2]
     pages, tables = writes[ring]
-    h = _norm(bp, "ln1", x, cfg)
+    h = sublayer_in(bp, "ln1", x, cfg)
     q, k, v = _qkv_proj(bp, h, cfg)          # q:[B,C,H,Dh] kv:[B,C,Hkv,Dh]
     if kind.rope:
         # Per-row positions: the continuous batch has every row at its
         # own offset. The cache stores rotated keys, like the dense path.
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
+    n_heads = q.shape[2]
+    extra = kpool.shape[-2] - k.shape[2]
+    if extra:
+        # the pool stores more KV heads than the model has
+        # (paged_kv.stored_kv_heads): zero heads, and zero queries to them
+        heads = lambda n: ((0, 0), (0, 0), (0, n), (0, 0))      # noqa: E731
+        q = jnp.pad(q, heads(extra * (n_heads // k.shape[2])))
+        k, v = jnp.pad(k, heads(extra)), jnp.pad(v, heads(extra))
     kpool = kpool.at[layer, pages, offsets].set(
         k.astype(kpool.dtype), mode="drop")
     vpool = vpool.at[layer, pages, offsets].set(
@@ -94,22 +120,110 @@ def paged_block(bp: dict, kind: LayerKind, pools: tuple, where: tuple,
     with jax.named_scope("attn_sliding" if ring else "attn_full"):
         o = paged_attention(q, kpool, vpool, tables, positions, lengths,
                             window=kind.window, impl=impl, layer=layer)
-    pools = pools[:2] + (kpool, vpool) if ring else (kpool, vpool) + pools[2:]
-    x = x + o.reshape(b, c, -1) @ bp["wo"]
-    h = _norm(bp, "ln2", x, cfg)
+    if extra:
+        o = o[:, :, :n_heads]
+    pools = pools[:at] + (kpool, vpool) + pools[at + 2:]
+    x = x + sublayer_out(bp, "ln1", o.reshape(b, c, -1) @ bp["wo"], cfg)
+    x, aux = _ffn_sublayer(bp, kind, x, valid, cfg)
+    return x, pools, aux
+
+
+def _ffn_sublayer(bp: dict, kind: LayerKind, x, valid, cfg):
+    """The block's second half, the same after either mixer."""
+    h = sublayer_in(bp, "ln2", x, cfg)
     h, aux = _ffn(bp, h, cfg, tp_axis=None, ep_axis=None, kind=kind,
                   valid=valid)
-    return x + h, pools, aux
+    return x + sublayer_out(bp, "ln2", h, cfg), aux
+
+
+def state_block(bp: dict, kind: LayerKind, pools: tuple, layer,
+                x: jax.Array, valid: jax.Array, fresh,
+                cfg: TransformerConfig, *, impl: str):
+    """One block whose mixer is the gated delta rule, over the state
+    pools.
+
+    x: [B, C, d]; pools[4:]: (state [L, N, dk, Hv * dv] float32, tail
+    [L, N, K - 1, ch]); ``layer`` (traced or not) this layer's index in
+    them; valid: [B, C] tokens that exist: padding of a last chunk and
+    idle decode rows leave state and tail as they are. Row ``i`` of the
+    batch is row ``i`` of the pools, in both callers:
+
+    * the decode round (``fresh`` None, ``C == 1``, ``N`` the engine's
+      slots): the recurrence's one step on every row of the layer's slab,
+      read once and written once where it lies (``gated_delta_decode``:
+      the pool goes in whole with ``layer``, no slab is cut out);
+    * the prefill chunk (``B == N == 1``: :func:`_layers` hands in the
+      row's own slot, all layers of it, and puts it back): the chunked
+      form from the slot's state, or from zeros where ``fresh`` says the
+      sequence starts here (nothing cleared the slot when its last
+      tenant left).
+
+    Returns ``(x, pools, aux)`` like :func:`paged_block`.
+    """
+    b, c = x.shape[:2]
+    state, tails = pools[4:]
+    if b != state.shape[1] or (fresh is None and c != 1):
+        raise NotImplementedError(
+            "a state layer takes one token a slot (the decode round) or "
+            "one sequence's chunk (prefill); a verify window would need "
+            "the state rolled back past rejected drafts")
+    h = sublayer_in(bp, "ln1", x, cfg)
+    tail = tails[layer]
+    if fresh is not None:
+        tail = jnp.where(fresh, jnp.zeros_like(tail), tail)
+    q, k, v, log_alpha, beta, gate, tail1 = gated_delta_inputs(
+        bp, h, cfg, tail, jnp.sum(valid, axis=1, dtype=jnp.int32))
+    with jax.named_scope("linattn_rule"):
+        if fresh is None:
+            live = valid[:, 0, None]
+            # an idle row: alpha 1, beta 0, and zeros for whatever its
+            # garbage token gave (zero times NaN would be NaN)
+            q, k, v = (jnp.where(live[..., None], a[:, 0], 0)
+                       for a in (q, k, v))
+            o, state = gated_delta_decode(
+                state, layer, q, k, v,
+                jnp.where(live, jnp.exp(log_alpha[:, 0]), 1.0),
+                jnp.where(live, beta[:, 0], 0.0), impl=impl)
+            o = o[:, None]
+        else:
+            s0 = state[layer]
+            s0 = jnp.where(fresh, jnp.zeros_like(s0), s0)
+            o, s1 = gated_delta_chunk(
+                q, k, v, log_alpha, beta,
+                unpool_state(s0, cfg.lin_value_heads), valid)
+            state = state.at[layer].set(pool_state(s1))
+    tails = tails.at[layer].set(tail1)
+    x = x + sublayer_out(bp, "ln1", gated_delta_output(bp, o, gate, cfg),
+                         cfg)
+    x, aux = _ffn_sublayer(bp, kind, x, valid, cfg)
+    return x, pools[:4] + (state, tails), aux
 
 
 def _layers(params: dict, pools, stats, x, positions, pages, tables,
             valid, lengths, cfg, layout: CacheLayout | None, impl: str,
-            page_size: int):
+            page_size: int, fresh=None):
     """All layers over the paged cache (``run_layers``). pages [B, C]:
     each token's logical page (an invalid token's is irrelevant);
-    tables: (table [B, N], ring [B, R] or None). Returns ``(x, pools,
-    stats)`` with the routed layers' counters added to ``stats``."""
-    table, ring = tables
+    tables: (table [B, N], ring [B, R] or None[, slot [B]: the prefill
+    row's slot, for state layers]); ``fresh`` (the prefill step's; None
+    in a decode round): the row's sequence starts with this call (a state
+    layer then starts from zeros). Returns ``(x, pools, stats)`` with the
+    routed layers' counters added to ``stats``."""
+    table, ring, *rest = tables
+    mine = None
+    if rest:
+        # The prefill row's own slot of the state pools, every layer of
+        # it, taken out once and put back once: the layers work on 27 MB
+        # and the pools (0.85 GB) see one read and one write in place.
+        # (Sliced and updated a layer at a time under the scan, XLA
+        # rematerialised an update to shorten the pool's live range, and
+        # a second update of one buffer is a copy of it: read in the step
+        # compiled for a described v5e.)
+        mine = (0, rest[0][0], 0, 0)
+        whole = pools[4:]
+        pools = pools[:4] + tuple(
+            jax.lax.dynamic_slice(p, mine, (p.shape[0], 1) + p.shape[2:])
+            for p in whole)
     n = table.shape[1]
     offsets = positions % page_size
 
@@ -128,12 +242,21 @@ def _layers(params: dict, pools, stats, x, positions, pages, tables,
     def layer(bp, kind, body, rep, carry):
         x, pools = carry
         is_ring, base, stride = bodies[body]
-        x, pools, aux = paged_block(
-            bp, kind, pools, (is_ring, base + rep * stride), x, positions,
-            writes, offsets, lengths, valid, cfg, impl=impl)
+        if is_ring is None:                    # a state layer: no K/V
+            x, pools, aux = state_block(
+                bp, kind, pools, base + rep * stride, x, valid, fresh, cfg,
+                impl=impl)
+        else:
+            x, pools, aux = paged_block(
+                bp, kind, pools, (is_ring, base + rep * stride), x,
+                positions, writes, offsets, lengths, valid, cfg, impl=impl)
         return (x, pools), (aux if routed and kind.ffn == "moe" else None)
 
     (x, pools), counts = run_layers(params, (x, pools), layer, cfg)
+    if mine is not None:
+        pools = pools[:4] + tuple(
+            jax.lax.dynamic_update_slice(p, new, mine)
+            for p, new in zip(whole, pools[4:]))
     if stats is not None:
         stats = jax.tree.map(jnp.add, stats, counts)
     return x, pools, stats
@@ -190,9 +313,12 @@ def make_prefill_step(cfg: TransformerConfig, *, page_size: int,
     """One request's prompt chunk against the paged cache.
 
     Returns ``step(params, pools, stats, tokens [1, chunk], pos0,
-    n_valid, tables ([N], [R] | None), key) -> (pools, stats, next_token
-    [1])``. ``pos0``/``n_valid`` are traced scalars, so every chunk of
-    every prompt length hits one compiled program. The returned token is
+    n_valid, tables ([N], [R] | None[, slot]), key) -> (pools,
+    stats, next_token [1])``. ``slot`` (a scalar) says whose recurrent
+    state the row is, where the model has state layers; a chunk at
+    ``pos0 == 0`` with a valid token starts them from zeros.
+    ``pos0``/``n_valid`` are traced scalars, so every chunk of every
+    prompt length hits one compiled program. The returned token is
     sampled from the last VALID position's logits — meaningful only on
     the final chunk (it becomes the request's first generated token,
     ``generate()``'s ``tok0``); earlier chunks discard it.
@@ -209,7 +335,8 @@ def make_prefill_step(cfg: TransformerConfig, *, page_size: int,
         x, pools, stats = _layers(
             params, pools, stats, x, positions, positions // page_size,
             jax.tree.map(lambda t: t[None], tables), valid, lengths, cfg,
-            layout, impl, page_size)
+            layout, impl, page_size,
+            fresh=jnp.logical_and(pos0 == 0, n_valid > 0))
         xl = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
         logits = unembed(params, xl, cfg)[:, 0]               # [1, V]
         sub = (jax.random.fold_in(key, pos0 + n_valid - 1) if sampled

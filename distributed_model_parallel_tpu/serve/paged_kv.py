@@ -53,11 +53,17 @@ class PagePoolError(RuntimeError):
 
 
 class CacheKindError(NotImplementedError):
-    """Asked of a cache with sliding (ring) layers what only whole
-    contexts can give: prefix sharing (a ring holds no page a second
-    sequence could read) or migration of pages by value. The engine
-    never asks: under ``prefix_cache`` it lays every layer out in whole
-    pages, and it drains a ring cache by replaying tokens."""
+    """Asked of a cache what only whole contexts in pages can give.
+    Sliding (ring) layers: prefix sharing (a ring holds no page a second
+    sequence could read) or migration of pages by value; the engine never
+    asks: under ``prefix_cache`` it lays every layer out in whole pages,
+    and it drains a ring cache by replaying tokens. State layers (a
+    recurrent state a slot, no pages at all): the same two, and
+    speculation; there is no other layout to fall back to, so the engine
+    refuses ``prefix_cache`` and ``spec_k`` for such a model, each with
+    what it would take (a state snapshot where a shared prefix ends; a
+    rollback of the state past rejected drafts; the state by value), and
+    drains by replay."""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,6 +80,14 @@ class CacheLayout:
     is the most tokens one program writes before it reads (the prefill
     chunk, or the verify window).
 
+    A **state** layer (``LayerKind.mixer == "gated_delta"``) has no K/V
+    at all: it keeps one recurrent state ``[dk, Hv * dv]`` float32 and
+    the short convolution's last ``K - 1`` inputs for each of the
+    engine's slots, whatever the context: pools indexed by **slot**,
+    allocated with the cache, counted by admission as "a slot" and never
+    by the page pool. Nothing clears them on release: a sequence's first
+    chunk starts from zeros inside the step (serve/model.py).
+
     Rings are for a model whose layers differ (sliding layers beside
     full ones). A stack of equal layers keeps whole pages whatever its
     window, as it always has, and so does every model under
@@ -83,14 +97,16 @@ class CacheLayout:
 
     ``bodies``: for each of the ``n_lead + period`` layer bodies of
     ``cfg.layer_plan`` ``(ring, base, stride)``: the layer of repeat
-    ``rep`` is layer ``base + rep * stride`` of the ring pools (``ring``)
-    or of the full pools.
+    ``rep`` is layer ``base + rep * stride`` of the ring pools (``ring``
+    True), of the full pools (False), or of the state pools (None: the
+    layer holds no K/V).
     """
 
     ring_pages: int          # 0: no layer keeps a ring
     n_full: int
     n_ring: int
     bodies: tuple
+    n_state: int = 0
 
     @classmethod
     def of(cls, cfg, *, page_size: int, max_seq_len: int, span: int,
@@ -101,13 +117,15 @@ class CacheLayout:
         whole_pages = whole_pages or cfg.homogeneous
 
         def ring_of(kind):
-            if whole_pages or kind.window is None:
+            if (whole_pages or kind.window is None
+                    or kind.mixer == "gated_delta"):
                 return 0
             r = ring_pages_for(kind.window, page_size, span)
             return r if r < pages_per_seq else 0
 
         rings = [ring_of(k) for k in kinds]
-        ring = [bool(r) for r in rings]
+        ring = [None if k.mixer == "gated_delta" else bool(r)
+                for k, r in zip(kinds, rings)]
         in_period = ring[n_lead:n_lead + period]
         # body j is layer j of the first repeat: as many layers of its
         # kind lie before it; a repeat later, as many more as a period has
@@ -117,12 +135,24 @@ class CacheLayout:
             for j in range(n_lead + period))
         return cls(ring_pages=max(rings, default=0),
                    n_full=ring.count(False), n_ring=ring.count(True),
-                   bodies=bodies)
+                   bodies=bodies, n_state=ring.count(None))
 
     @classmethod
     def all_full(cls, n_layers: int) -> "CacheLayout":
         """Every layer keeps whole contexts: one stack of equal layers."""
         return cls(0, n_layers, 0, ((False, 0, 1),))
+
+
+def stored_kv_heads(kv_heads: int) -> int:
+    """KV heads a pool stores for a model of ``kv_heads``: above 16, the
+    next multiple of 16. The paged kernels read a page as ``[page * Hkv,
+    Dh]`` rows, a view of the pool only where the chip lays ``[Hkv, Dh]``
+    out without padding; bfloat16 tiles hold 16 rows, so 30 heads lie
+    there as 32, and the view became a copy of the whole pool, 2 GB a
+    pool and step (read in the step compiled for a described v5e). The
+    extra heads hold zeros; ``serve/model.paged_block`` pads what it
+    writes and asks, and drops what they return."""
+    return kv_heads if kv_heads <= 16 else -(-kv_heads // 16) * 16
 
 
 def ring_pages_for(window: int, page_size: int, span: int) -> int:
@@ -213,7 +243,10 @@ class PagedKVCache:
 
     ``ck``/``cv``: [L, n_pages, page_size, Hkv, Dh] device arrays the
     engine threads through its jitted steps (donated, so XLA updates
-    them in place). The page table of sequence ``sid`` maps logical page
+    them in place), beside the sliding layers' rings ``wk``/``wv`` and
+    the state layers' ``state``/``tail`` where the model has such layers
+    (``CacheLayout``; None where it has not). The page table of
+    sequence ``sid`` maps logical page
     ``i`` (tokens [i*page, (i+1)*page)) to a physical pool page;
     :meth:`table_array` pads it to the static per-sequence maximum with
     id 0 — padded entries are masked by length in the attention read, so
@@ -274,8 +307,8 @@ class PagedKVCache:
                 "shareable pages; this layout keeps a ring a sequence for "
                 "the sliding layers (CacheLayout.of(..., whole_pages=True) "
                 "keeps none; docs/SERVING.md)")
-        shape = (layout.n_full, n_pages, page_size, cfg.kv_heads,
-                 cfg.head_dim)
+        shape = (layout.n_full, n_pages, page_size,
+                 stored_kv_heads(cfg.kv_heads), cfg.head_dim)
         self.ck = jnp.zeros(shape, cfg.dtype)
         self.cv = jnp.zeros_like(self.ck)
         self.wk = self.wv = self.ring_pool = None
@@ -288,6 +321,28 @@ class PagedKVCache:
             self.wk = jnp.zeros((layout.n_ring, self.ring_pool.n_pages)
                                 + shape[2:], cfg.dtype)
             self.wv = jnp.zeros_like(self.wk)
+        # State layers: for each of the ``n_seqs`` slots one recurrent
+        # state [dk, Hv * dv] float32 (ops/gated_delta.pool_state: the
+        # heads side by side, whole lane tiles) and the convolution's last
+        # K - 1 inputs.
+        self.state = self.tail = None
+        if layout.n_state:
+            if n_seqs < 1:
+                raise ValueError("a cache with state layers needs n_seqs, "
+                                 "the engine's slots")
+            if prefix_cache:
+                raise CacheKindError(
+                    "prefix sharing needs every layer's whole context in "
+                    "shareable pages; a state layer keeps one recurrent "
+                    "state a slot and no page. Sharing would take a "
+                    "snapshot of the state where the shared prefix ends "
+                    "(docs/SERVING.md)")
+            self.state = jnp.zeros(
+                (layout.n_state, n_seqs, cfg.lin_key_dim,
+                 cfg.lin_value_heads * cfg.lin_value_dim), jnp.float32)
+            self.tail = jnp.zeros(
+                (layout.n_state, n_seqs, cfg.lin_conv - 1,
+                 cfg.lin_channels), cfg.dtype)
 
     def pages_needed(self, n_tokens: int) -> int:
         return -(-n_tokens // self.page_size)
@@ -295,11 +350,18 @@ class PagedKVCache:
     @property
     def pools(self) -> tuple:
         """The device state the jitted steps thread (and donate)."""
-        return (self.ck, self.cv, self.wk, self.wv)
+        return (self.ck, self.cv, self.wk, self.wv, self.state, self.tail)
 
     @pools.setter
     def pools(self, pools) -> None:
-        self.ck, self.cv, self.wk, self.wv = pools
+        self.ck, self.cv, self.wk, self.wv, self.state, self.tail = pools
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Bytes one slot holds in the state layers' pools (0 without)."""
+        if self.state is None:
+            return 0
+        return (self.state.nbytes + self.tail.nbytes) // self.state.shape[1]
 
     def open(self, sid) -> None:
         """Start ``sid``'s table; a sequence of a model with sliding
@@ -543,6 +605,12 @@ class PagedKVCache:
                 f"{what}: a sliding layer's ring is not a run of whole "
                 f"pages that could be copied by value; migrate such a "
                 f"sequence by replaying its tokens (serve/journal.py)")
+        if self.state is not None:
+            raise CacheKindError(
+                f"{what}: a state layer's memory is its slot's recurrent "
+                f"state, not pages; moving it would take the state and the "
+                f"convolution's tail by value. Migrate such a sequence by "
+                f"replaying its tokens (serve/journal.py)")
 
     def cached_prefix_tokens(self, tokens: list[int]) -> int:
         """Usable cached-prefix length for ``tokens`` (quantized to the
@@ -581,6 +649,11 @@ def memory_gauges(cache: PagedKVCache) -> dict:
         "sliding_layer_pages": (cache.ring_pool.used_pages
                                 * cache.layout.n_ring
                                 if cache.ring_pool is not None else 0),
+        # a state layer holds no page: every resident sequence, one slot's
+        # recurrent states and convolution tails, whatever its context
+        "state_slots": len(cache._tables) if cache.state is not None else 0,
+        "state_bytes": (len(cache._tables) * cache.state_bytes_per_slot
+                        if cache.state is not None else 0),
     }
 
 

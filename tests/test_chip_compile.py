@@ -23,6 +23,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from distributed_model_parallel_tpu.ops import (
+    gated_delta as gd,
     paged_attention as pa,
     pallas_attention as fa,
     pallas_optim,
@@ -67,9 +68,13 @@ def _compiled_text(fn, *args) -> str:
     # a sliding layer (its table is the ring's pages, repeated)
     (64, 64, 8, 16384, jnp.bfloat16, None),
     (64, 64, 8, 16384, jnp.bfloat16, 128),
+    # olmo-hybrid-7b-pp2 under traffic/longgen-batch.json: 32 slots, 30
+    # heads with no grouping, stored as 32 (paged_kv.stored_kv_heads), 512
+    # pages a row
+    (32, 32, 32, 8192, jnp.bfloat16, None),
 ], ids=["bf16", "f32", "bf16-gqa", "f32-gqa", "bf16-window",
         "starcoder2-3b-serving", "k-exaone-full-layer",
-        "k-exaone-sliding-layer"])
+        "k-exaone-sliding-layer", "olmo-hybrid-full-layer"])
 def test_paged_decode_kernel_compiles(one_chip, b, h, hkv, t, dtype, window):
     """Serving decode shapes: heads x 128, page 16, one pool page for
     every slot's full context."""
@@ -100,8 +105,11 @@ def test_paged_decode_kernel_compiles(one_chip, b, h, hkv, t, dtype, window):
     (64, 8, 1024, jnp.bfloat16, None),
     (64, 8, 1024, jnp.bfloat16, 128),
     (8, 2, 128, jnp.float32, None),
+    # olmo-hybrid-7b-pp2: 32 stored heads, one query head a KV head; a
+    # turn's keys are cut to what two buffers of all heads' rows may hold
+    (32, 32, 512, jnp.bfloat16, None),
 ], ids=["starcoder2-3b-serving", "k-exaone-full-layer",
-        "k-exaone-sliding-layer", "f32-gqa"])
+        "k-exaone-sliding-layer", "f32-gqa", "olmo-hybrid-full-layer"])
 def test_paged_prefill_kernel_compiles(one_chip, h, hkv, n, dtype, window):
     """The serving cells' prefill chunk: 512 query tokens of one row,
     heads x 128, page 16, within the default scoped VMEM."""
@@ -131,6 +139,8 @@ def _compile_kernels(monkeypatch):
     for name in ("paged_attention_kernel", "paged_prefill_attention"):
         monkeypatch.setattr(
             pa, name, functools.partial(getattr(pa, name), interpret=False))
+    monkeypatch.setattr(gd, "gated_delta_kernel", functools.partial(
+        gd.gated_delta_kernel, interpret=False))
 
 
 @pytest.mark.parametrize("impl", ["pallas", "xla"])
@@ -275,6 +285,121 @@ def test_serving_steps_cut_no_slab_out_of_the_pools(
             op == "scatter" or (op == "fusion" and "/scatter\"" in line))]
         assert not moved, moved[0][:300]
         assert whole                       # the pattern reads this program
+
+
+def test_gated_delta_decode_kernel_compiles(one_chip):
+    """The decode round's state update at the published widths (30 heads
+    of 96 x 192, 32 slots, 12 layers): Mosaic takes it, and the pool goes
+    in and comes out as one buffer: no op of the program yields a pool or
+    a layer's slab of it but the kernel."""
+    n_layers, n, h, dk, dv = 12, 32, 30, 96, 192
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    text = jax.jit(
+        functools.partial(gd.gated_delta_kernel, interpret=False),
+        donate_argnums=(0,)).lower(
+        sds((n_layers, n, dk, h * dv), jnp.float32), sds((), jnp.int32),
+        sds((n, h, dk), jnp.bfloat16), sds((n, h, dk), jnp.bfloat16),
+        sds((n, h, dv), jnp.bfloat16), sds((n, h), jnp.float32),
+        sds((n, h), jnp.float32)).compile().as_text()
+    assert re.search(r"%gated_delta_decode[\w.]* = .*custom-call\(", text)
+    moved = _state_ops(text, (n_layers, n, dk, h * dv))
+    assert [op for op, _ in moved] == ["custom-call"], moved
+
+
+def _state_ops(text: str, pool: tuple) -> list:
+    """The instructions, as ``(opcode, line)``, that yield the state pool
+    ``[L, N, dk, Hv * dv]`` or one layer's slab of it (``[1,]N,dk,...``);
+    what moves nothing is left out."""
+    n_layers, n, dk, hd = pool
+    shapes = f"{n_layers},{n},{dk},{hd}|1,{n},{dk},{hd}|{n},{dk},{hd}"
+    # "%name = <type, maybe a tuple> opcode(": a layout's "T(8,128)" has
+    # no space before it, an opcode has
+    ops = re.finditer(r"^\s*(?:ROOT )?%\S+ = (.*?)\s([a-z][\w-]*)\(.*$",
+                      text, re.M)
+    return [(m.group(2), m.group(0)) for m in ops
+            if re.search(rf"f32\[(?:{shapes})\]", m.group(1))
+            and m.group(2) not in ("parameter", "bitcast",
+                                   "get-tuple-element", "tuple", "while")]
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+def test_hybrid_steps_copy_no_state_slab_and_no_pool(
+        one_chip, monkeypatch, step):
+    """The steps of a model with state layers (two periods of linear,
+    linear, linear, full, so the layer index is traced; the published
+    linear widths and 30 heads stored as 32; 32 slots), compiled for the
+    chip. Decode: the state pool is yielded by the three kernel calls of
+    the period's body and by nothing else: no slab ``f32[1,32,96,5760]``
+    is cut out. Prefill: one write in place of the row's own slot (27 MB
+    at the cell's 12 layers), the pool never copied. Either way the K/V
+    pools of 32 stored heads reach the paged kernels as views (the 30
+    heads the model has would be laid out as 32 and copied whole)."""
+    from distributed_model_parallel_tpu.models import transformer as tfm
+    from distributed_model_parallel_tpu.serve import model as sm
+    from distributed_model_parallel_tpu.serve.paged_kv import (
+        CacheLayout,
+        stored_kv_heads,
+    )
+
+    _compile_kernels(monkeypatch)
+    page, chunk, max_seq, n_pages, slots = 16, 512, 8192, 4096, 32
+    lin, full = tfm.LayerKind(mixer="gated_delta"), tfm.LayerKind()
+    cfg = tfm.TransformerConfig(
+        vocab_size=1024, d_model=256, n_heads=30, n_kv_heads=30, d_head=128,
+        n_layers=8, d_ff=512, max_seq_len=max_seq, dtype=jnp.bfloat16,
+        pos_embedding="rope", norm="rmsnorm", ffn="swiglu",
+        qk_norm_whole=True, norm_placement="post",
+        layer_kinds=(lin, lin, lin, full) * 2, lin_key_heads=30,
+        lin_value_heads=30, lin_key_dim=96, lin_value_dim=192,
+        lin_neg_eigval=True)
+    assert cfg.layer_plan == (0, 4, 2)
+    layout = CacheLayout.of(cfg, page_size=page, max_seq_len=max_seq,
+                            span=chunk)
+    assert (layout.n_full, layout.n_state) == (2, 6)
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda: tfm.init_params(jax.random.key(0), cfg)))
+    assert stored_kv_heads(30) == 32
+    kv = sds((2, n_pages, page, 32, 128), jnp.bfloat16)
+    state = (6, slots, 96, 30 * 192)
+    pools = (kv, kv, None, None, sds(state, jnp.float32),
+             sds((6, slots, 3, 11520), jnp.bfloat16))
+    n = max_seq // page
+    kws = dict(page_size=page, impl="pallas", layout=layout)
+    if step == "decode":
+        lowered = sm.make_decode_step(cfg, **kws).lower(
+            params, pools, None, sds((slots,)), sds((slots,)),
+            (sds((slots, n)), None), sds((slots,), jnp.bool_), None)
+    else:
+        lowered = sm.make_prefill_step(cfg, chunk=chunk, **kws).lower(
+            params, pools, None, sds((1, chunk)), sds(()), sds(()),
+            (sds((n,)), None, sds(())), None)
+    text = lowered.compile().as_text()
+    moved = _state_ops(text, state)
+    if step == "decode":
+        assert len(re.findall(
+            r"%gated_delta_decode[\w.]* = .*custom-call\(", text)) == 3
+        assert [op for op, _ in moved] == ["custom-call"] * 3, moved
+    else:
+        assert "%gated_delta_decode" not in text
+        # one write in place (and the fusion that holds it)
+        assert [op for op, _ in moved].count("dynamic-update-slice") == 1
+        assert all(op == "dynamic-update-slice" or (
+            op == "fusion" and "dynamic_update_slice" in line)
+            for op, line in moved), moved
+    slabs, whole = _pool_ops(text, kv.shape)
+    assert not slabs, slabs[0][1][:300]
+    copies = [line for op, line in whole if not (
+        op == "scatter" or (op == "fusion" and "/scatter\"" in line))]
+    assert not copies, copies[0][:300]
+    assert whole
 
 
 def test_a_slab_cut_out_under_a_scan_is_found(one_chip):

@@ -536,6 +536,50 @@ def test_jitted_steps_lower_to_named_modules():
     assert got[-2:] == ["jit_lm_train_step"] * 2
 
 
+def test_state_layers_carry_their_scopes_and_gauges():
+    """A model with gated-delta layers: both steps' device ops sit under
+    ``linattn_proj``, ``linattn_conv``, ``linattn_rule`` and
+    ``linattn_gate`` (the full layers keep ``attn_full``), the decode
+    round's rule is the kernel named ``gated_delta_decode``, and the
+    memory gauges count the slots that hold a state."""
+    import jax.numpy as jnp
+    from distributed_model_parallel_tpu.models import transformer as tfm
+    from distributed_model_parallel_tpu.ops import gated_delta
+    from distributed_model_parallel_tpu.serve import Engine, ServeConfig
+    from distributed_model_parallel_tpu.serve.paged_kv import memory_gauges
+
+    lin, full = tfm.LayerKind(mixer="gated_delta"), tfm.LayerKind()
+    cfg = tfm.TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_kv_heads=2, n_layers=4,
+        d_ff=64, max_seq_len=64, pos_embedding="rope", norm="rmsnorm",
+        ffn="swiglu", qk_norm_whole=True, norm_placement="post",
+        layer_kinds=(lin, lin, lin, full), lin_key_heads=2,
+        lin_value_heads=2, lin_key_dim=8, lin_value_dim=64)
+    eng = Engine(tfm.init_params(jax.random.key(0), cfg), cfg,
+                 ServeConfig(n_slots=2, page_size=8, n_pages=32,
+                             max_seq_len=64, prefill_chunk=8,
+                             attn_impl="pallas"), slo_metrics=False)
+    scopes = ("linattn_proj", "linattn_conv", "linattn_rule",
+              "linattn_gate", "attn_full")
+    got = eng.op_scopes(scopes)
+    assert sorted(got) == ["jit_decode_step", "jit_prefill_step"]
+    for module in got.values():
+        assert set(module.values()) == set(scopes)
+    # the kernel carries its name into the op line, inside its scope
+    x = jnp.zeros((2, 2, 8))
+    jaxpr = jax.make_jaxpr(lambda pool: gated_delta.gated_delta_kernel(
+        pool, jnp.int32(0), x, x, jnp.zeros((2, 2, 64)), jnp.ones((2, 2)),
+        jnp.ones((2, 2))))(jnp.zeros((3, 2, 8, 128)))
+    assert _pallas_call_names(jaxpr.jaxpr, []) == ["gated_delta_decode"]
+    req = eng.submit([1, 2, 3], 5)
+    eng.step_once(0.0, 0.0)
+    g = memory_gauges(eng.cache)
+    assert g["state_slots"] == 1 and req.slot is not None
+    assert g["state_bytes"] == eng.cache.state_bytes_per_slot == 3 * (
+        8 * 128 * 4 + 3 * (2 * 2 * 8 + 128) * 4)
+    assert g["full_layer_pages"] == g["used_pages"] == 1
+
+
 def test_build_trace_tolerates_minimal_and_foreign_records():
     # Empty-ish and schema-poor records must not KeyError the exporter.
     trace = build_trace([])
