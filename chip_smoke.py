@@ -584,6 +584,7 @@ def phase_serve_routed(size: dict, block: dict, on_tpu: bool,
     import jax.numpy as jnp
 
     from distributed_model_parallel_tpu.models import transformer as tfm
+    from distributed_model_parallel_tpu.ops import moe
     from distributed_model_parallel_tpu.serve import ServeConfig
 
     kernel = "auto" if on_tpu else "pallas"
@@ -602,6 +603,26 @@ def phase_serve_routed(size: dict, block: dict, on_tpu: bool,
         moe_d_ff=block["d_expert"], moe_shared_experts=1,
         moe_experts_held=block["held"])
     params = tfm.init_params(jax.random.key(seed), cfg)
+    # the held experts' products on rows sorted by expert: the kernel
+    # (compiled on the chip, interpreted off it) against ragged_dot, at a
+    # chunk's rows: groups of unequal size, one empty, rows past the held
+    bp = jax.tree.map(lambda a: a[0], params["blocks"][1])
+    rows = size["chunk"] * block["top_k"]
+    sizes = jnp.asarray([rows // 16, 0, rows // 4, 3], jnp.int32)
+    xs = jax.random.normal(jax.random.key(seed + 1), (rows, cfg.d_model))
+    with jax.default_matmul_precision("highest"):
+        visits = moe.row_tile_visits(sizes, rows)
+        got = moe.expert_products(xs, bp, sizes, visits,
+                                  interpret=not on_tpu)
+        ragged = jax.lax.ragged_dot
+        want = ragged(jax.nn.silu(ragged(xs, bp["we_g"], sizes))
+                      * ragged(xs, bp["we_u"], sizes), bp["we_d"], sizes)
+    held = int(sizes.sum())
+    worst = float(jnp.max(jnp.abs(got[:held] - want[:held])))
+    check(worst <= RULE_ATOL, f"grouped expert products, kernel vs "
+          f"ragged_dot over {held} held rows of {rows} in "
+          f"{int(visits[3])} visits: within {worst:.2e} (limit "
+          f"{RULE_ATOL:.0e})")
     pages_per_seq = -(-size["max_seq"] // size["page"])
     geometry = dict(n_slots=size["n_slots"], page_size=size["page"],
                     n_pages=(size["n_slots"] + 1) * pages_per_seq,

@@ -19,9 +19,12 @@ Shapes (inside shard_map over the expert axis):
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 @dataclasses.dataclass(frozen=True)
@@ -225,6 +228,155 @@ def route_scores(params: dict, xf: jax.Array, cfg: MoEConfig):
     return experts.astype(jnp.int32), w * cfg.routed_scale
 
 
+# The grouped products' row tile: an MXU pass streams up to 128 rows through
+# a 128 x 128 block of weights, so a visit with fewer rows costs the same and
+# one with more costs more (XLA:TPU's ragged dot works in tiles of 512: 16
+# experts of 3 or 32 rows each pay for 512).
+ROW_TILE = 128
+# The bytes of the weight blocks a grid step streams from HBM (all of K by
+# some of an expert's columns, of each product's weights): megabytes, so
+# that the copy runs at the memory's bandwidth; two buffers of it are held
+# in fast memory.
+_WEIGHT_BLOCK_BYTES = 12 << 20
+
+
+def row_tile(rows: int) -> int:
+    """The grouped products' row tile for ``rows`` sorted rows."""
+    return min(ROW_TILE, rows)
+
+
+def row_tile_visits(sizes: jax.Array, rows: int):
+    """The schedule of the grouped products: which (row tile, group) pairs
+    of ``rows`` sorted rows, ``row_tile(rows)`` to a row tile, hold a row,
+    in row order. ``sizes`` int32 [G], the groups' rows (the rows past
+    their sum belong to no group and are in no visit). Returns ``(offsets
+    [G + 1], group [V], row tile [V], n_visits)``, V = ceil(rows / tile)
+    + G - 1, the most there can be; entries past ``n_visits`` repeat the
+    last group and run past its tiles: nothing reads them."""
+    g, tile = sizes.shape[0], row_tile(rows)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first = starts // tile
+    tiles = jnp.where(sizes > 0, (ends - 1) // tile - first + 1, 0)
+    upto = jnp.cumsum(tiles)
+    v = jnp.arange(-(-rows // tile) + g - 1, dtype=jnp.int32)
+    group = jnp.minimum(jnp.sum(v[:, None] >= upto[None, :], axis=1), g - 1)
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    return offsets, group, first[group] + v - (upto - tiles)[group], upto[-1]
+
+
+def _grouped_kernel(offsets_ref, group_ref, tile_ref, x_ref, *refs, tm: int):
+    """Grid: (tiles of N, visits); a visit is one (row tile, group) pair
+    that holds a row. Scalar prefetch: the schedule of
+    :func:`row_tile_visits`. x block [tm, K]: the visit's row tile; one
+    weight block [K, tn] of the visit's group, or two (gate and up); out
+    block [tm, tn]. One product over all of K, summed in float32; the
+    rows of the tile that are the group's own are written
+    (``silu(gate) * up`` in float32, rounded once, where there are two
+    products) and the others left as they stand: the next visits of the
+    same row tile, which follow at once, write theirs."""
+    *w_refs, o_ref = refs
+    v = pl.program_id(1)
+    g = group_ref[v]
+    x = x_ref[...]
+    y = jnp.dot(x, w_refs[0][...], preferred_element_type=jnp.float32)
+    if len(w_refs) == 2:
+        y = jax.nn.silu(y) * jnp.dot(x, w_refs[1][...],
+                                     preferred_element_type=jnp.float32)
+    row = tile_ref[v] * tm + jax.lax.broadcasted_iota(
+        jnp.int32, o_ref.shape, 0)
+    mine = jnp.logical_and(row >= offsets_ref[g], row < offsets_ref[g + 1])
+    o_ref[...] = jnp.where(mine, y.astype(o_ref.dtype), o_ref[...])
+
+
+def _weight_block_cols(k: int, n: int, itemsize: int, n_w: int) -> int:
+    """Columns of a weight block [K, tn]: the largest multiple of 128 that
+    divides ``n`` and keeps the step's ``n_w`` blocks inside
+    ``_WEIGHT_BLOCK_BYTES``; ``n`` itself where no multiple of 128 divides
+    it (a block as wide as the array is always allowed)."""
+    most = min(n, max(128, _WEIGHT_BLOCK_BYTES // (n_w * k * itemsize)))
+    for tn in range(most // 128 * 128, 0, -128):
+        if n % tn == 0:
+            return tn
+    return n
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def grouped_matmul(x: jax.Array, weights: tuple, visits: tuple,
+                   interpret: bool = False) -> jax.Array:
+    """``x[rows of group g] @ w[g]`` for every group, rows sorted by
+    group: the Pallas TPU kernel ``moe_grouped_matmul``. x [M, K];
+    ``weights`` one [G, K, N] array, or two (gate, up: the result is
+    ``silu(x @ gate[g]) * (x @ up[g])``, both products in one pass over
+    the rows); ``visits`` from :func:`row_tile_visits` at this ``M``.
+    Returns [M, N] in x's dtype; rows of no group (past
+    the groups' sum) are not written and hold anything.
+
+    The work follows the groups' rows: the grid is (tiles of N, visits),
+    as many visits as there are (row tile, group) pairs holding a row.
+    A step's weight block is all of K by ``tn`` columns of the visit's
+    group, copied from HBM under the step before it (the blocks are
+    pipelined), and not copied again where the next visit is the same
+    group's next row tile: an expert's weights are read once a call
+    however its rows lie. A row's result is its own: one float32 sum
+    over K, whatever shares the row's tile. Jitted on its own, so the
+    layers and steps that call it at one shape share one trace."""
+    m, k = x.shape
+    n_w, n = len(weights), weights[0].shape[2]
+    tile, n_visits = row_tile(m), visits[3]
+    w_size = weights[0].dtype.itemsize
+    tn = _weight_block_cols(k, n, w_size, n_w)
+
+    def x_map(ni, v, offsets, group, tiles):
+        return (tiles[v], 0)
+
+    def w_map(ni, v, offsets, group, tiles):
+        return (group[v], 0, ni)
+
+    def o_map(ni, v, offsets, group, tiles):
+        return (tiles[v], ni)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(pl.cdiv(n, tn), n_visits),
+        in_specs=[pl.BlockSpec((tile, k), x_map)]
+        + [pl.BlockSpec((None, k, tn), w_map)] * n_w,
+        out_specs=pl.BlockSpec((tile, tn), o_map),
+    )
+    # two buffers a block, the float32 products, and room for the compiler
+    blocks = (tile * k + tile * tn) * x.dtype.itemsize + n_w * k * tn * w_size
+    return pl.pallas_call(
+        functools.partial(_grouped_kernel, tm=tile),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=2 * blocks + 4 * n_w * tile * tn + (16 << 20)),
+        interpret=interpret,
+        name="moe_grouped_matmul",
+    )(*visits[:3], x, *weights)
+
+
+def expert_products(xs: jax.Array, params: dict, sizes: jax.Array,
+                    visits: tuple, interpret: bool | None = None
+                    ) -> jax.Array:
+    """The held experts' SiLU-gated MLPs on rows sorted by expert:
+    ``(silu(xs @ we_g[g]) * (xs @ we_u[g])) @ we_d[g]`` for the rows of
+    group g. ``interpret=None``: the kernel (:func:`grouped_matmul`,
+    gate and up in one call, down in a second) on a TPU backend,
+    ``jax.lax.ragged_dot`` elsewhere (the CPU tests' path, and what the
+    kernel is tested against); True or False forces the kernel,
+    interpreted or compiled."""
+    if interpret is None and jax.devices()[0].platform != "tpu":
+        a = (jax.nn.silu(jax.lax.ragged_dot(xs, params["we_g"], sizes))
+             * jax.lax.ragged_dot(xs, params["we_u"], sizes))
+        return jax.lax.ragged_dot(a, params["we_d"], sizes)
+    a = grouped_matmul(xs, (params["we_g"], params["we_u"]), visits,
+                       interpret=bool(interpret))
+    return grouped_matmul(a, (params["we_d"],), visits,
+                          interpret=bool(interpret))
+
+
 def moe_ffn_dropless(params: dict, x: jax.Array, cfg: MoEConfig,
                      valid: jax.Array | None = None
                      ) -> tuple[jax.Array, jax.Array]:
@@ -238,16 +390,20 @@ def moe_ffn_dropless(params: dict, x: jax.Array, cfg: MoEConfig,
     The held assignments are sorted by expert, their tokens gathered into
     one [N * k, d] buffer (the worst case: every choice held here; rows
     past the held ones belong to no group and are zeroed), three grouped
-    products (``jax.lax.ragged_dot``: work follows the rows the groups
-    really have) and a gather back, each token summing its choices in
-    the order it made them. A row's result is its own: nothing depends on
+    products (:func:`expert_products`: work follows the rows the groups
+    really have, a row tile of at most ``ROW_TILE`` at a time) and a
+    gather back, each token summing its choices in the order it made
+    them. A row's result is its own: nothing depends on
     which other tokens share the call, so a request's tokens do not
     depend on its batch.
 
     x [..., d]; ``valid`` [...] bool: tokens that exist (padding and idle
     rows are routed nowhere and not counted). Returns ``(y, counts)``,
-    counts int32 [G + 2]: tokens a held expert, then tokens routed, then
-    held experts that got a token at all (whose weights this call read).
+    counts int32 [G + 3]: tokens a held expert, then tokens routed, then
+    held experts that got a token at all, then the (row tile, expert)
+    pairs that held a row (:func:`row_tile_visits`: each reads one
+    expert's weights once a product; held rows over visits says how full
+    the row tiles were).
     """
     d = x.shape[-1]
     xf = x.reshape(-1, d)
@@ -264,11 +420,10 @@ def moe_ffn_dropless(params: dict, x: jax.Array, cfg: MoEConfig,
         sizes = jnp.zeros((g + 1,), jnp.int32).at[group].add(1)[:g]
         n_held = jnp.sum(sizes)
         token = order // k               # the sorted rows' tokens
+        visits = row_tile_visits(sizes, n * k)
     with jax.named_scope("moe_experts"):
         xs = xf[token]                                         # [N * k, d]
-        a = (jax.nn.silu(jax.lax.ragged_dot(xs, params["we_g"], sizes))
-             * jax.lax.ragged_dot(xs, params["we_u"], sizes))
-        o = jax.lax.ragged_dot(a, params["we_d"], sizes)
+        o = expert_products(xs, params, sizes, visits)
         o = jnp.where((jnp.arange(n * k) < n_held)[:, None], o, 0)
     with jax.named_scope("moe_combine"):
         back = jnp.argsort(order)        # row of (token, choice) in o
@@ -280,5 +435,5 @@ def moe_ffn_dropless(params: dict, x: jax.Array, cfg: MoEConfig,
     routed = n if valid is None else jnp.sum(valid)
     counts = jnp.concatenate([
         sizes, jnp.asarray(routed, jnp.int32)[None],
-        jnp.sum(sizes > 0, dtype=jnp.int32)[None]])
+        jnp.sum(sizes > 0, dtype=jnp.int32)[None], visits[3][None]])
     return y.astype(x.dtype).reshape(x.shape), counts

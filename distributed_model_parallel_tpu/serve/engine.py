@@ -474,13 +474,17 @@ class Engine:
         """The routed layers' counters since the engine was built, fetched
         from the device (a sync: ask between iterations, not inside one):
         ``{layer: {"tokens_routed", "held_assignments",
-        "tokens_per_held_expert", "experts_touched"}}`` (the last: held
-        experts that got a token, summed over the layer's calls); empty
-        for a dense model."""
-        return {layer: {"tokens_routed": int(row[-2]),
-                        "held_assignments": int(row[:-2].sum()),
-                        "tokens_per_held_expert": row[:-2].tolist(),
-                        "experts_touched": int(row[-1])}
+        "tokens_per_held_expert", "experts_touched",
+        "row_tile_visits"}}`` (``experts_touched``: held experts that got
+        a token, summed over the layer's calls; ``row_tile_visits``: the
+        (row tile, expert) pairs the grouped products visited, so
+        ``held_assignments`` over it is the rows a visit held); empty for
+        a dense model."""
+        return {layer: {"tokens_routed": int(row[-3]),
+                        "held_assignments": int(row[:-3].sum()),
+                        "tokens_per_held_expert": row[:-3].tolist(),
+                        "experts_touched": int(row[-2]),
+                        "row_tile_visits": int(row[-1])}
                 for layer, row in stats_by_layer(self._stats,
                                                  self.cfg).items()}
 

@@ -265,21 +265,22 @@ def _layers(params: dict, pools, stats, x, positions, pages, tables,
 def init_stats(cfg: TransformerConfig):
     """Zeroed counters of the routed layers in ``run_layers``' order of
     bodies (None for a model without dropless routed layers): int32
-    [repeats, G + 2] a routed body: tokens a held expert, tokens routed,
-    experts touched a call (ops/moe.moe_ffn_dropless). :func:`stats_by_layer` puts them
-    in layer order."""
+    [repeats, G + 3] a routed body: tokens a held expert, tokens routed,
+    experts touched and row tiles visited a call
+    (ops/moe.moe_ffn_dropless). :func:`stats_by_layer` puts them in layer
+    order."""
     if not (cfg.moe_dropless and cfg.moe_experts):
         return None
     n_lead, period, n_periods = cfg.layer_plan
     g = cfg.moe.held_range[1]
     return tuple(
-        jnp.zeros((1 if i < n_lead else n_periods, g + 2), jnp.int32)
+        jnp.zeros((1 if i < n_lead else n_periods, g + 3), jnp.int32)
         if cfg.kinds[i].ffn == "moe" else None
         for i in range(n_lead + period))
 
 
 def stats_by_layer(stats, cfg: TransformerConfig) -> dict:
-    """{layer index: host int array [G + 2]} of the routed layers."""
+    """{layer index: host int array [G + 3]} of the routed layers."""
     import numpy as np
 
     n_lead, period, _ = cfg.layer_plan

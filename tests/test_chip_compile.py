@@ -434,15 +434,22 @@ def test_a_slab_cut_out_under_a_scan_is_found(one_chip):
 
 @pytest.mark.parametrize("rows", [4096, 512],
                          ids=["prefill-chunk-512x8", "decode-round-64x8"])
-def test_grouped_expert_products_compile(one_chip, rows):
+def test_grouped_expert_products_compile(one_chip, monkeypatch, rows):
     """The dropless routed layer (ops/moe.moe_ffn_dropless) at
     K-EXAONE's expert shapes: 16 held experts of 6144 x 2048 out of a
     router 128 wide, 8 chosen a token, every assignment of a 512-token
-    chunk (or of a 64-row decode round) in one buffer. XLA:TPU has to
-    take ``jax.lax.ragged_dot`` as a grouped kernel (a custom call whose
-    work follows the groups' rows), not as one dense product a group."""
+    chunk (or of a 64-row decode round) in one buffer. Mosaic has to
+    take the grouped products' kernel (``moe_grouped_matmul``: gate and
+    up in one call, down in a second, row tiles of 128 visited where a
+    group holds a row) at these widths, and the layer holds no
+    ``jax.lax.ragged_dot`` beside it: XLA:TPU's grouped kernel works in
+    row tiles of 512 whatever a group holds."""
     from distributed_model_parallel_tpu.ops import moe
 
+    # on a TPU backend the layer takes the kernel by itself; the described
+    # chip is not the backend here
+    monkeypatch.setattr(moe, "expert_products", functools.partial(
+        moe.expert_products, interpret=False))
     cfg = moe.MoEConfig(num_experts=128, d_model=6144, d_ff=2048, top_k=8,
                         scoring="sigmoid", routed_scale=2.5, held=(0, 16))
 
@@ -455,10 +462,16 @@ def test_grouped_expert_products_compile(one_chip, rows):
     text = _compiled_text(
         lambda p, x, v: moe.moe_ffn_dropless(p, x, cfg, valid=v),
         params, sds((rows // 8, 6144)), sds((rows // 8,), jnp.bool_))
-    # the name the benchmark's reader finds the grouped products by
-    assert len(re.findall(r"%ragged-dot[\w.-]* = [^\n]*custom-call\(",
-                          text)) >= 3
+    # the kernel's own name in a trace (no reader of the benchmark finds
+    # it by name: ``moe_dev_share.mixed`` takes the ops under the scope
+    # ``moe_experts``, whatever implements them)
+    assert len(re.findall(
+        r"%moe_grouped_matmul[\w.-]* = [^\n]*custom-call\(", text)) == 2
     assert "tpu_custom_call" in text
+    assert "%ragged-dot" not in text
+    # no expert's weights are copied on their way into the kernel
+    assert not re.findall(r"= bf16\[16,(?:6144,2048|2048,6144)\]\S* "
+                          r"(?:copy|fusion)\(", text)
 
 
 def _flash_args(one_chip, t):

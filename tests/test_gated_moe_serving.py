@@ -142,8 +142,10 @@ def test_dropless_layer_matches_reference_and_counts():
     per_expert = [int(((np.asarray(experts) == 4 + e)
                        & np.asarray(valid)[:, None]).sum())
                   for e in range(4)]
-    assert counts.tolist() == per_expert + [
-        int(valid.sum()), sum(1 for c in per_expert if c)]
+    # 96 sorted rows are one row tile: a visit a touched expert
+    touched = sum(1 for c in per_expert if c)
+    assert counts.tolist() == per_expert + [int(valid.sum()), touched,
+                                            touched]
 
 
 def test_every_chosen_held_expert_computes_even_when_all_choose_it():
@@ -155,7 +157,7 @@ def test_every_chosen_held_expert_computes_even_when_all_choose_it():
     with jax.default_matmul_precision("highest"):
         y, counts = moe.moe_ffn_dropless(bp, x, cfg.moe)
         want = ref.routed(bp, x, top_k=4, scale=2.5, held=(4, 4))
-    assert counts.tolist() == [x.shape[0]] * 4 + [x.shape[0], 4]
+    assert counts.tolist() == [x.shape[0]] * 4 + [x.shape[0], 4, 4]
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-5,
                                rtol=2e-5)
 
@@ -185,6 +187,138 @@ def test_shares_sum_to_the_uncut_layer():
                                np.asarray(want), atol=3e-5, rtol=3e-5)
     np.testing.assert_allclose(np.asarray(uncut), np.asarray(total),
                                atol=3e-5, rtol=3e-5)
+
+
+# -- the grouped products' kernel (interpreted here; compiled for the chip in
+# tests/test_chip_compile.py) against jax.lax.ragged_dot -----------------------
+
+def visits_by_hand(sizes, tile):
+    """(row tile, group) pairs that hold a row, counted one group at a
+    time from the groups' first and last rows."""
+    pairs, start = [], 0
+    for g, size in enumerate(sizes):
+        if size:
+            pairs += [(t, g) for t in range(start // tile,
+                                            (start + size - 1) // tile + 1)]
+        start += size
+    return pairs
+
+
+@pytest.mark.parametrize("sizes,rows,dtype", [
+    ([0, 1, 3, 32, 200, 0, 20], 512, jnp.float32),
+    ([100, 56], 256, jnp.float32),
+    ([128, 100, 28], 256, jnp.float32),
+    ([0, 0, 0], 256, jnp.float32),
+    ([17, 0, 23], 384, jnp.float32),
+    ([150, 30, 20], 200, jnp.float32),
+    ([5, 7], 40, jnp.float32),
+    ([0, 1, 3, 32, 200, 0, 20], 512, jnp.bfloat16),
+    ([3, 0, 2, 4, 0, 3], 64, jnp.bfloat16),
+], ids=["groups-of-0-1-3-32-200", "a-group-straddles-two-row-tiles",
+        "every-row-held", "none-held", "rows-past-the-held-left-out",
+        "last-row-tile-ragged", "fewer-rows-than-a-tile",
+        "bf16-groups-of-0-1-3-32-200", "bf16-a-decode-round"])
+def test_grouped_matmul_kernel_matches_ragged_dot(sizes, rows, dtype):
+    """One product, and gate and up in one call, over rows sorted by
+    group. float32 operands under true-float32 products: to 1e-5.
+    bfloat16 operands: the kernel accumulates in float32 and rounds
+    once, so it lies within one bfloat16 step (2 ** -7 relative) of the
+    float32-accumulated oracle. Rows past the groups' sum are poisoned
+    (NaN): they reach no row that is held, and the row tiles that hold
+    only such rows are in no visit."""
+    k, n, g = 256, 384, len(sizes)
+    keys = jax.random.split(jax.random.key(rows + g), 3)
+    held = sum(sizes)
+    x = jax.random.normal(keys[0], (rows, k), jnp.float32)
+    x = jnp.where((jnp.arange(rows) < held)[:, None], x, jnp.nan)
+    x = x.astype(dtype)
+    w = [(jax.random.normal(kk, (g, k, n)) * k ** -0.5).astype(dtype)
+         for kk in keys[1:]]
+    sz = jnp.asarray(sizes, jnp.int32)
+    tile = moe.row_tile(rows)
+    assert tile == min(128, rows)
+    visits = moe.row_tile_visits(sz, rows)
+    by_hand = visits_by_hand(sizes, tile)
+    n_visits = int(visits[3])
+    assert n_visits == len(by_hand)
+    assert list(zip(visits[2][:n_visits].tolist(),
+                    visits[1][:n_visits].tolist())) == by_hand
+    assert all(t * tile < held for t, _ in by_hand)
+    with jax.default_matmul_precision("highest"):
+        one = moe.grouped_matmul(x, (w[0],), visits, interpret=True)
+        two = moe.grouped_matmul(x, tuple(w), visits, interpret=True)
+        gate, up = (jax.lax.ragged_dot(jnp.nan_to_num(x), wi, sz,
+                                       preferred_element_type=jnp.float32)
+                    for wi in w)
+    assert one.dtype == two.dtype == dtype and one.shape == (rows, n)
+    tol = (dict(atol=1e-5, rtol=1e-5) if dtype == jnp.float32
+           else dict(atol=2 ** -9, rtol=2 ** -7))
+    np.testing.assert_allclose(np.asarray(one, np.float32)[:held],
+                               np.asarray(gate)[:held], **tol)
+    np.testing.assert_allclose(
+        np.asarray(two, np.float32)[:held],
+        np.asarray(jax.nn.silu(gate) * up)[:held], **tol)
+
+
+@pytest.fixture
+def through_kernel(monkeypatch):
+    """The routed layer's products through the Pallas kernel, interpreted
+    (off the chip ``expert_products`` takes ``jax.lax.ragged_dot``). The
+    serving steps are cached by configuration: they are built anew under
+    the kernel, and again after it. A test that asks for this and traces
+    no kernel call fails."""
+    steps = (smodel.make_prefill_step, smodel.make_decode_step,
+             smodel.make_verify_step)
+    by_backend, traced = moe.expert_products, []
+
+    def forced(*args):
+        traced.append(args[-1])
+        return by_backend(*args, interpret=True)
+
+    forced.by_backend = by_backend
+    monkeypatch.setattr(moe, "expert_products", forced)
+    for make in steps:
+        make.cache_clear()
+    yield
+    for make in steps:
+        make.cache_clear()
+    assert traced
+
+
+@pytest.mark.parametrize("n,dtype", [(24, jnp.float32), (400, jnp.float32),
+                                     (400, jnp.bfloat16)],
+                         ids=["one-row-tile", "held-rows-in-three", "bf16"])
+def test_layer_through_the_kernel_is_the_layer_through_ragged_dot(
+        through_kernel, n, dtype):
+    """``moe_ffn_dropless`` forced through the kernel against the same
+    layer through ``ragged_dot``: 400 tokens x 4 choices are 1,600
+    sorted rows, twelve and a half row tiles of 128, of which the held
+    rows (about 340) fill the first three. The counters agree to the
+    last entry, the (row tile, expert) visits, which is a count by hand
+    from the sizes."""
+    cfg = config(dtype)
+    bp, x = _moe_inputs(cfg, n=n)
+    x = x.astype(dtype)
+    valid = jnp.arange(n) % 7 != 0
+    with jax.default_matmul_precision("highest"):
+        got, counts = moe.moe_ffn_dropless(bp, x, cfg.moe, valid=valid)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(moe, "expert_products",
+                       moe.expert_products.by_backend)
+            want, counts_xla = moe.moe_ffn_dropless(bp, x, cfg.moe,
+                                                    valid=valid)
+    assert counts.tolist() == counts_xla.tolist()
+    sizes = counts.tolist()[:4]
+    assert int(counts[-1]) == len(visits_by_hand(sizes,
+                                                 moe.row_tile(4 * n)))
+    if n == 400:
+        assert int(counts[-1]) > int(counts[-2])     # a group in two tiles
+    # bfloat16: the kernel rounds gate x up once where ragged_dot rounds
+    # gate, up and their product
+    tol = 2e-5 if dtype == jnp.float32 else 0.05
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=tol,
+                               rtol=tol)
 
 
 # -- prefill then decode through the paged cache, against one full forward ----
@@ -250,7 +384,7 @@ def test_paged_prefill_then_decode_matches_reference_f32(
                                rtol=2e-4)
     rows = smodel.stats_by_layer(stats, cfg)
     assert sorted(rows) == list(range(1, 9))       # the routed layers
-    assert all(int(r[-2]) == n_total for r in rows.values())
+    assert all(int(r[-3]) == n_total for r in rows.values())
 
 
 @pytest.mark.parametrize("seed", [0, 3])
@@ -292,12 +426,17 @@ def _prompts(cfg, lengths, seed=11):
     return [rng.integers(0, cfg.vocab_size, n).tolist() for n in lengths]
 
 
+@pytest.mark.parametrize("products", ["ragged_dot", "kernel"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-def test_a_requests_tokens_do_not_depend_on_its_batch(dtype):
+def test_a_requests_tokens_do_not_depend_on_its_batch(dtype, products,
+                                                      request):
     """What the engine's old refusal of routed models guarded: alone, or
     sixth in a queue over four slots, a request decodes the same tokens,
-    bit for bit."""
+    bit for bit; through ``ragged_dot`` and through the grouped products'
+    kernel, whose row tiles hold other rows beside a request's own."""
+    if products == "kernel":
+        request.getfixturevalue("through_kernel")
     cfg = config(dtype)
     params = random_params(cfg)
     prompts = _prompts(cfg, [27, 9, 40, 16, 33, 21])
@@ -331,6 +470,26 @@ def test_engine_tokens_are_the_references_choices():
         assert c["held_assignments"] == sum(c["tokens_per_held_expert"])
         assert 0 < c["held_assignments"] < 4 * tokens
         assert 0 < c["experts_touched"] <= 4 * eng._iterations * 2
+        # a chunk is 64 sorted rows and a round 16: one row tile a call,
+        # so a visit a touched expert
+        assert c["row_tile_visits"] == c["experts_touched"]
+
+
+def test_row_tile_visits_reach_the_engines_counters(monkeypatch):
+    """Row tiles of 8 rows (the chip's are 128): one request of one
+    14-token chunk and no decode round, so the layer ran once, on 64
+    sorted rows, and its visits are a count by hand from its sizes."""
+    monkeypatch.setattr(moe, "ROW_TILE", 8)
+    cfg = dataclasses.replace(config(), rope_theta=2e4)    # steps of its own
+    eng, _ = run_requests(random_params(cfg), cfg, serve_config(),
+                          _prompts(cfg, [14]), [1])
+    for c in eng.moe_counters().values():
+        assert c["tokens_routed"] == 14
+        by_hand = visits_by_hand(c["tokens_per_held_expert"], 8)
+        assert c["row_tile_visits"] == len(by_hand)
+        assert c["experts_touched"] <= len(by_hand) <= 8 + 4 - 1
+    assert any(c["row_tile_visits"] > c["experts_touched"]
+               for c in eng.moe_counters().values())
 
 
 def test_sliding_cache_stays_within_its_bound_and_returns_its_pages():
