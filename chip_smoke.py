@@ -31,7 +31,13 @@ benchmark's own models are ``chipbench/configs/``):
    with sliding layers in rings, and gated-delta linear-attention layers
    3:1 with full attention (a recurrent state a slot): the rule's decode
    kernel and chunked form against the recurrence at the published
-   widths, then kernels against the XLA forms, token for token.
+   widths, then kernels against the XLA forms, token for token;
+   **serve-looped** — a stack run three times over shared weights
+   (sandwich norms, the norm closing each pass, the exit gate; 16 heads
+   of 128 with no grouping): the decode logits of a captured batch
+   through the pools, twelve cache layers deep, kernel against XLA and
+   both against the full forward with no cache; tokens, the loop's
+   counters.
 
 ``--multichip`` is a separate run for a four-chip host: only the
 cross-chip paths (GSPMD dp 4, shard_map DDP, the four-stage pipeline, LM
@@ -78,6 +84,9 @@ FULL = dict(
     # full layers' 30 heads with no grouping, at a small hidden size
     hybrid=dict(vocab=8192, d_model=512, heads=30, head_dim=128, d_ff=1024,
                 lin_heads=30, lin_dk=96, lin_dv=192, rule_tokens=512),
+    # the looped stack's heads as published (16 of 128, no grouping)
+    looped=dict(vocab=8192, d_model=512, heads=16, head_dim=128, d_ff=1024,
+                layers=4, passes=3),
     multi=dict(lm_seq=2048, lm_batch=16, loss_chunk=512),
 )
 TINY = dict(
@@ -91,6 +100,8 @@ TINY = dict(
                 window=16),
     hybrid=dict(vocab=256, d_model=64, heads=4, head_dim=32, d_ff=128,
                 lin_heads=4, lin_dk=8, lin_dv=64, rule_tokens=80),
+    looped=dict(vocab=256, d_model=64, heads=2, head_dim=32, d_ff=128,
+                layers=3, passes=3),
     multi=dict(lm_seq=128, lm_batch=16, loss_chunk=0),
 )
 
@@ -124,6 +135,11 @@ DP_LOSS_RTOL, DP_UPDATE_RTOL = 1e-4, 2e-2
 # (a solve and six products a sub-chunk for 64 rank-one updates): values
 # of order 1, so a few hundred ulps.
 RULE_ATOL = 2e-4
+# The looped stack's float32 decode logits under true float32 products,
+# through the paged cache against the full forward with no cache: the
+# same sums in another order through passes x layers sandwich-normed
+# sublayer pairs (the CPU tests hold 1e-4 at nine layer passes).
+LOOP_LOGIT_ATOL = 5e-4
 
 
 def log(msg: str) -> None:
@@ -427,7 +443,8 @@ def run_engine(params, cfg, serve_cfg, requests, snapshot_at=None):
     """Serve ``requests`` to completion. Returns (tokens per request,
     engine, snapshot): ``snapshot`` is the decode batch the engine was
     about to feed at iteration ``snapshot_at`` (pools copied: the live
-    ones are donated to the next step)."""
+    ones are donated to the next step; ``seqs``: each decoding slot's
+    tokens so far, the one it is about to feed last)."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -447,6 +464,7 @@ def run_engine(params, cfg, serve_cfg, requests, snapshot_at=None):
         tokens, positions = np.zeros(b, np.int32), np.zeros(b, np.int32)
         active = np.zeros(b, bool)
         tables = np.zeros((b, eng.cache.pages_per_seq), np.int32)
+        seqs = {r.slot: list(r.prompt) + list(r.generated) for r in decoding}
         for r in decoding:
             tokens[r.slot] = r.generated[-1]
             positions[r.slot] = r.prompt_len + len(r.generated) - 1
@@ -454,7 +472,7 @@ def run_engine(params, cfg, serve_cfg, requests, snapshot_at=None):
             tables[r.slot] = eng.cache.table_array(r.rid)
         snap.update(ck=jnp.copy(eng.cache.ck), cv=jnp.copy(eng.cache.cv),
                     tokens=tokens, positions=positions, active=active,
-                    tables=tables)
+                    tables=tables, seqs=seqs)
 
     eng = Engine(params, cfg, serve_cfg, step_hook=hook)
     reqs = [eng.submit(p, n, rid=f"r{i}")
@@ -771,6 +789,89 @@ def phase_serve_hybrid(size: dict, block: dict, on_tpu: bool,
           "f32 hybrid engine: greedy tokens identical, kernels vs xla")
 
 
+def phase_serve_looped(size: dict, block: dict, on_tpu: bool,
+                       seed: int) -> None:
+    """A looped stack through the engine: ``layers`` layers run
+    ``passes`` times over the same leaves, a cache layer a (pass, layer);
+    sandwich norms, the final norm closing every pass, the exit gate."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_model_parallel_tpu.models import transformer as tfm
+    from distributed_model_parallel_tpu.serve import ServeConfig
+    from distributed_model_parallel_tpu.serve.model import decode_logits
+
+    kernel = "auto" if on_tpu else "pallas"
+    t, n_layers = block["passes"], block["layers"]
+    cfg = tfm.TransformerConfig(
+        vocab_size=block["vocab"], d_model=block["d_model"],
+        n_heads=block["heads"], n_kv_heads=block["heads"],
+        d_head=block["head_dim"], n_layers=n_layers, d_ff=block["d_ff"],
+        max_seq_len=size["max_seq"], dtype=jnp.float32,
+        pos_embedding="rope", rope_theta=1e6, norm="rmsnorm", norm_eps=1e-6,
+        ffn="swiglu", norm_placement="sandwich", n_passes=t,
+        loop_final_norm=True, exit_gate=True)
+    params = tfm.init_params(jax.random.key(seed), cfg)
+    params["embed"] = params["embed"] * 50.0       # unit embeddings
+    pages_per_seq = -(-size["max_seq"] // size["page"])
+    geometry = dict(n_slots=size["n_slots"], page_size=size["page"],
+                    n_pages=(size["n_slots"] + 1) * pages_per_seq,
+                    max_seq_len=size["max_seq"], prefill_chunk=size["chunk"])
+    requests = make_requests(size, cfg.vocab_size, seed)
+    with jax.default_matmul_precision("highest"):
+        runs = {}
+        for name, impl in (("kernel", kernel), ("xla", "xla")):
+            runs[name], eng, snap = run_engine(
+                params, cfg, ServeConfig(attn_impl=impl, **geometry),
+                requests, snapshot_at=size["n_slots"])
+            check(eng.cache.ck.shape[0] == t * n_layers
+                  and jax.tree.leaves(params["blocks"])[0].shape[0]
+                  == n_layers,
+                  f"{t * n_layers} cache layers for {n_layers} layers of "
+                  f"weights")
+            got = eng.loop_counters()
+            fed = sum(len(p) + n - 1 for p, n in requests)
+            check(got["tokens"] == fed
+                  and got["token_passes"] == t * fed
+                  and abs(sum(got["exit_mass"]) - fed) <= 1e-3 * fed
+                  and min(got["exit_mass"]) > 0,
+                  f"loop counters: {fed} tokens, {t} passes each, the "
+                  f"gate's mass {[round(m) for m in got['exit_mass']]}")
+            check(eng.cache.pool.free_pages == eng.cache.pool.n_pages,
+                  "page pool back to empty")
+            del eng
+        check(runs["kernel"] == runs["xla"],
+              "f32 looped engine: greedy tokens identical, kernel vs xla")
+        # the captured decode batch (the xla run's: same tokens, same
+        # pools): logits through the cache under each impl, and of the
+        # full forward over each row's whole sequence, no cache
+        logits = {}
+        for impl in (kernel, "xla"):
+            out = jax.jit(lambda p, ck, cv, tok, pos, tab, act, impl=impl:
+                          decode_logits(p, (ck, cv, None, None, None, None),
+                                        None, tok, pos, (tab, None), act,
+                                        cfg, page_size=size["page"],
+                                        impl=impl)[2])(
+                params, snap["ck"], snap["cv"], snap["tokens"],
+                snap["positions"], snap["tables"], snap["active"])
+            logits[impl] = np.asarray(out, np.float32)[snap["active"]]
+        worst = float(np.abs(logits[kernel] - logits["xla"]).max())
+        check(worst <= LOOP_LOGIT_ATOL,
+              f"looped decode logits through {t * n_layers} cache layers, "
+              f"kernel vs xla: within {worst:.2e} (limit "
+              f"{LOOP_LOGIT_ATOL:.0e})")
+        rows = [i for i in range(size["n_slots"]) if snap["active"][i]]
+        full = [np.asarray(tfm.apply(
+            params, jnp.asarray(snap["seqs"][i], jnp.int32)[None],
+            cfg)[0, -1], np.float32) for i in rows]
+        worst = float(np.abs(logits["xla"] - np.stack(full)).max())
+        check(worst <= LOOP_LOGIT_ATOL,
+              f"looped decode logits of {len(rows)} rows, through the "
+              f"cache vs the full forward with none: within {worst:.2e} "
+              f"(limit {LOOP_LOGIT_ATOL:.0e})")
+
+
 def run_single(sizes: dict, workdir: str, meter: CompileMeter, dev,
                seed: int) -> None:
     on_tpu = dev.platform == "tpu"
@@ -784,6 +885,8 @@ def run_single(sizes: dict, workdir: str, meter: CompileMeter, dev,
         phase_serve_routed(sizes["serve"], sizes["routed"], on_tpu, seed)
     with phase("serve-hybrid", meter):
         phase_serve_hybrid(sizes["serve"], sizes["hybrid"], on_tpu, seed)
+    with phase("serve-looped", meter):
+        phase_serve_looped(sizes["serve"], sizes["looped"], on_tpu, seed)
 
 
 # ---------------------------------------------------------------------------

@@ -24,14 +24,17 @@ QK-norm (a head or the whole vector), where the norms sit
 (what mixes the tokens: softmax attention, with a window or none, rotated
 or not, or the gated delta rule's recurrent state, ops/gated_delta.py;
 dense or routed FFN: ``layer_kinds``, arranged by ``layer_plan`` as
-leading layers and a scanned period). ``block_apply`` and the serving
-engine's steps (serve/model.py) take every kind; ``generate`` and its
-dense cache keep to the default block.
+leading layers and a scanned period), and how many passes run over the
+stack (``n_passes``, ``run_passes``: the same leaves every pass, the
+final norm closing each, the exit gate read off each). ``block_apply``
+and the serving engine's steps (serve/model.py) take every kind;
+``generate`` and its dense cache keep to the default block run once.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -151,10 +154,25 @@ class TransformerConfig:
     # ... or over the whole vector of q and of k, all heads, before the
     # split into heads (scales [H, Dh] and [Hkv, Dh]).
     qk_norm_whole: bool = False
-    # Where a sublayer's norm sits: "pre" (on its input, x + f(norm(x)))
-    # or "post" (on its output, x + norm(f(x)): nothing normalises what
-    # the sublayer reads). The leaves are ln1/ln2 either way.
+    # Where a sublayer's norm sits: "pre" (on its input, x + f(norm(x))),
+    # "post" (on its output, x + norm(f(x)): nothing normalises what
+    # the sublayer reads; the leaves are ln1/ln2 either way) or
+    # "sandwich" (both, x + norm_out(f(norm(x))): ln1/ln2 on the way in,
+    # ln1_out/ln2_out on the way out).
     norm_placement: str = "pre"
+    # The looped stack: the layers run ``n_passes`` times, one pass after
+    # another over the SAME leaves (``run_passes``); a pass's K/V are its
+    # own (serve/paged_kv.CacheLayout). ``loop_final_norm``: the final
+    # norm closes every pass, so the next pass and the head read the
+    # normed stream (``unembed`` then norms nothing). ``exit_gate``:
+    # leaves ``gate_w`` [d], ``gate_b`` []; sigmoid(x . gate_w + gate_b)
+    # on each pass's output is the probability of leaving the loop there.
+    # ``early_exit_threshold``: only 1.0 (every row runs every pass; the
+    # gate is counted, not acted on).
+    n_passes: int = 1
+    loop_final_norm: bool = False
+    exit_gate: bool = False
+    early_exit_threshold: float = 1.0
     # The gated-delta layers (LayerKind.mixer == "gated_delta"): key and
     # value heads (values a multiple of keys: a key head serves a group),
     # their sizes, the short convolution's length, and whether beta spans
@@ -189,9 +207,19 @@ class TransformerConfig:
             raise ValueError(f"unknown norm {self.norm!r}")
         if self.ffn not in ("gelu", "swiglu"):
             raise ValueError(f"unknown ffn {self.ffn!r}")
-        if self.norm_placement not in ("pre", "post"):
+        if self.norm_placement not in ("pre", "post", "sandwich"):
             raise ValueError(
                 f"unknown norm_placement {self.norm_placement!r}")
+        if self.n_passes < 1:
+            raise ValueError(f"n_passes must be >= 1, got {self.n_passes}")
+        if self.early_exit_threshold != 1.0:
+            raise NotImplementedError(
+                f"early_exit_threshold={self.early_exit_threshold}: only 1.0 "
+                f"is run (every row takes every pass). Rows leaving the loop "
+                f"at different passes (a decode round whose rows take "
+                f"unequal work) and the cache entries of the passes they "
+                f"skip are not written (serve/model._layers, "
+                f"serve/scheduler.py)")
         if any(k.mixer == "gated_delta" for k in self.layer_kinds or ()):
             if min(self.lin_key_heads, self.lin_key_dim,
                    self.lin_value_dim) < 1 or (
@@ -254,6 +282,12 @@ class TransformerConfig:
     @property
     def homogeneous(self) -> bool:
         return self.layer_plan[:2] == (0, 1)
+
+    @property
+    def looped(self) -> bool:
+        """Whether anything of the looped stack is on: more than one
+        pass, the norm that closes a pass, or the exit gate."""
+        return self.n_passes > 1 or self.loop_final_norm or self.exit_gate
 
     @property
     def kv_heads(self) -> int:
@@ -350,6 +384,11 @@ def _init_blocks(k, cfg: TransformerConfig, kind: LayerKind, L: int) -> dict:
     if cfg.norm == "layernorm":
         blocks["ln1_bias"] = jnp.zeros((L, d), dt)
         blocks["ln2_bias"] = jnp.zeros((L, d), dt)
+    if cfg.norm_placement == "sandwich":
+        for name in ("ln1_out", "ln2_out"):
+            blocks[name + "_scale"] = jnp.ones((L, d), dt)
+            if cfg.norm == "layernorm":
+                blocks[name + "_bias"] = jnp.zeros((L, d), dt)
     if kind.mixer == "gated_delta":
         blocks.update(_init_gated_delta(k[2], cfg, stack, L))
     else:
@@ -397,7 +436,8 @@ def _init_blocks(k, cfg: TransformerConfig, kind: LayerKind, L: int) -> dict:
 
 def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
     """Parameter pytree. A stack of equal layers: ``blocks`` is one dict,
-    stacked on a leading [n_layers] axis. Where layers differ
+    stacked on a leading [n_layers] axis (``n_layers`` of them however
+    many passes run over them). Where layers differ
     (``cfg.layer_plan``): ``lead`` is a tuple of single layers' dicts and
     ``blocks`` a tuple with one dict a position of the period, each
     stacked on [n_periods]."""
@@ -414,6 +454,10 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
     }
     if cfg.norm == "layernorm":
         out["ln_f_bias"] = jnp.zeros((d,), dt)
+    if cfg.exit_gate:
+        out["gate_w"] = jax.random.normal(
+            jax.random.fold_in(k[6], 1), (d,), dt) * (d ** -0.5)
+        out["gate_b"] = jnp.zeros((), dt)
     if cfg.homogeneous:
         out["blocks"] = _init_blocks(k, cfg, kinds[0], cfg.n_layers)
     else:
@@ -569,9 +613,15 @@ def sublayer_in(bp: dict, name: str, x, cfg: TransformerConfig):
 
 
 def sublayer_out(bp: dict, name: str, y, cfg: TransformerConfig):
-    """What a sublayer adds to the stream: its result, or under
-    ``norm_placement="post"`` the norm of it."""
-    return _norm(bp, name, y, cfg) if cfg.norm_placement == "post" else y
+    """What a sublayer adds to the stream: its result, under
+    ``norm_placement="post"`` the norm of it (the leaves ``<name>``),
+    under ``"sandwich"`` the norm of it too (``<name>_out``: ``<name>``
+    normed what the sublayer read)."""
+    if cfg.norm_placement == "post":
+        return _norm(bp, name, y, cfg)
+    if cfg.norm_placement == "sandwich":
+        return _norm(bp, name + "_out", y, cfg)
+    return y
 
 
 def gated_delta_inputs(bp: dict, h: jax.Array, cfg: TransformerConfig,
@@ -778,6 +828,64 @@ def run_layers(params: dict, carry, fn, cfg: TransformerConfig):
     return carry, tuple(outs) + tuple(got)
 
 
+def exit_gate(params: dict, x: jax.Array) -> jax.Array:
+    """The looped stack's exit gate on a pass's output x [..., d]:
+    ``sigmoid(x . gate_w + gate_b)`` [...], float32: the probability of
+    leaving the loop after this pass, given that the row is still in."""
+    with jax.named_scope("exit_gate"):
+        z = jnp.einsum("...d,d->...", x, params["gate_w"],
+                       preferred_element_type=jnp.float32)
+        return jax.nn.sigmoid(z + params["gate_b"].astype(jnp.float32))
+
+
+def exit_distribution(gates: jax.Array) -> jax.Array:
+    """gates [T, ...] (``exit_gate`` of each pass) -> p_exit [T, ...]:
+    ``p_exit(t) = g_t * prod_{j<t} (1 - g_j)``, the last pass taking what
+    is left, ``prod_{j<T-1} (1 - g_j)``: sums to 1 over T."""
+    still_in = jnp.cumprod(1.0 - gates, axis=0)
+    before = jnp.concatenate([jnp.ones_like(gates[:1]), still_in[:-1]])
+    return jnp.concatenate([(gates * before)[:-1], before[-1:]])
+
+
+def run_passes(params: dict, x: jax.Array, rest, fn,
+               cfg: TransformerConfig):
+    """All passes over all layers: THE definition of the looped stack,
+    for the full forward (``layers_forward``) and the serving steps
+    (serve/model._layers). ``fn(t, bp, kind, body, rep, (x, rest)) ->
+    ((x, rest), out)`` is one layer as ``run_layers`` takes it, told
+    which pass ``t`` it runs in; ``rest`` is whatever rides beside the
+    stream (the serving steps' pools; None). Every pass walks the same
+    leaves of ``params``; ``cfg.loop_final_norm`` closes each pass with
+    the final norm, and ``cfg.exit_gate`` reads the gate off each pass's
+    output. Returns ``(x, rest, outs, gates)``.
+
+    A configuration with nothing of the loop on (``not cfg.looped``) is
+    ``run_layers`` and nothing else: ``outs`` as it gives them, ``gates``
+    None. Otherwise the passes are ONE ``lax.scan`` around the layer
+    walk, so the stack's body is traced and compiled once whatever
+    ``n_passes`` (``t`` is traced, the weights are closed over, ``rest``
+    is in the carry: pools written in place stay in place); ``outs`` and
+    ``gates`` ([T, ...] float32, None without the gate) are stacked on a
+    leading [n_passes] axis."""
+    if not cfg.looped:
+        (x, rest), outs = run_layers(params, (x, rest),
+                                     functools.partial(fn, 0), cfg)
+        return x, rest, outs, None
+
+    def one_pass(carry, t):
+        with jax.named_scope("loop_stack"):
+            (x, rest), outs = run_layers(params, carry,
+                                         functools.partial(fn, t), cfg)
+            if cfg.loop_final_norm:
+                x = _norm(params, "ln_f", x, cfg)
+        gate = exit_gate(params, x) if cfg.exit_gate else None
+        return (x, rest), (outs, gate)
+
+    (x, rest), (outs, gates) = jax.lax.scan(
+        one_pass, (x, rest), jnp.arange(cfg.n_passes))
+    return x, rest, outs, gates
+
+
 def blocks_scan(blocks: dict, x: jax.Array, cfg: TransformerConfig
                 ) -> tuple[jax.Array, jax.Array]:
     """Run stacked blocks of equal layers (single device, or a pipeline
@@ -788,9 +896,9 @@ def blocks_scan(blocks: dict, x: jax.Array, cfg: TransformerConfig
 
 def layers_forward(params: dict, x: jax.Array, cfg: TransformerConfig
                    ) -> tuple[jax.Array, jax.Array]:
-    """Every layer of ``params`` on x (``run_layers``: ``lead`` and
-    ``blocks`` as ``init_params`` arranges them). Returns ``(x, aux)``
-    like :func:`blocks_scan`."""
+    """Every layer of ``params`` on x, every pass of them
+    (``run_passes``: ``lead`` and ``blocks`` as ``init_params`` arranges
+    them). Returns ``(x, aux)`` like :func:`blocks_scan`."""
     apply = block_apply
     if cfg.remat:
         if cfg.remat_policy == "dots":
@@ -803,14 +911,14 @@ def layers_forward(params: dict, x: jax.Array, cfg: TransformerConfig
         apply = jax.checkpoint(block_apply, static_argnums=(2, 3),
                                policy=policy)
 
-    def layer(bp, kind, body, rep, carry):
-        carry, aux = apply(bp, carry, cfg, kind)
+    def layer(t, bp, kind, body, rep, carry):
+        x, aux = apply(bp, carry[0], cfg, kind)
         if kind.ffn == "moe" and cfg.moe_dropless:
             # the dropless layer's counters are not a loss
             aux = jnp.zeros((AUX_STATS,), jnp.float32)
-        return carry, aux
+        return (x, None), aux
 
-    out, auxes = run_layers(params, x, layer, cfg)
+    out, _, auxes, _ = run_passes(params, x, None, layer, cfg)
     auxes = jnp.concatenate([a.reshape(-1, AUX_STATS) for a in auxes])
     return out, jnp.mean(auxes, axis=0)       # [AUX_STATS], mean over layers
 
@@ -835,10 +943,12 @@ def embed(params: dict, tokens: jax.Array, cfg: TransformerConfig,
 
 def unembed(params: dict, x: jax.Array,
             cfg: TransformerConfig | None = None) -> jax.Array:
-    """Final norm and head (``cfg`` None: the default LayerNorm)."""
+    """Final norm and head (``cfg`` None: the default LayerNorm). Under
+    ``cfg.loop_final_norm`` the last pass already closed with the norm
+    (``run_passes``): the head reads x as it is."""
     if cfg is None:
         x = layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
-    else:
+    elif not cfg.loop_final_norm:
         x = _norm(params, "ln_f", x, cfg)
     return x @ params["head"]
 
@@ -948,6 +1058,13 @@ def _require_default_block(cfg: TransformerConfig, what: str) -> None:
     """``generate`` and its dense cache know the default block only
     (LayerNorm, the GELU or capacity-routed FFN, every layer alike); the
     other kinds are served through ``serve.Engine`` (ROADMAP M1)."""
+    if cfg.looped:
+        raise NotImplementedError(
+            f"{what} runs a stack once: a looped stack (n_passes="
+            f"{cfg.n_passes}, loop_final_norm={cfg.loop_final_norm}, "
+            f"exit_gate={cfg.exit_gate}) would take a dense cache "
+            f"n_passes x n_layers deep and the walk repeated over it; "
+            f"serve this configuration through serve.Engine")
     if (not cfg.homogeneous or cfg.norm != "layernorm" or cfg.ffn != "gelu"
             or cfg.qk_norm or cfg.qk_norm_whole or cfg.moe_dropless
             or cfg.norm_placement != "pre"

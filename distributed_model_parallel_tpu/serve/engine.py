@@ -266,8 +266,9 @@ class Engine:
                   layout=layout, temperature=serve.temperature,
                   top_k=serve.top_k, top_p=serve.top_p)
         # The routed layers' counters (tokens routed, tokens a held
-        # expert), summed on the device by every step and fetched only
-        # when somebody asks (moe_counters); None for a dense model.
+        # expert) and the looped stack's (tokens, passes taken, the exit
+        # gate's mass a pass), summed on the device by every step and
+        # fetched only when somebody asks (moe_counters, loop_counters).
         self._stats = init_stats(cfg)
         self._prefill = make_prefill_step(cfg, chunk=serve.prefill_chunk,
                                           **kw)
@@ -370,6 +371,10 @@ class Engine:
                 "full": self.cache.layout.n_full,
                 "ring": self.cache.layout.n_ring,
                 "state": self.cache.layout.n_state},
+            # a looped stack: passes over the same weights, and the cache
+            # layers they fill (passes x the model's layers)
+            "passes": self.cfg.n_passes,
+            "cache_layers": self.cache.layout.cache_layers,
             "refused_for_state_layers": (
                 ["prefix_cache", "spec_k", "export_request",
                  "import_request"] if self.cache.layout.n_state else []),
@@ -487,6 +492,23 @@ class Engine:
                         "row_tile_visits": int(row[-1])}
                 for layer, row in stats_by_layer(self._stats,
                                                  self.cfg).items()}
+
+    def loop_counters(self) -> dict:
+        """The looped stack's counters since the engine was built, fetched
+        from the device (a sync, like :meth:`moe_counters`): ``{"passes",
+        "tokens", "token_passes", "exit_mass"}``: valid tokens through the
+        stack (prompt and decode alike), the passes they took
+        (``passes`` each while every row runs every pass), and for each
+        pass the sum over those tokens of the exit gate's probability of
+        leaving there (sums to ``tokens``; all on the last pass without a
+        gate); empty for a stack run once."""
+        loop = self._stats["loop"]
+        if loop is None:
+            return {}
+        return {"passes": self.cfg.n_passes,
+                "tokens": int(loop["tokens"]),
+                "token_passes": int(loop["token_passes"]),
+                "exit_mass": np.asarray(loop["exit_mass"]).tolist()}
 
     # -- submission ---------------------------------------------------------
 

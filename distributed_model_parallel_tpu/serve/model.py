@@ -27,12 +27,20 @@ dropless layer (``ops/moe.moe_ffn_dropless``): a token's result is its
 own whatever shares the call. The capacity-dropping layer couples
 co-resident tokens and stays refused (serve/engine.py).
 
+A looped stack (``TransformerConfig.n_passes``) is served by the same
+three steps: :func:`_layers` runs ``transformer.run_passes``, the walk
+over the layers repeated over the same leaves inside one jitted step,
+each pass writing its K/V into its own cache layer of the donated pools
+(serve/paged_kv.CacheLayout: the pools are ``n_passes`` times as deep as
+the weights) and reading them back whole with that index.
+
 Every step takes and returns the device state it donates: ``pools``
 (``PagedKVCache.pools``, six arrays by layer kind: the full layers' K and
 V pools, the sliding layers' ring pools, the state layers' recurrent
 states and convolution tails, serve/paged_kv.CacheLayout; None where the
-model has no layer of the kind) and ``stats`` (the routed layers'
-counters, summed on the device; None for a dense model). ``tables`` is
+model has no layer of the kind) and ``stats`` (:func:`init_stats`: the
+routed layers' counters and the looped stack's, summed on the device;
+None to count nothing). ``tables`` is
 ``(table, ring)``: a sequence's pages of the shared pool and its ring
 pages (None where no layer keeps a ring); the prefill step of a model
 with state layers takes ``(table, ring, slot)``: which slot's state the
@@ -54,8 +62,9 @@ from distributed_model_parallel_tpu.models.transformer import (
     apply_rope,
     gated_delta_inputs,
     gated_delta_output,
+    exit_distribution,
     make_sampler,
-    run_layers,
+    run_passes,
     sublayer_in,
     sublayer_out,
     unembed,
@@ -202,13 +211,18 @@ def state_block(bp: dict, kind: LayerKind, pools: tuple, layer,
 def _layers(params: dict, pools, stats, x, positions, pages, tables,
             valid, lengths, cfg, layout: CacheLayout | None, impl: str,
             page_size: int, fresh=None):
-    """All layers over the paged cache (``run_layers``). pages [B, C]:
-    each token's logical page (an invalid token's is irrelevant);
-    tables: (table [B, N], ring [B, R] or None[, slot [B]: the prefill
-    row's slot, for state layers]); ``fresh`` (the prefill step's; None
-    in a decode round): the row's sequence starts with this call (a state
-    layer then starts from zeros). Returns ``(x, pools, stats)`` with the
-    routed layers' counters added to ``stats``."""
+    """All passes over all layers over the paged cache (``run_passes``:
+    a looped stack walks the same leaves ``cfg.n_passes`` times inside
+    this one step, the pools in the carry across passes, and pass ``t``
+    of a layer reads and writes its own cache layer,
+    ``CacheLayout.cache_layer``). pages [B, C]: each token's logical
+    page (an invalid token's is irrelevant); tables: (table [B, N], ring
+    [B, R] or None[, slot [B]: the prefill row's slot, for state
+    layers]); ``fresh`` (the prefill step's; None in a decode round):
+    the row's sequence starts with this call (a state layer then starts
+    from zeros). Returns ``(x, pools, stats)`` with the routed layers'
+    counters and the looped stack's added to ``stats``
+    (:func:`init_stats`)."""
     table, ring, *rest = tables
     mine = None
     if rest:
@@ -236,56 +250,85 @@ def _layers(params: dict, pools, stats, x, positions, pages, tables,
         # logical page j of a sliding layer lives in ring page j % R
         ring_table = jnp.take(ring, jnp.arange(n) % ring.shape[1], axis=1)
         writes[True] = (physical(ring_table, pools[2]), ring_table)
-    bodies = (layout or CacheLayout.all_full(cfg.n_layers)).bodies
+    layout = layout or CacheLayout.all_full(cfg)
     routed = cfg.moe_dropless
 
-    def layer(bp, kind, body, rep, carry):
+    def layer(t, bp, kind, body, rep, carry):
         x, pools = carry
-        is_ring, base, stride = bodies[body]
+        is_ring, at = layout.cache_layer(body, rep, t)
         if is_ring is None:                    # a state layer: no K/V
             x, pools, aux = state_block(
-                bp, kind, pools, base + rep * stride, x, valid, fresh, cfg,
-                impl=impl)
+                bp, kind, pools, at, x, valid, fresh, cfg, impl=impl)
         else:
             x, pools, aux = paged_block(
-                bp, kind, pools, (is_ring, base + rep * stride), x,
-                positions, writes, offsets, lengths, valid, cfg, impl=impl)
+                bp, kind, pools, (is_ring, at), x, positions, writes,
+                offsets, lengths, valid, cfg, impl=impl)
         return (x, pools), (aux if routed and kind.ffn == "moe" else None)
 
-    (x, pools), counts = run_layers(params, (x, pools), layer, cfg)
+    x, pools, counts, gates = run_passes(params, x, pools, layer, cfg)
     if mine is not None:
         pools = pools[:4] + tuple(
             jax.lax.dynamic_update_slice(p, new, mine)
             for p, new in zip(whole, pools[4:]))
-    if stats is not None:
-        stats = jax.tree.map(jnp.add, stats, counts)
+    if stats is None:
+        return x, pools, stats
+    stats = dict(stats)
+    if stats["moe"] is not None:
+        if cfg.looped:                         # counts: stacked on [passes]
+            counts = jax.tree.map(lambda c: jnp.sum(c, axis=0), counts)
+        stats["moe"] = jax.tree.map(jnp.add, stats["moe"], counts)
+    if stats["loop"] is not None:
+        n = jnp.sum(valid, dtype=jnp.int32)
+        # without a gate every token leaves after the last pass
+        mass = (jnp.zeros((cfg.n_passes,), jnp.float32).at[-1].set(n)
+                if gates is None else jnp.sum(
+                    jnp.where(valid, exit_distribution(gates), 0.0),
+                    axis=(1, 2)))
+        stats["loop"] = jax.tree.map(jnp.add, stats["loop"], {
+            "tokens": n, "token_passes": cfg.n_passes * n,
+            "exit_mass": mass})
     return x, pools, stats
 
 
-def init_stats(cfg: TransformerConfig):
-    """Zeroed counters of the routed layers in ``run_layers``' order of
-    bodies (None for a model without dropless routed layers): int32
-    [repeats, G + 3] a routed body: tokens a held expert, tokens routed,
-    experts touched and row tiles visited a call
-    (ops/moe.moe_ffn_dropless). :func:`stats_by_layer` puts them in layer
-    order."""
-    if not (cfg.moe_dropless and cfg.moe_experts):
-        return None
-    n_lead, period, n_periods = cfg.layer_plan
-    g = cfg.moe.held_range[1]
-    return tuple(
-        jnp.zeros((1 if i < n_lead else n_periods, g + 3), jnp.int32)
-        if cfg.kinds[i].ffn == "moe" else None
-        for i in range(n_lead + period))
+def init_stats(cfg: TransformerConfig) -> dict:
+    """The zeroed counters the steps sum on the device, ``{"moe", "loop"}``:
+
+    * ``moe``: the routed layers', in ``run_layers``' order of bodies
+      (None for a model without dropless routed layers): int32 [repeats,
+      G + 3] a routed body: tokens a held expert, tokens routed, experts
+      touched and row tiles visited a call (ops/moe.moe_ffn_dropless),
+      summed over a looped stack's passes. :func:`stats_by_layer` puts
+      them in layer order.
+    * ``loop``: the looped stack's (None for a stack run once with no
+      gate and no norm in the loop): ``tokens`` (valid tokens through the
+      stack), ``token_passes`` (passes they took: ``n_passes`` each
+      today, what rows that leave early would lower), int32; ``exit_mass``
+      [n_passes] float32, the sum over those tokens of the exit gate's
+      ``p_exit(t)`` (``transformer.exit_distribution``)."""
+    moe = None
+    if cfg.moe_dropless and cfg.moe_experts:
+        n_lead, period, n_periods = cfg.layer_plan
+        g = cfg.moe.held_range[1]
+        moe = tuple(
+            jnp.zeros((1 if i < n_lead else n_periods, g + 3), jnp.int32)
+            if cfg.kinds[i].ffn == "moe" else None
+            for i in range(n_lead + period))
+    loop = None
+    if cfg.looped:
+        loop = {"tokens": jnp.zeros((), jnp.int32),
+                "token_passes": jnp.zeros((), jnp.int32),
+                "exit_mass": jnp.zeros((cfg.n_passes,), jnp.float32)}
+    return {"moe": moe, "loop": loop}
 
 
 def stats_by_layer(stats, cfg: TransformerConfig) -> dict:
-    """{layer index: host int array [G + 3]} of the routed layers."""
+    """{layer index: host int array [G + 3]} of the routed layers
+    (``stats``: :func:`init_stats`' dict as the steps returned it)."""
     import numpy as np
 
     n_lead, period, _ = cfg.layer_plan
     out = {}
-    for body, rows in enumerate(stats or ()):
+    for body, rows in enumerate((stats or {}).get("moe") or ()):
         if rows is None:
             continue
         for rep, row in enumerate(np.asarray(rows)):

@@ -95,11 +95,20 @@ class CacheLayout:
     prefix needs every layer's pages whole): such a cache shares
     prefixes and migrates by page like any other.
 
+    **Cache layers are not weight layers** where the stack is looped
+    (``cfg.n_passes`` passes over the same leaves): pass ``t`` of layer
+    ``l`` keeps its own K/V (the keys of pass 2 are projections of pass
+    1's output, not of the embedding), so ``n_full``, ``n_ring`` and
+    ``n_state`` count ``passes`` times the model's layers of the kind,
+    pass after pass: a page id spans all of them, and a token costs
+    ``passes`` times a single walk's bytes.
+
     ``bodies``: for each of the ``n_lead + period`` layer bodies of
-    ``cfg.layer_plan`` ``(ring, base, stride)``: the layer of repeat
-    ``rep`` is layer ``base + rep * stride`` of the ring pools (``ring``
-    True), of the full pools (False), or of the state pools (None: the
-    layer holds no K/V).
+    ``cfg.layer_plan`` ``(ring, base, stride)``: in pass ``t`` the layer
+    of repeat ``rep`` is cache layer ``t * (layers of its kind a pass) +
+    base + rep * stride`` (:meth:`cache_layer`) of the ring pools
+    (``ring`` True), of the full pools (False), or of the state pools
+    (None: the layer holds no K/V).
     """
 
     ring_pages: int          # 0: no layer keeps a ring
@@ -107,6 +116,7 @@ class CacheLayout:
     n_ring: int
     bodies: tuple
     n_state: int = 0
+    passes: int = 1
 
     @classmethod
     def of(cls, cfg, *, page_size: int, max_seq_len: int, span: int,
@@ -133,14 +143,32 @@ class CacheLayout:
             (ring[j], ring[:j].count(ring[j]),
              0 if j < n_lead else in_period.count(ring[j]))
             for j in range(n_lead + period))
+        t = cfg.n_passes
         return cls(ring_pages=max(rings, default=0),
-                   n_full=ring.count(False), n_ring=ring.count(True),
-                   bodies=bodies, n_state=ring.count(None))
+                   n_full=t * ring.count(False), n_ring=t * ring.count(True),
+                   bodies=bodies, n_state=t * ring.count(None), passes=t)
 
     @classmethod
-    def all_full(cls, n_layers: int) -> "CacheLayout":
-        """Every layer keeps whole contexts: one stack of equal layers."""
-        return cls(0, n_layers, 0, ((False, 0, 1),))
+    def all_full(cls, cfg) -> "CacheLayout":
+        """Every layer keeps whole contexts: one stack of equal layers,
+        a cache layer for each pass of each."""
+        return cls(0, cfg.n_passes * cfg.n_layers, 0, ((False, 0, 1),),
+                   passes=cfg.n_passes)
+
+    @property
+    def cache_layers(self) -> int:
+        """Cache layers of every kind: the model's layers times its
+        passes."""
+        return self.n_full + self.n_ring + self.n_state
+
+    def cache_layer(self, body: int, rep, t=0) -> tuple:
+        """``(ring, layer)``: which pools hold the K/V (or the state) of
+        layer body ``body``, repeat ``rep`` (traced or not), in pass
+        ``t`` (traced or not), and at which index."""
+        ring, base, stride = self.bodies[body]
+        of_kind = {False: self.n_full, True: self.n_ring,
+                   None: self.n_state}[ring]
+        return ring, t * (of_kind // self.passes) + base + rep * stride
 
 
 def stored_kv_heads(kv_heads: int) -> int:
@@ -243,7 +271,8 @@ class PagedKVCache:
 
     ``ck``/``cv``: [L, n_pages, page_size, Hkv, Dh] device arrays the
     engine threads through its jitted steps (donated, so XLA updates
-    them in place), beside the sliding layers' rings ``wk``/``wv`` and
+    them in place; ``L`` counts cache layers: a looped stack's passes
+    times its layers, ``CacheLayout``), beside the sliding layers' rings ``wk``/``wv`` and
     the state layers' ``state``/``tail`` where the model has such layers
     (``CacheLayout``; None where it has not). The page table of
     sequence ``sid`` maps logical page
@@ -299,7 +328,7 @@ class PagedKVCache:
         # engine's slots), handed out at admission and returned with the
         # sequence. No layout: every layer is full.
         if layout is None:
-            layout = CacheLayout.all_full(cfg.n_layers)
+            layout = CacheLayout.all_full(cfg)
         self.layout = layout
         if layout.ring_pages and prefix_cache:
             raise CacheKindError(
@@ -355,6 +384,13 @@ class PagedKVCache:
     @pools.setter
     def pools(self, pools) -> None:
         self.ck, self.cv, self.wk, self.wv, self.state, self.tail = pools
+
+    @property
+    def kv_bytes_per_token(self) -> int:
+        """Bytes one token of context holds in the shared pool: K and V
+        of every full cache layer (every pass of a looped stack)."""
+        return (self.ck.nbytes + self.cv.nbytes) // (
+            self.pool.n_pages * self.page_size)
 
     @property
     def state_bytes_per_slot(self) -> int:
@@ -533,8 +569,8 @@ class PagedKVCache:
                        trace_fields=None):
         """Serialize the K/V **contents** of ``sid``'s first ``n_tokens``
         written positions to host arrays ``(k, v)`` of shape
-        ``[L, pages, page_size, Hkv, Dh]`` — whole pages, values only.
-        Shared prefix pages are exported by value like any other, so the
+        ``[L, pages, page_size, Hkv, Dh]`` (``L`` cache layers) — whole
+        pages, values only. Shared prefix pages are exported by value like any other, so the
         payload holds no reference to this pool (the destination
         allocates fresh pages; see :meth:`import_request`). The caller
         guarantees every exported position's KV is actually written —
@@ -550,10 +586,10 @@ class PagedKVCache:
                 f"sequence {sid!r}: exporting {n_tokens} tokens spans "
                 f"{n} pages but the table holds {len(table)}")
         idx = np.asarray(table[:n], np.int32)
-        # One host fetch per pool: [L, n, page, Hkv, Dh].
+        # One host fetch per pool: [L, n, page, Hkv, Dh], L the cache
+        # layers (every pass of a looped stack: a page id spans them all).
         k = np.asarray(self.ck[:, idx]) if n else np.zeros(
-            (self.cfg.n_layers, 0, self.page_size, self.ck.shape[3],
-             self.ck.shape[4]), self.ck.dtype)
+            (self.ck.shape[0], 0) + self.ck.shape[2:], self.ck.dtype)
         v = np.asarray(self.cv[:, idx]) if n else np.zeros_like(k)
         if req is not None:
             tracing.rtrace(req, "export", sink=sink, pages=n,
@@ -643,8 +679,12 @@ def memory_gauges(cache: PagedKVCache) -> dict:
         "used_pages": cache.pool.used_pages,
         "prefix_pages": len(cache.prefix) if cache.prefix is not None else 0,
         "free_watermark": cache.pool.free_watermark,
-        # pages held by layer kind: a full layer holds every page of the
-        # shared pool that is in use, a sliding layer the rings
+        # cache layers (a looped stack: passes x layers; the weights have
+        # fewer) and what one token of context costs across them
+        "cache_layers": cache.layout.cache_layers,
+        "kv_bytes_per_token": cache.kv_bytes_per_token,
+        # pages held by layer kind: a full cache layer holds every page of
+        # the shared pool that is in use, a sliding layer the rings
         "full_layer_pages": cache.pool.used_pages * cache.layout.n_full,
         "sliding_layer_pages": (cache.ring_pool.used_pages
                                 * cache.layout.n_ring

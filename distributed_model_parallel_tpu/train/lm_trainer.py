@@ -175,6 +175,15 @@ class LMTrainer:
         if cfg.max_seq_len < config.seq_len:
             raise ValueError("model max_seq_len < training seq_len")
         self.cfg = cfg
+        if cfg.looped:
+            raise NotImplementedError(
+                f"LMTrainer trains a stack run once: a looped stack "
+                f"(n_passes={cfg.n_passes}, loop_final_norm="
+                f"{cfg.loop_final_norm}, exit_gate={cfg.exit_gate}) would "
+                f"take the head on every pass's output and the looped "
+                f"objective (the expected loss over exit steps under the "
+                f"gate's distribution, with its entropy term), which are "
+                f"not written (ROADMAP M8); serve it through serve.Engine")
         if config.optimizer.ema_decay is not None:
             raise ValueError(
                 "ema_decay is implemented by the data-parallel Trainer "

@@ -287,6 +287,191 @@ def test_serving_steps_cut_no_slab_out_of_the_pools(
         assert whole                       # the pattern reads this program
 
 
+@pytest.mark.parametrize("step", ["prefill", "decode", "verify"])
+def test_looped_steps_cut_no_slab_and_copy_no_pool_at_any_pass(
+        one_chip, monkeypatch, step):
+    """The steps of a looped stack (48 layers run four times over the
+    same leaves: ``run_passes``' scan around ``run_layers``' scan, the
+    pass and the layer both traced) at the shapes of the benchmark's cell
+    ``ouro-2.6b.reason-batch``: pools ``[192, 320, 16, 16, 128]`` (a cache
+    layer a (pass, layer), 16 stored heads with no grouping), 16 rows, a
+    256-token chunk; sandwich norms, the norm that closes a pass, the
+    exit gate. The stack's body is compiled ONCE (one paged kernel call
+    in the program, not four nor 192), no op cuts a cache layer's slab out
+    of the pools, and the only ops that yield a pool are the writes in
+    place: the outer loop carries the pools as the inner one does."""
+    from distributed_model_parallel_tpu.models import transformer as tfm
+    from distributed_model_parallel_tpu.serve import model as sm
+    from distributed_model_parallel_tpu.serve.paged_kv import CacheLayout
+
+    _compile_kernels(monkeypatch)
+    page, chunk, width, max_seq, n_pages, slots = 16, 256, 4, 1024, 320, 16
+    cfg = tfm.TransformerConfig(
+        vocab_size=1024, d_model=256, n_heads=16, n_kv_heads=16, d_head=128,
+        n_layers=48, d_ff=512, max_seq_len=max_seq, dtype=jnp.bfloat16,
+        pos_embedding="rope", rope_theta=1e6, norm="rmsnorm", ffn="swiglu",
+        norm_placement="sandwich", n_passes=4, loop_final_norm=True,
+        exit_gate=True)
+    layout = CacheLayout.of(cfg, page_size=page, max_seq_len=max_seq,
+                            span=chunk)
+    assert (layout.n_full, layout.passes, layout.ring_pages) == (192, 4, 0)
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    as_sds = functools.partial(jax.tree.map, lambda x: sds(x.shape, x.dtype))
+    params = as_sds(jax.eval_shape(
+        lambda: tfm.init_params(jax.random.key(0), cfg)))
+    assert params["blocks"]["wq"].shape[0] == 48
+    stats = as_sds(jax.eval_shape(lambda: sm.init_stats(cfg)))
+    pool = sds((192, n_pages, page, 16, 128), jnp.bfloat16)
+    pools = (pool, pool, None, None, None, None)
+    n = max_seq // page
+    tables, active = (sds((slots, n)), None), sds((slots,), jnp.bool_)
+    kws = dict(page_size=page, impl="pallas", layout=layout)
+    lowered = {
+        "prefill": lambda: sm.make_prefill_step(cfg, chunk=chunk, **kws).lower(
+            params, pools, stats, sds((1, chunk)), sds(()), sds(()),
+            (sds((n,)), None), None),
+        "decode": lambda: sm.make_decode_step(cfg, **kws).lower(
+            params, pools, stats, sds((slots,)), sds((slots,)), tables,
+            active, None),
+        "verify": lambda: sm.make_verify_step(cfg, width=width, **kws).lower(
+            params, pools, stats, sds((slots, width)), sds((slots,)),
+            sds((slots,)), tables, active, None),
+    }[step]()
+    text = lowered.compile().as_text()
+    assert text.startswith(f"HloModule jit_{step}_step")
+    kernel = "decode" if step == "decode" else "prefill"
+    assert len(re.findall(
+        rf"%paged_{kernel}_attention[\w.]* = .*custom-call\(", text)) == 1
+    slabs, whole = _pool_ops(text, pool.shape)
+    assert not slabs, slabs[0][1][:300]
+    moved = [line for op, line in whole if not (
+        op == "scatter" or (op == "fusion" and "/scatter\"" in line))]
+    assert not moved, moved[0][:300]
+    assert whole                           # the pattern reads this program
+    # the scopes the benchmark's readers find the stack and the gate by
+    assert "loop_stack" in text and "exit_gate" in text
+
+
+def _plain_passes(params, x, rest, fn, cfg):
+    """``run_passes`` as the parent commit walked the layers: once, with
+    no norm, no gate and no outer loop."""
+    from distributed_model_parallel_tpu.models import transformer as tfm
+
+    (x, rest), outs = tfm.run_layers(params, (x, rest),
+                                     functools.partial(fn, 0), cfg)
+    return x, rest, outs, None
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+@pytest.mark.parametrize("family", ["windowed-gqa", "routed-mixed",
+                                    "gated-delta-hybrid"])
+def test_steps_of_a_stack_run_once_compile_to_what_the_parent_compiles(
+        one_chip, monkeypatch, family, step):
+    """The blocks of the benchmark's older configurations (the default
+    block under a window with grouped queries; the gated, routed block
+    with sliding and full layers; gated-delta layers beside full ones), at
+    ``n_passes = 1``: the program the chip's compiler makes of each step
+    is, instruction for instruction, the one it makes of the parent
+    commit's walk (``_plain_passes``: ``run_layers`` once and nothing
+    around it). Source positions aside: they name the walking function."""
+    from distributed_model_parallel_tpu.models import transformer as tfm
+    from distributed_model_parallel_tpu.ops import moe
+    from distributed_model_parallel_tpu.serve import model as sm
+    from distributed_model_parallel_tpu.serve.paged_kv import (
+        CacheLayout,
+        stored_kv_heads,
+    )
+
+    _compile_kernels(monkeypatch)
+    monkeypatch.setattr(moe, "expert_products", functools.partial(
+        moe.expert_products, interpret=False))
+    page, chunk, max_seq, n_pages, slots = 16, 256, 2048, 1024, 8
+    kw = dict(vocab_size=1024, d_model=256, d_head=128, d_ff=512,
+              max_seq_len=max_seq, dtype=jnp.bfloat16, pos_embedding="rope")
+    if family == "windowed-gqa":
+        cfg = tfm.TransformerConfig(n_layers=4, n_heads=8, n_kv_heads=2,
+                                    attn_window=max_seq, **kw)
+    elif family == "routed-mixed":
+        s = tfm.LayerKind(window=128, rope=True, ffn="moe")
+        f = tfm.LayerKind(window=None, rope=False, ffn="moe")
+        cfg = tfm.TransformerConfig(
+            n_layers=5, n_heads=8, n_kv_heads=2, norm="rmsnorm",
+            ffn="swiglu", qk_norm=True,
+            layer_kinds=(tfm.LayerKind(128, True, "dense"), s, s, f, s),
+            moe_experts=16, moe_top_k=4, moe_dropless=True,
+            moe_scoring="sigmoid", moe_d_ff=256, moe_shared_experts=1,
+            moe_experts_held=(4, 4), **kw)
+    else:
+        lin, full = tfm.LayerKind(mixer="gated_delta"), tfm.LayerKind()
+        cfg = tfm.TransformerConfig(
+            n_layers=8, n_heads=30, n_kv_heads=30, norm="rmsnorm",
+            ffn="swiglu", qk_norm_whole=True, norm_placement="post",
+            layer_kinds=(lin, lin, lin, full) * 2, lin_key_heads=30,
+            lin_value_heads=30, lin_key_dim=96, lin_value_dim=192,
+            lin_neg_eigval=True, **kw)
+    assert not cfg.looped
+    layout = CacheLayout.of(cfg, page_size=page, max_seq_len=max_seq,
+                            span=chunk)
+    assert layout.passes == 1
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    as_sds = functools.partial(jax.tree.map, lambda x: sds(x.shape, x.dtype))
+    params = as_sds(jax.eval_shape(
+        lambda: tfm.init_params(jax.random.key(0), cfg)))
+    stats = as_sds(jax.eval_shape(lambda: sm.init_stats(cfg)))
+    hkv, rings = stored_kv_heads(cfg.kv_heads), layout.ring_pages
+    pool = lambda n, p: (sds((n, p, page, hkv, 128),       # noqa: E731
+                             jnp.bfloat16) if n else None)
+    full, ring = pool(layout.n_full, n_pages), pool(layout.n_ring,
+                                                    slots * rings)
+    state = tail = None
+    if layout.n_state:
+        state = sds((layout.n_state, slots, 96, 30 * 192), jnp.float32)
+        tail = sds((layout.n_state, slots, 3, cfg.lin_channels),
+                   jnp.bfloat16)
+    pools = (full, full, ring, ring, state, tail)
+    n = max_seq // page
+    kws = dict(page_size=page, impl="pallas", layout=layout)
+
+    def compiled():
+        for make in (sm.make_prefill_step, sm.make_decode_step):
+            make.cache_clear()
+        if step == "decode":
+            lowered = sm.make_decode_step(cfg, **kws).lower(
+                params, pools, stats, sds((slots,)), sds((slots,)),
+                (sds((slots, n)), sds((slots, rings)) if rings else None),
+                sds((slots,), jnp.bool_), None)
+        else:
+            table = (sds((n,)), sds((rings,)) if rings else None)
+            if layout.n_state:
+                table += (sds(()),)
+            lowered = sm.make_prefill_step(cfg, chunk=chunk, **kws).lower(
+                params, pools, stats, sds((1, chunk)), sds(()), sds(()),
+                table, None)
+        text = lowered.compile().as_text()
+        # the instructions, without where in the Python they came from
+        # (the table of stack frames and each instruction's index into it)
+        body = text[text.index("\n\n", text.index("StackFrames")):] if (
+            "StackFrames" in text) else text
+        # ... nor a kernel's serialized body, which carries its own
+        body = re.sub(r'"body": ?"[^"]*"', '"body":""', body)
+        return text.split("\n", 1)[0] + re.sub(r" stack_frame_id=\d+", "",
+                                                 body)
+
+    ours = compiled()
+    monkeypatch.setattr(sm, "run_passes", _plain_passes)
+    theirs = compiled()
+    for make in (sm.make_prefill_step, sm.make_decode_step):
+        make.cache_clear()
+    assert f"HloModule jit_{step}_step" in ours
+    assert ours == theirs
+
+
 def test_gated_delta_decode_kernel_compiles(one_chip):
     """The decode round's state update at the published widths (30 heads
     of 96 x 192, 32 slots, 12 layers): Mosaic takes it, and the pool goes

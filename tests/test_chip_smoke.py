@@ -36,14 +36,20 @@ def _last_line(out: str) -> dict:
 def test_tiny_rehearsal_runs_every_phase(capsys):
     assert chip_smoke.main(["--tiny"]) == 0
     out = capsys.readouterr().out
-    for name in ("cnn", "lm", "serve", "serve-routed", "serve-hybrid"):
+    for name in ("cnn", "lm", "serve", "serve-routed", "serve-hybrid",
+                 "serve-looped"):
         assert f"phase {name}: ok" in out, out[-2000:]
     assert out.index("phase cnn: ok") < out.index("phase lm: ok") \
         < out.index("phase serve: ok") < out.index("phase serve-routed: ok") \
-        < out.index("phase serve-hybrid: ok")
+        < out.index("phase serve-hybrid: ok") \
+        < out.index("phase serve-looped: ok")
     for what in ("gated-delta decode kernel vs the step",
                  "gated-delta chunked form (80 tokens) vs the recurrence",
-                 "f32 hybrid engine: greedy tokens identical"):
+                 "f32 hybrid engine: greedy tokens identical",
+                 "9 cache layers for 3 layers of weights",
+                 "f32 looped engine: greedy tokens identical",
+                 "looped decode logits through 9 cache layers, kernel vs "
+                 "xla"):
         assert f"ok: {what}" in out, out[-3000:]
     assert "multichip" not in out
     _last_line(out)

@@ -514,7 +514,7 @@ def test_config_refuses_linear_layers_without_widths():
     with pytest.raises(ValueError, match="lin_key_heads"):
         config(lin_key_heads=0)
     with pytest.raises(ValueError, match="norm_placement"):
-        config(norm_placement="sandwich")
+        config(norm_placement="sideways")
     with pytest.raises(NotImplementedError, match="default block"):
         tfm.generate(random_params(config()), config(),
                      jnp.zeros((1, 4), jnp.int32), 2)

@@ -613,7 +613,7 @@ def test_equal_windowed_layers_keep_whole_pages_and_migrate_by_page():
         _, want = run_requests(params, cfg, serve_config(n_slots=2), prompts,
                                max_new)
         src, moved, got = drain_midway(params, cfg, prompts, max_new, 3)
-    assert src.cache.layout == paged_kv.CacheLayout.all_full(3)
+    assert src.cache.layout == paged_kv.CacheLayout.all_full(cfg)
     # r0 decodes, r1 is in its third prefill chunk, r2 still waits
     assert moved == [("r0", True, False), ("r1", True, False),
                      ("r2", False, False)]

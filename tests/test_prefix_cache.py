@@ -176,8 +176,8 @@ def test_radix_exclude_protects_matched_path():
 # ---------------------------------------------------------------------------
 
 def _cache(n_pages=12, page=4, max_seq=32):
-    cfg = type("C", (), {"n_layers": 1, "kv_heads": 1, "head_dim": 4,
-                         "dtype": jnp.float32})
+    cfg = type("C", (), {"n_layers": 1, "n_passes": 1, "kv_heads": 1,
+                         "head_dim": 4, "dtype": jnp.float32})
     return PagedKVCache(cfg, n_pages=n_pages, page_size=page,
                         max_seq_len=max_seq, prefix_cache=True)
 
@@ -258,8 +258,8 @@ def test_share_granularity_quantizes_to_chunk_boundary():
     assert share_granularity_for(8, 32) == 32
     assert share_granularity_for(16, 12) == 48
     cache = PagedKVCache(
-        type("C", (), {"n_layers": 1, "kv_heads": 1, "head_dim": 4,
-                       "dtype": jnp.float32}),
+        type("C", (), {"n_layers": 1, "n_passes": 1, "kv_heads": 1,
+                       "head_dim": 4, "dtype": jnp.float32}),
         n_pages=16, page_size=4, max_seq_len=64, prefix_cache=True,
         share_granularity=8)
     toks = list(range(13))                     # 3 full pages

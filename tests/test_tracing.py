@@ -593,3 +593,47 @@ def test_build_trace_tolerates_minimal_and_foreign_records():
     ])
     assert all("ts" in e for e in trace["traceEvents"])
 
+
+
+def test_the_looped_stack_carries_its_scopes_counters_and_gauges():
+    """A stack run three times over shared weights: both steps' device
+    ops sit under ``loop_stack`` (every pass of every layer, the norm
+    that closes a pass) or ``exit_gate``; ``Engine.loop_counters()``
+    counts tokens, the passes they took and the gate's mass a pass; the
+    memory gauges say how deep the cache is and what a token costs."""
+    import jax.numpy as jnp
+    from distributed_model_parallel_tpu.models import transformer as tfm
+    from distributed_model_parallel_tpu.serve import Engine, ServeConfig
+    from distributed_model_parallel_tpu.serve.paged_kv import memory_gauges
+
+    cfg = tfm.TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_kv_heads=2, n_layers=2,
+        d_ff=64, max_seq_len=64, pos_embedding="rope", norm="rmsnorm",
+        ffn="swiglu", norm_placement="sandwich", n_passes=3,
+        loop_final_norm=True, exit_gate=True)
+    eng = Engine(tfm.init_params(jax.random.key(0), cfg), cfg,
+                 ServeConfig(n_slots=2, page_size=8, n_pages=32,
+                             max_seq_len=64, prefill_chunk=8,
+                             attn_impl="xla"), slo_metrics=False)
+    got = eng.op_scopes(("loop_stack", "exit_gate"))
+    assert sorted(got) == ["jit_decode_step", "jit_prefill_step"]
+    for module in got.values():
+        assert set(module.values()) == {"loop_stack", "exit_gate"}
+    assert eng.loop_counters() == {
+        "passes": 3, "tokens": 0, "token_passes": 0,
+        "exit_mass": [0.0, 0.0, 0.0]}
+    req = eng.submit([1, 2, 3], 5)
+    eng.step_once(0.0, 0.0)
+    eng.step_once(0.0, 0.0)
+    c = eng.loop_counters()
+    # the prompt, and every generated token but the last (not yet fed)
+    fed = 3 + len(req.generated) - 1
+    assert len(req.generated) >= 2
+    assert (c["tokens"], c["token_passes"]) == (fed, 3 * fed)
+    assert sum(c["exit_mass"]) == pytest.approx(fed, rel=1e-5)
+    g = memory_gauges(eng.cache)
+    assert g["cache_layers"] == 6 and req.slot is not None
+    assert g["kv_bytes_per_token"] == 6 * 2 * 2 * 16 * 4
+    assert g["full_layer_pages"] == 6 * g["used_pages"] == 6
+    status = eng._status()
+    assert (status["passes"], status["cache_layers"]) == (3, 6)
