@@ -543,18 +543,27 @@ def _rope_qk(q: jax.Array, k: jax.Array, cfg: TransformerConfig
             apply_rope(k, positions, cfg.rope_theta))
 
 
+def _in_proj(bp: dict, name: str, h: jax.Array) -> jax.Array:
+    """``h [B,T,d]`` through the in-projection ``name``: the stored leaf
+    ``[d,H,X]``, or ``name_t [H,X,d]`` where the layer holds that instead
+    (the serving engine's layout, serve/model.in_proj_d_last: the
+    product's right-hand side as XLA:TPU takes it, ``d`` minor). The same
+    contraction in the same types either way."""
+    if name + "_t" in bp:
+        return jnp.einsum("btd,hxd->bthx", h, bp[name + "_t"])
+    return jnp.einsum("btd,dhx->bthx", h, bp[name])
+
+
 def _qkv_proj(bp: dict, h: jax.Array, cfg: TransformerConfig):
     """Project to q [B,T,H(_local),Dh] and k/v [B,T,Hkv(_local),Dh] —
     fused wqkv for multi-head, separate wq/wkv for grouped-query. One
     helper for training, prefill, and cached decode so they never
     diverge."""
     if cfg.gqa:
-        q = jnp.einsum("btd,dhx->bthx", h, bp["wq"])
-        kv = jnp.einsum("btd,dhx->bthx", h, bp["wkv"])
-        k, v = jnp.split(kv, 2, axis=-1)
+        q = _in_proj(bp, "wq", h)
+        k, v = jnp.split(_in_proj(bp, "wkv", h), 2, axis=-1)
     else:
-        qkv = jnp.einsum("btd,dhx->bthx", h, bp["wqkv"])
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q, k, v = jnp.split(_in_proj(bp, "wqkv", h), 3, axis=-1)
     if cfg.qk_norm_whole:
         # over all heads at once: the mean runs over the whole vector
         whole = lambda x, g: rms_norm(                        # noqa: E731

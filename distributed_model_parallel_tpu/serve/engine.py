@@ -32,6 +32,8 @@ from distributed_model_parallel_tpu.models.transformer import (
     validate_sampling,
 )
 from distributed_model_parallel_tpu.serve.model import (
+    in_proj_d_last,
+    in_proj_relaid,
     init_stats,
     make_decode_step,
     make_prefill_step,
@@ -168,7 +170,13 @@ class Engine:
         if serve.spec_ngram < 1:
             raise ValueError(f"spec_ngram must be >= 1, got "
                              f"{serve.spec_ngram}")
-        self.params = params
+        # The attention in-projections of stacked layers in the layout
+        # their product takes (``d`` last), converted here once: no step
+        # then cuts a layer's ``wq``/``wkv`` out of the stack to transpose
+        # it. The caller's tree is left as it was; one that already holds
+        # the converted leaves (another engine's ``params``) comes back
+        # as it is.
+        self.params = in_proj_d_last(params)
         self.cfg = cfg
         self.serve = serve
         self.telemetry = telemetry
@@ -344,6 +352,7 @@ class Engine:
 
     def _status(self) -> dict:
         """The engine's /statusz provider payload."""
+        relaid = in_proj_relaid(self.params)
         return {
             "workload": "serve",
             "policy": self.serve.policy,
@@ -375,6 +384,11 @@ class Engine:
             # layers they fill (passes x the model's layers)
             "passes": self.cfg.n_passes,
             "cache_layers": self.cache.layout.cache_layers,
+            # the in-projection leaves this engine holds with the
+            # contracted axis last (serve/model.in_proj_d_last): new
+            # arrays beside the tree of a caller that keeps its own
+            "weights_relaid": len(relaid),
+            "weights_relaid_bytes": sum(w.nbytes for w in relaid),
             "refused_for_state_layers": (
                 ["prefix_cache", "spec_k", "export_request",
                  "import_request"] if self.cache.layout.n_state else []),

@@ -34,6 +34,16 @@ each pass writing its K/V into its own cache layer of the donated pools
 (serve/paged_kv.CacheLayout: the pools are ``n_passes`` times as deep as
 the weights) and reading them back whole with that index.
 
+The steps read the attention in-projections of layers stacked under
+``run_layers``' scan with the contracted axis last (``wq_t [L, H, Dh,
+d]``, ``wkv_t``, ``wqkv_t``: :func:`in_proj_d_last`, which
+``Engine.__init__`` applies once to the tree it is given;
+``Engine._status()`` reports the leaves it holds so and their bytes as
+``weights_relaid`` and ``weights_relaid_bytes``). A tree as stored (``wq
+[L, d, H, Dh]``) serves the same tokens through the same steps, with
+each layer's leaves cut out of the stack and transposed on the way into
+the product.
+
 Every step takes and returns the device state it donates: ``pools``
 (``PagedKVCache.pools``, six arrays by layer kind: the full layers' K and
 V pools, the sliding layers' ring pools, the state layers' recurrent
@@ -334,6 +344,69 @@ def stats_by_layer(stats, cfg: TransformerConfig) -> dict:
         for rep, row in enumerate(np.asarray(rows)):
             out[body + rep * period] = row
     return dict(sorted(out.items()))
+
+
+IN_PROJECTIONS = ("wq", "wkv", "wqkv")
+
+
+def _block_groups(params: dict) -> tuple:
+    """The dicts of stacked layer leaves: ``blocks`` itself, or one a
+    position of the period."""
+    blocks = params["blocks"]
+    return (blocks,) if isinstance(blocks, dict) else tuple(blocks)
+
+
+@jax.jit
+def _d_last(leaves: list) -> list:
+    return [jnp.moveaxis(w, 1, -1) for w in leaves]
+
+
+def in_proj_d_last(params: dict) -> dict:
+    """``params`` with the attention in-projections of every group of
+    ``blocks`` stacked over more than one layer held in the layout their
+    product takes: ``wq [L, d, H, Dh]`` becomes ``wq_t [L, H, Dh, d]``, ``wkv``
+    ``wkv_t [L, Hkv, 2 Dh, d]``, ``wqkv`` ``wqkv_t``;
+    ``transformer._qkv_proj`` reads whichever a layer holds.
+
+    Why: XLA:TPU gives the right-hand side of ``h [B, T, d] x w`` the
+    layout with the contracted axis ``d`` minor. Of a stored leaf, ``d``
+    third from minor, every step cut each layer out of the stack under
+    ``run_layers``' scan and transposed it (at 16 heads with no grouping:
+    all layers' at the step's entry), 5-7 % of three of the benchmark's
+    cells; of a leaf with ``d`` last the product reads the stack in
+    place, as the MLP's do. Why only where ``L > 1``: a layer whose index
+    is static (a leading layer, a period that does not repeat) is not cut
+    out of anything, the compiler fuses its transposition into the
+    product's own read, and there is nothing to take out; converted all
+    the same, K-EXAONE's five such layers cost the prefill step 4.6 ms of
+    23 on the chip (other operands lost their place in fast memory). The
+    stored format (``init_params``, checkpoints, the sharding rules)
+    stays ``[L, d, H, Dh]``; the engine converts once, when it is built
+    (``Engine.__init__``, and nothing else calls this).
+
+    One jitted call over all the leaves. Every other leaf is passed
+    through, the same array; a tree that already holds the ``*_t``
+    leaves comes back as it is. The caller's own leaves are not donated:
+    who keeps its tree holds both forms."""
+    groups = _block_groups(params)
+    found = [(g, name) for g, bp in enumerate(groups)
+             for name in IN_PROJECTIONS
+             if name in bp and bp[name].shape[0] > 1]
+    if not found:
+        return params
+    new = [dict(bp) for bp in groups]
+    for (g, name), w in zip(found,
+                            _d_last([groups[g][n] for g, n in found])):
+        del new[g][name]
+        new[g][name + "_t"] = w
+    return dict(params, blocks=(
+        new[0] if isinstance(params["blocks"], dict) else tuple(new)))
+
+
+def in_proj_relaid(params: dict) -> list:
+    """The ``*_t`` leaves of a tree (:func:`in_proj_d_last`'s)."""
+    return [bp[name + "_t"] for bp in _block_groups(params)
+            for name in IN_PROJECTIONS if name + "_t" in bp]
 
 
 def _embed_rows(params: dict, tokens: jax.Array, positions: jax.Array,

@@ -15,6 +15,7 @@ same reason.
 """
 
 import functools
+import itertools
 import re
 
 import jax
@@ -353,6 +354,176 @@ def test_looped_steps_cut_no_slab_and_copy_no_pool_at_any_pass(
     assert whole                           # the pattern reads this program
     # the scopes the benchmark's readers find the stack and the gate by
     assert "loop_stack" in text and "exit_gate" in text
+
+
+def _outside_fusions(text: str) -> str:
+    """The instructions of a compiled program that are ops of their own:
+    the bodies of the computations no fusion calls (the entry, the loops'
+    bodies and conditions). What sits inside a fused computation is read
+    and written by its fusion alone."""
+    fused = set(re.findall(r"kind=k\w+, calls=(%[\w.-]+)", text))
+    return "\n".join(
+        body for name, body in re.findall(
+            r"^(?:ENTRY )?(%[\w.-]+) \([^\n]*\{\n(.*?)^\}", text,
+            re.M | re.S) if name not in fused)
+
+
+def _in_proj_ops(text: str, leaves: list) -> list:
+    """The ops of a compiled program that yield an attention
+    in-projection: an array with the axes of one of ``leaves`` (``[L, d,
+    H, X]`` as stored) in any order, of one layer (with or without its
+    leading 1) or of the whole stack. Left out: what moves nothing (a
+    parameter, a bitcast, an element of a tuple) and ``copy-done``, the
+    end of a prefetch into fast memory that keeps the layout and runs
+    under other ops (a layer whose index is static gets one in either
+    layout)."""
+    shapes = set()
+    for n_layers, *axes in (leaf.shape for leaf in leaves):
+        for order in itertools.permutations(axes):
+            for lead in ((), (1,), (n_layers,)):
+                shapes.add(",".join(map(str, lead + order)))
+    shapes = "|".join(sorted(shapes))
+    ops = re.finditer(
+        rf"^\s*(?:ROOT )?%\S+ = bf16\[(?:{shapes})\]\S* ([\w-]+)\(.*$",
+        _outside_fusions(text), re.M)
+    return [m.group(0).strip() for m in ops if m.group(1) not in (
+        "parameter", "bitcast", "get-tuple-element", "copy-done")]
+
+
+def _in_proj_family(family: str):
+    """(cfg, page, chunk, max_seq, n_pages, slots) at the attention
+    widths of three of the benchmark's configurations."""
+    from distributed_model_parallel_tpu.models import transformer as tfm
+
+    kw = dict(vocab_size=1024, d_head=128, dtype=jnp.bfloat16,
+              pos_embedding="rope")
+    if family == "starcoder2-widths":
+        # d 3072, 24 heads over 2, the MLP's 12,288: four equal layers
+        # under run_layers' scan, pools of the cells' size
+        return tfm.TransformerConfig(
+            d_model=3072, n_heads=24, n_kv_heads=2, n_layers=4, d_ff=12288,
+            max_seq_len=4096, **kw), 16, 512, 4096, 8192, 32
+    if family == "looped":
+        # test_looped_steps_cut_no_slab_and_copy_no_pool_at_any_pass's
+        # stack at Ouro's widths: 16 heads with no grouping, the passes'
+        # scan around the layers'
+        return tfm.TransformerConfig(
+            d_model=2048, n_heads=16, n_kv_heads=16, n_layers=8, d_ff=5632,
+            max_seq_len=1024, rope_theta=1e6, norm="rmsnorm", ffn="swiglu",
+            norm_placement="sandwich", n_passes=4, loop_final_norm=True,
+            exit_gate=True, **kw), 16, 256, 1024, 320, 16
+    # K-EXAONE's kinds and attention widths (d 6144, 64 heads over 8):
+    # one period of five, every index static, every position its own
+    # leaves [1, d, H, X] (25 MB the smallest; a chunk of 256 keeps the
+    # activations, 3 MB each, well under that)
+    full = tfm.LayerKind(None, False, "dense")
+    sliding = tfm.LayerKind(128, True, "dense")
+    return tfm.TransformerConfig(
+        d_model=6144, n_heads=64, n_kv_heads=8, n_layers=5, d_ff=512,
+        max_seq_len=4096, norm="rmsnorm", ffn="swiglu", qk_norm=True,
+        layer_kinds=(sliding, sliding, sliding, full, sliding),
+        **kw), 16, 256, 4096, 8192, 32
+
+
+def _in_proj_program(one_chip, family: str, step: str, convert: bool):
+    """``(compiled, stored in-projection leaves)`` of one serving step of
+    ``family``, from the tree as stored or as an engine holds it."""
+    from distributed_model_parallel_tpu.models import transformer as tfm
+    from distributed_model_parallel_tpu.serve import model as sm
+    from distributed_model_parallel_tpu.serve.paged_kv import CacheLayout
+
+    cfg, page, chunk, max_seq, n_pages, slots = _in_proj_family(family)
+    layout = CacheLayout.of(cfg, page_size=page, max_seq_len=max_seq,
+                            span=chunk)
+    rings = layout.ring_pages
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    as_sds = functools.partial(jax.tree.map, lambda x: sds(x.shape, x.dtype))
+    stored = jax.eval_shape(lambda: tfm.init_params(jax.random.key(0), cfg))
+    leaves = [bp[name] for bp in sm._block_groups(stored)
+              for name in sm.IN_PROJECTIONS if name in bp]
+    params = as_sds(jax.eval_shape(sm.in_proj_d_last, stored) if convert
+                    else stored)
+    # at static indices the engine keeps the leaves as stored
+    assert bool(sm.in_proj_relaid(params)) == (
+        convert and family != "mixed-kinds")
+    stats = as_sds(jax.eval_shape(lambda: sm.init_stats(cfg)))
+    pool = lambda n, p: (sds((n, p, page, cfg.kv_heads, 128),  # noqa: E731
+                             jnp.bfloat16) if n else None)
+    full, ring = pool(layout.n_full, n_pages), pool(layout.n_ring,
+                                                    slots * rings)
+    pools = (full, full, ring, ring, None, None)
+    n = max_seq // page
+    table = (sds((n,)), sds((rings,)) if rings else None)
+    tables = (sds((slots, n)), sds((slots, rings)) if rings else None)
+    active = sds((slots,), jnp.bool_)
+    kws = dict(page_size=page, impl="pallas", layout=layout)
+    lowered = {
+        "prefill": lambda: sm.make_prefill_step(cfg, chunk=chunk, **kws).lower(
+            params, pools, stats, sds((1, chunk)), sds(()), sds(()), table,
+            None),
+        "decode": lambda: sm.make_decode_step(cfg, **kws).lower(
+            params, pools, stats, sds((slots,)), sds((slots,)), tables,
+            active, None),
+        "verify": lambda: sm.make_verify_step(cfg, width=4, **kws).lower(
+            params, pools, stats, sds((slots, 4)), sds((slots,)),
+            sds((slots,)), tables, active, None),
+    }[step]()
+    return lowered.compile(), leaves
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode", "verify"])
+@pytest.mark.parametrize("family", ["starcoder2-widths", "looped",
+                                    "mixed-kinds"])
+def test_steps_of_a_converted_tree_move_no_in_projection(
+        one_chip, monkeypatch, family, step):
+    """The three serving steps compiled from the tree as an ``Engine``
+    holds it (``serve/model.in_proj_d_last``: under a scan ``wq_t [L, H,
+    Dh, d]``, ``wkv_t [L, Hkv, 2 Dh, d]``, the contracted axis last), at
+    the attention widths of StarCoder2 under the layers' scan, of Ouro
+    under the passes' scan around it, and of K-EXAONE's mixed kinds at
+    static indices (left as stored: nothing cuts such a layer out, and
+    its transposition sits inside the product's fusion): no ``copy`` and
+    no slice fusion yields one layer's ``wq`` or ``wkv``, or the stack's:
+    the products read the leaves where they lie, as the MLP's do, and the
+    step holds under 10 MB of temporaries. The control is the next
+    test."""
+    _compile_kernels(monkeypatch)
+    compiled, leaves = _in_proj_program(one_chip, family, step, convert=True)
+    text = compiled.as_text()
+    assert text.startswith(f"HloModule jit_{step}_step")
+    moved = _in_proj_ops(text, leaves)
+    assert not moved, moved[0][:300]
+    assert compiled.memory_analysis().temp_size_in_bytes < 10e6
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+@pytest.mark.parametrize("family", ["starcoder2-widths", "looped"])
+def test_steps_of_the_stored_tree_cut_out_and_transpose_them(
+        one_chip, monkeypatch, family, step):
+    """The control of the test above, and what the benchmark's traces
+    showed before the engine converted its tree: from the leaves as
+    stored (``[L, d, H, Dh]``: the contracted axis third from minor) the
+    same steps under the same scans do yield them. Grouped heads: one
+    layer's ``wq`` and ``wkv`` sliced out of the stack, every layer
+    (``constant_dynamic-slice_fusion``, and a ``copy`` to the product's
+    layout in the prefill step). 16 heads with no grouping: the whole
+    stack's, copied at the step's entry (135 MB of temporaries at 8
+    layers, the ``wkv``'s; 1.21 GB at Ouro's 48, both)."""
+    _compile_kernels(monkeypatch)
+    compiled, leaves = _in_proj_program(one_chip, family, step,
+                                        convert=False)
+    moved = _in_proj_ops(compiled.as_text(), leaves)
+    assert len(moved) >= 2, moved                  # wq's and wkv's
+    if family == "looped":
+        assert all(" copy(" in op for op in moved)
+        # the stack's wkv in HBM (its wq, half of that, fits fast memory)
+        assert compiled.memory_analysis().temp_size_in_bytes >= max(
+            2 * leaf.size for leaf in leaves)
+    else:
+        assert any("dynamic-slice" in op for op in moved)
 
 
 def _plain_passes(params, x, rest, fn, cfg):
