@@ -47,24 +47,6 @@ TPU_PEAK_FLOPS: dict[str, float] = {
 }
 
 
-# Published HBM bandwidth per chip (bytes/s), same prefix keying. Used for
-# the bandwidth roofline: a step whose achieved bytes/s sits at this
-# ceiling is HBM-bound — more MFU is not available without moving less
-# data (fusion, layout, batching), which turns "the CNN rows are
-# HBM-bound" from an assertion into a measurement.
-TPU_PEAK_HBM_BYTES: dict[str, float] = {
-    "TPU v6": 1640e9,        # v6e (Trillium)
-    "TPU v5p": 2765e9,
-    "TPU v5 lite": 819e9,    # v5e
-    "TPU v5e": 819e9,
-    "TPU v5": 2765e9,
-    "TPU v4 lite": 614e9,
-    "TPU v4": 1228e9,
-    "TPU v3": 900e9,
-    "TPU v2": 700e9,
-}
-
-
 def match_device_kind(table: dict, device=None, *, kind: str | None = None):
     """Longest-prefix lookup of ``device.device_kind`` in ``table`` (so
     "TPU v5 lite..." hits a "TPU v5 lite" row, not "TPU v5"). Shared by the
@@ -201,28 +183,6 @@ def assert_donation(jitted: Callable, *args, min_aliased: int = 1,
             f"compiled program has {report['n_aliased']} — donation is "
             f"not set up (missing donate_argnums?)")
     return report
-
-
-def demand_frac_of_peak(bytes_per_s: float | None,
-                        peak_bytes_per_s: float | None
-                        ) -> tuple[float | None, str | None]:
-    """Demand-side bytes rate as a fraction of the physical HBM peak —
-    or ``(None, reason)`` when the fraction exceeds 1.0: a demand
-    estimate above the DMA ceiling is an op-level byte-accounting
-    overcount (XLA's op-level "bytes accessed" bills a value kept in
-    VMEM once per use), not a measurement, and must not be published as
-    one. The policy point of scripts/dmp_report.py. The GB/s demand
-    number stays honest as *demand*; only the roofline *position* is
-    refused."""
-    if not bytes_per_s or not peak_bytes_per_s:
-        return None, None
-    frac = bytes_per_s / peak_bytes_per_s
-    if frac > 1.0:
-        return None, (f"demand {bytes_per_s / 1e9:.0f} GB/s exceeds the "
-                      f"{peak_bytes_per_s / 1e9:.0f} GB/s physical peak "
-                      f"({frac:.2f}x): op-level byte accounting overcount, "
-                      f"not a DMA rate")
-    return round(frac, 3), None
 
 
 def lm_model_flops(cfg, batch: int, seq: int, causal: bool = True) -> float:

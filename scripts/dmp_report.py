@@ -6,10 +6,6 @@ every trainer via RunLogger and by the serving engine) with an optional
 xplane trace directory (utils/xplane op breakdown) and prints:
 
 * step-time percentiles (p50/p90/p99) and throughput from ``step`` records;
-* step phase breakdown (``step_phase`` records): host-input /
-  h2d / device seconds per step + the pipeline-active proof (device
-  prefetch lead, donation aliases, grad bucketing, fused optimizer) —
-  "phase timing unavailable" on runs that could not attribute (CPU);
 * comm/compute overlap from the xplane device timeline (``--trace``): the
   comm-hidden fraction — how much of the collective time the backward
   actually covered;
@@ -19,7 +15,6 @@ xplane trace directory (utils/xplane op breakdown) and prints:
   any engine with a telemetry stream attached;
 * MFU against the profiling.py peak tables — or an honest "MFU unavailable"
   line when the device has no peak entry (CPU) or the run recorded no FLOPs;
-* HBM-roofline position when the run recorded demand bytes;
 * communication volume AND message counts per collective kind x mesh axis
   (trace-time ring-model estimates from ops/collectives.py — the beta and
   alpha terms the autotuner's cost model prices with);
@@ -126,10 +121,9 @@ def _steps_section(lines: list[str], steps: list[dict]) -> list[float]:
 
 
 def _mfu_section(lines: list[str], meta: dict, device: dict,
-                 by_kind: dict, times: list[float]) -> None:
+                 times: list[float]) -> None:
     from distributed_model_parallel_tpu.utils.profiling import (
         TPU_PEAK_FLOPS,
-        TPU_PEAK_HBM_BYTES,
         match_device_kind,
     )
 
@@ -137,114 +131,23 @@ def _mfu_section(lines: list[str], meta: dict, device: dict,
     kind = device.get("device_kind", "") or device.get("platform", "?")
     n_dev = max(1, int(device.get("n_devices", 1) or 1))
     peak = match_device_kind(TPU_PEAK_FLOPS, kind=kind)
-    # Global analytic FLOPs (the LM trainer's run meta) or per-device
-    # cost-analysis FLOPs (a "cost_analysis" record).
+    # Global analytic FLOPs from the LM trainer's run meta.
     flops_global = meta.get("model_flops_per_step")
-    ca = (by_kind.get("cost_analysis") or [{}])[-1]
-    flops_device = ca.get("device_flops_per_step")
     if not times:
         lines.append("MFU unavailable (no step-time records)")
     elif peak is None:
         lines.append(f"MFU unavailable (no peak-FLOPs table entry for "
                      f"device_kind={kind!r} — expected on CPU)")
-    elif not (flops_global or flops_device):
+    elif not flops_global:
         lines.append("MFU unavailable (run recorded no FLOPs-per-step; the "
                      "LM trainer records them)")
     else:
         t50 = percentile(times, 50)
-        per_chip = (flops_device if flops_device
-                    else flops_global / n_dev)
+        per_chip = flops_global / n_dev
         lines.append(f"MFU {per_chip / t50 / peak:.3f}  "
                      f"({per_chip / 1e12:.2f} TF/chip/step at p50 "
                      f"{_fmt_s(t50)} vs {peak / 1e12:.0f} TF/s peak "
                      f"[{kind}])")
-    hbm_peak = match_device_kind(TPU_PEAK_HBM_BYTES, kind=kind)
-    bytes_step = ca.get("bytes_accessed_per_step")
-    if bytes_step and times and hbm_peak:
-        from distributed_model_parallel_tpu.utils.profiling import (
-            demand_frac_of_peak,
-        )
-
-        rate = bytes_step / percentile(times, 50)
-        frac, frac_err = demand_frac_of_peak(rate, hbm_peak)
-        if frac_err:
-            # A fraction of the physical peak > 1 is not a roofline
-            # position, it is proof the measurement overcounted — the
-            # policy in utils/profiling.demand_frac_of_peak refuses it.
-            lines.append(f"HBM roofline: MEASUREMENT ERROR — {frac_err}")
-        else:
-            lines.append(
-                f"HBM roofline: demand {rate / 1e9:.0f} GB/s vs "
-                f"{hbm_peak / 1e9:.0f} GB/s peak ({frac:.2f}x) — "
-                f"demand-side estimate (analytic bytes / measured time), "
-                f"not a hardware counter")
-    elif bytes_step:
-        lines.append("HBM roofline unavailable (no peak-bandwidth entry "
-                     f"for device_kind={kind!r})")
-
-
-def _phase_section(lines: list[str], by_kind: dict) -> None:
-    """Step phase breakdown (``step_phase`` records): where a
-    step's wall time goes — host batch assembly, host→device transfer,
-    device compute — plus the no-silent-fallback proof that the raw-speed
-    levers (device prefetch, donation, bucketed grads, fused optimizer)
-    are active. Renders "phase timing unavailable" honestly when the run
-    could not attribute (CPU: no h2d/device boundary)."""
-    recs = by_kind.get("step_phase") or []
-    if not recs:
-        return
-    r = recs[-1]
-    lines.append("== step phase breakdown ==")
-    pipe = r.get("pipeline")
-    if pipe and pipe.get("workload"):
-        # Decode/serve-flavored record: the pipeline identity is its own
-        # key set (batch, prompt/gen lengths, cache kind) — render as-is.
-        lines.append("pipeline: " + "  ".join(
-            f"{k}={v}" for k, v in pipe.items()))
-    elif pipe:
-        lines.append(
-            (f"pipeline: input={pipe.get('input_path')}"
-             if pipe.get("input_path") else "pipeline:")
-            + f"  device_prefetch={pipe.get('device_prefetch_depth')}"
-            + (f" (max lead observed "
-               f"{pipe.get('device_prefetch_max_lead')}"
-               + (", streaming-path probe — the timed loop is "
-                  "device-resident)"
-                  if pipe.get("device_resident_data") else ")")
-               if pipe.get("device_prefetch_max_lead") is not None else "")
-            + f"  host_prefetch={pipe.get('host_prefetch_depth')}"
-            + (f"  steps_per_dispatch={pipe.get('steps_per_dispatch')}"
-               if pipe.get("device_resident_data") else "")
-            + f"  grad={pipe.get('grad_reduction')}"
-            + f"  fused_opt={pipe.get('fused_optimizer')}")
-        dropped = pipe.get("donation_dropped") or []
-        lines.append(
-            f"donation: {pipe.get('donation_aliases')} input→output "
-            f"aliases committed"
-            + (f", dropped {dropped}" if dropped else ", none dropped"))
-    phases = r.get("phases")
-    if not phases:
-        lines.append("phase timing unavailable"
-                     + (f" ({r.get('reason')})" if r.get("reason") else ""))
-        return
-    # Training records carry host-input/h2d/device; a decode record
-    # carries prefill/decode_token/sample — render whatever
-    # ``*_s`` phases the record holds, in record order.
-    keys = [k for k in phases
-            if k.endswith("_s") and isinstance(phases.get(k), (int, float))]
-    total = sum(phases[k] for k in keys)
-    # Training records are per-step; decode records are per generate run
-    # (uniform within each record, so the shares are honest either way).
-    unit = "/run" if pipe and pipe.get("workload") else "/step"
-    for key in keys:
-        v = phases[key]
-        label = key[:-2].replace("_", "-")
-        share = f" ({v / total:5.1%})" if total > 0 else ""
-        lines.append(f"  {label:12s} {_fmt_s(v):>10s}{unit}{share}")
-    lines.append(f"  (serialized attribution probe over "
-                 f"{phases.get('n_steps')} steps — phases cannot hide "
-                 f"behind one another here; the throughput number is the "
-                 f"overlapped pipeline)")
 
 
 def _serving_section(lines: list[str], by_kind: dict) -> None:
@@ -782,8 +685,7 @@ def build_report(records: list[dict], *, trace_dir: str | None = None,
 
     steps = by_kind.get("step", [])
     times = _steps_section(lines, steps)
-    _mfu_section(lines, meta, device, by_kind, times)
-    _phase_section(lines, by_kind)
+    _mfu_section(lines, meta, device, times)
     _serving_section(lines, by_kind)
     _fleet_serving_section(lines, by_kind)
     _capacity_section(lines, records, by_kind)
