@@ -716,6 +716,7 @@ def test_the_looped_stack_carries_its_scopes_counters_and_gauges():
     req = eng.submit([1, 2, 3], 5)
     eng.step_once(0.0, 0.0)
     eng.step_once(0.0, 0.0)
+    eng.results()             # commits the tokens of the turn in flight
     c = eng.loop_counters()
     # the prompt, and every generated token but the last (not yet fed)
     fed = 3 + len(req.generated) - 1
